@@ -159,6 +159,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     # surface the Pallas backend so CI logs show what produced the numbers
     from repro.kernels import use_interpret
 

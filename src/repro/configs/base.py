@@ -35,6 +35,7 @@ class ICDConfig:
     k: int
     alpha0: float = 1.0
     l2: float = 0.1
+    nnz: int = 0          # observed interactions of the training set
     # fm extras
     p_ctx: int = 0
     p_item: int = 0

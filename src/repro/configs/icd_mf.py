@@ -11,8 +11,9 @@ CONFIG = ICDConfig(
     k=128,
     alpha0=1.0,
     l2=0.1,
+    nnz=ICD_SHAPES["epoch_youtube"].extra("nnz"),
 )
 
-SMOKE_CONFIG = dataclasses.replace(CONFIG, n_ctx=60, n_items=40, k=8)
+SMOKE_CONFIG = dataclasses.replace(CONFIG, n_ctx=60, n_items=40, k=8, nnz=0)
 
 SHAPES = ICD_SHAPES

@@ -10,11 +10,20 @@ k=128 fp32) combines them.
 
 ``gram`` dispatches to the Pallas TPU kernel (``repro.kernels.gram``) when
 requested; the pure-XLA path is the default and the oracle.
+
+Precision: every Gram product runs at :data:`PRECISION` (``HIGHEST``). On
+the TPU an fp32 dot at default precision rounds its inputs to bf16 (one
+MXU pass), which would put ~1e-3 relative error into R'/R'' and hence into
+every Newton step; the k×k result costs O(rows·k²) either way, so the
+fp32-exact passes are cheap. The Pallas kernel and the R' products of the
+MF sweeps use the same setting, so flat and fused epochs agree.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+PRECISION = jax.lax.Precision.HIGHEST
 
 
 def gram(m: jax.Array, *, implementation: str = "xla",
@@ -30,7 +39,8 @@ def gram(m: jax.Array, *, implementation: str = "xla",
     if weights is not None:
         return weighted_gram(m, weights)
     mf = m.astype(jnp.float32)
-    return jnp.dot(mf.T, mf, preferred_element_type=jnp.float32)
+    return jnp.dot(mf.T, mf, precision=PRECISION,
+                   preferred_element_type=jnp.float32)
 
 
 def gram_pair(phi: jax.Array, psi: jax.Array, *, implementation: str = "xla"):
@@ -58,4 +68,4 @@ def weighted_gram(m: jax.Array, w: jax.Array) -> jax.Array:
     """J = mᵀ diag(w) m — used for confidence-weighted variants. w: (rows,)."""
     mf = m.astype(jnp.float32)
     return jnp.dot(mf.T * w[None, :].astype(jnp.float32), mf,
-                   preferred_element_type=jnp.float32)
+                   precision=PRECISION, preferred_element_type=jnp.float32)

@@ -554,7 +554,8 @@ def fit(params, x, z, data, hp, n_epochs, callback=None, refresh_residuals=True,
     for ep in range(n_epochs):
         if refresh_residuals and ep > 0:
             e = residuals(params, x, z, data, hp)  # bound multi-hot drift
-        params, e = epoch(params, x, z, data, e, hp, schedule, ep, weights)
+        params, e = epoch(params, x, z, data, e, hp, schedule,
+                          ep if schedule is not None else 0, weights)
         if callback is not None:
             callback(ep, params)
     return params

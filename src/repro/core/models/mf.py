@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import sweeps
+from repro.core.gram import PRECISION as gram_precision
 from repro.core.gram import gram
 from repro.core.implicit import implicit_objective
 from repro.sparse.interactions import Interactions
@@ -116,7 +117,8 @@ def _side_sweep(
         lp = segment_sum(alpha * e * o_col, rows_nnz, n_rows)
         lpp = segment_sum(alpha * o_col * o_col, rows_nnz, n_rows)
         # implicit parts (R'/2, R''/2) via the opposite Gram — Lemma 3
-        rp = side_m @ sweeps.take_col(other_j, f)      # Σ_f' J(f',f)·w_{·,f'}
+        rp = jnp.dot(side_m, sweeps.take_col(other_j, f),  # Σ_f' J(f',f)·w_{·,f'}
+                     precision=gram_precision)
         rpp = other_j[f, f]
         delta = sweeps.newton_delta(
             sweeps.NewtonParts(lp + hp.alpha0 * rp, lpp + hp.alpha0 * rpp),
@@ -208,10 +210,13 @@ def fit(
 
     With a ``schedule``, epoch ``ep`` sweeps the schedule's blocks for
     ``sweep_index=ep`` — e.g. ``SweepSchedule('rotating',
-    blocks_per_sweep=1)`` turns each "epoch" into one k_b subspace step."""
+    blocks_per_sweep=1)`` turns each "epoch" into one k_b subspace step.
+    Without one, every epoch passes ``sweep_index=0``: the index is static,
+    so unscheduled epochs share one compiled program."""
     e = residuals(params, data)
     for ep in range(n_epochs):
-        params, e = epoch(params, data, e, hp, schedule, ep, weights)
+        params, e = epoch(params, data, e, hp, schedule,
+                          ep if schedule is not None else 0, weights)
         if callback is not None:
             callback(ep, params)
     return params

@@ -179,7 +179,8 @@ def _route(e_src, src_idx, dst_pos, p_dest, axis_name):
 def make_shard_mesh(n_shards: int):
     """One flat shard axis over all chips — the optimized iCD layout (the
     hillclimb's alternative to the baseline (data, model) GSPMD layout)."""
-    return jax.make_mesh((n_shards,), ("shards",))
+    return jax.make_mesh((n_shards,), ("shards",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def build_epoch(mesh, hp: MFHyperParams, sd_template: ShardedMF,
@@ -196,7 +197,6 @@ def build_epoch(mesh, hp: MFHyperParams, sd_template: ShardedMF,
     wire_dtype — iteration 3: bf16 on the wire for routed/gathered values
                  (Newton math stays fp32; quantizing ψ/φ inputs only).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = mesh.axis_names[0]
@@ -296,20 +296,12 @@ def build_epoch(mesh, hp: MFHyperParams, sd_template: ShardedMF,
         c_per=sd_template.c_per, i_per=sd_template.i_per,
         n_shards=sd_template.n_shards,
     )
-    try:
-        fn = shard_map(
-            epoch_shard, mesh=mesh,
-            in_specs=(specs, specs, sd_specs, specs),
-            out_specs=(specs, specs, specs),
-            check_vma=False,
-        )
-    except TypeError:  # older jax spells it check_rep
-        fn = shard_map(
-            epoch_shard, mesh=mesh,
-            in_specs=(specs, specs, sd_specs, specs),
-            out_specs=(specs, specs, specs),
-            check_rep=False,
-        )
+    fn = jax.shard_map(
+        epoch_shard, mesh=mesh,
+        in_specs=(specs, specs, sd_specs, specs),
+        out_specs=(specs, specs, specs),
+        check_vma=False,
+    )
     return jax.jit(fn)
 
 

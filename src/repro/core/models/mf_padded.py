@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import sweeps
+from repro.core.gram import PRECISION as gram_precision
 from repro.core.models.mf import MFHyperParams, MFParams
 from repro.kernels import vmem
 from repro.kernels.cd_sweep.ops import cd_block_sweep, cd_block_sweep_gather
@@ -165,7 +166,8 @@ def _padded_side_sweep(side, other, other_j, ids_pad, alpha_pad, e_pad, hp):
     def body(f, carry):
         side_m, e_pad = carry
         psi_pad = jnp.take(sweeps.take_col(other, f), ids_pad)   # (n, d_pad)
-        r1 = side_m @ sweeps.take_col(other_j, f)
+        r1 = jnp.dot(side_m, sweeps.take_col(other_j, f),
+                     precision=gram_precision)
         w_new, e_pad = cd_column_update(
             psi_pad, alpha_pad, e_pad, sweeps.take_col(side_m, f), r1,
             other_j[f, f], alpha0=hp.alpha0, l2=hp.l2, eta=hp.eta,
@@ -174,7 +176,8 @@ def _padded_side_sweep(side, other, other_j, ids_pad, alpha_pad, e_pad, hp):
 
     def block_body(f0, kb, carry):
         side_m, e_pad = carry
-        r1_blk = side_m @ other_j[:, f0:f0 + kb]                 # R'/2 slab
+        r1_blk = jnp.dot(side_m, other_j[:, f0:f0 + kb],          # R'/2 slab
+                         precision=gram_precision)
         if use_gather:
             # ψ slab (n_items, kb) + id grid — the kernel gathers Ψ rows
             w_new, e_pad = cd_block_sweep_gather(

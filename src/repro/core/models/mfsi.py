@@ -416,7 +416,8 @@ def fit(params, x, z, data, hp, n_epochs, callback=None, schedule=None,
         weights=None):
     e = residuals(params, x, z, data)
     for ep in range(n_epochs):
-        params, e = epoch(params, x, z, data, e, hp, schedule, ep, weights)
+        params, e = epoch(params, x, z, data, e, hp, schedule,
+                          ep if schedule is not None else 0, weights)
         if callback is not None:
             callback(ep, params)
     return params

@@ -466,7 +466,8 @@ def fit(params, tc, data, hp, n_epochs, callback=None, schedule=None,
         weights=None):
     e = residuals(params, tc, data)
     for ep in range(n_epochs):
-        params, e = epoch(params, tc, data, e, hp, schedule, ep, weights)
+        params, e = epoch(params, tc, data, e, hp, schedule,
+                          ep if schedule is not None else 0, weights)
         if callback is not None:
             callback(ep, params)
     return params

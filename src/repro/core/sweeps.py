@@ -249,13 +249,22 @@ def put_col(m: jax.Array, f, col: jax.Array) -> jax.Array:
     return jax.lax.dynamic_update_slice_in_dim(m, col[:, None], f, axis=1)
 
 
+_RESID_BATCH = 1 << 16
+
+
 def residuals_from_factors(
     phi: jax.Array, psi: jax.Array, ctx: jax.Array, item: jax.Array, y: jax.Array
 ) -> jax.Array:
-    """e = ŷ − ȳ on observed pairs: Σ_f φ_f(c)ψ_f(i) − ȳ, per nnz."""
-    scores = jnp.sum(
-        jnp.take(phi, ctx, axis=0) * jnp.take(psi, item, axis=0), axis=-1
-    )
+    """e = ŷ − ȳ on observed pairs: Σ_f φ_f(c)ψ_f(i) − ȳ, per nnz.
+
+    Evaluated in batches of ``_RESID_BATCH`` pairs, so the gathered rows
+    never exceed ``2·_RESID_BATCH·k`` floats: at 20M pairs and k=128 the
+    whole gather would be 20 GB, more than one chip holds."""
+    def score(pair):
+        c, i = pair
+        return jnp.sum(jnp.take(phi, c, axis=0) * jnp.take(psi, i, axis=0))
+
+    scores = jax.lax.map(score, (ctx, item), batch_size=_RESID_BATCH)
     return scores - y
 
 

@@ -31,7 +31,8 @@ kernel re-streams the `(C, D_pad)` residual cache ``e`` and confidence
 tensor ``α`` from HBM once per column — k round-trips per sweep — even
 though the per-column compute is tiny. Here the `(block_ctx, D_pad)` tiles
 of ``e`` and ``α`` are loaded into VMEM ONCE and stay resident while all
-``k_b`` Newton steps run in an in-register ``lax.fori_loop``:
+``k_b`` Newton steps run, statically unrolled over 8-row slices
+(``_sweep_rows``; Mosaic lowers no value-level ``dynamic_slice``):
 
   inputs  (per block): Ψ tile  (bc, k_b, D_pad) — pre-gathered ψ_f(item)
                                                   for every column in block
@@ -83,37 +84,65 @@ from jax.experimental import pallas as pl
 from repro.kernels import vmem
 
 
+def _col(x, j):
+    """Column ``j`` of a (rows, n) value as (rows, 1): a one-hot lane select
+    and a lane reduction, which Mosaic lowers where a lane slice does not."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.sum(jnp.where(lane == j, x, 0.0), axis=-1, keepdims=True)
+
+
+_ROWS = 8  # rows per inner step: one sublane tile; every block_ctx is a multiple
+
+
+def _by_rows(n_rows, fn):
+    """Run ``fn(rows)`` over the block in ``_ROWS``-row slices. The rows of
+    a block are independent, so walking them in sublane-tile slices keeps
+    every value a few vregs wide — the unrolled column walk then compiles
+    in seconds at any block_ctx instead of growing with it."""
+    def step(r, carry):
+        fn(pl.ds(pl.multiple_of(r * _ROWS, _ROWS), _ROWS))
+        return carry
+
+    jax.lax.fori_loop(0, n_rows // _ROWS, step, 0)
+
+
+def _sweep_rows(alpha0, l2, eta, k_b, psi_col, coupling, alpha_ref, e_ref,
+                w_ref, r1_ref, w_out_ref, e_out_ref):
+    """The k_b sequential Newton steps of one block, shared by every block
+    sweep kernel. Per ``_ROWS``-row slice ``sl`` the column walk is
+    statically unrolled (k_b ≤ 8): ``psi_col(sl, j)`` is column j of Ψ
+    (rows, d_pad), and ``coupling(sl, j)`` is the (·, k_b) Gauss–Seidel R'
+    patch row of column j (its own entry is the R''/2 diagonal)."""
+    def rows(sl):
+        alpha = alpha_ref[sl, :].astype(jnp.float32)   # (rows, d_pad)
+        e = e_ref[sl, :].astype(jnp.float32)           # (rows, d_pad)
+        w = w_ref[sl, :].astype(jnp.float32)           # (rows, k_b)
+        r1 = r1_ref[sl, :].astype(jnp.float32)         # (rows, k_b)
+        lane = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+        for j in range(k_b):
+            psi_j = psi_col(sl, j)                     # (rows, d_pad)
+            c_row = coupling(sl, j)                    # (·, k_b)
+            lp = jnp.sum(alpha * e * psi_j, axis=1, keepdims=True)       # L'/2
+            lpp = jnp.sum(alpha * psi_j * psi_j, axis=1, keepdims=True)  # L''/2
+            num = lp + alpha0 * _col(r1, j) + l2 * _col(w, j)
+            den = lpp + alpha0 * _col(c_row, j) + l2
+            delta = -eta * num / jnp.maximum(den, 1e-12)
+            w = jnp.where(lane == j, w + delta, w)
+            e = e + delta * psi_j
+            r1 = r1 + delta * c_row
+        w_out_ref[sl, :] = w
+        e_out_ref[sl, :] = e
+
+    _by_rows(alpha_ref.shape[0], rows)
+
+
 def _sweep_kernel(alpha0, l2, eta, k_b, psi_ref, alpha_ref, e_ref, w_ref,
                   r1_ref, jblk_ref, w_out_ref, e_out_ref):
-    psi = psi_ref[...].astype(jnp.float32)      # (bc, k_b, d_pad)
-    alpha = alpha_ref[...].astype(jnp.float32)  # (bc, d_pad)
-    e = e_ref[...].astype(jnp.float32)          # (bc, d_pad)
-    w = w_ref[...].astype(jnp.float32)          # (bc, k_b)
-    r1 = r1_ref[...].astype(jnp.float32)        # (bc, k_b)
     jblk = jblk_ref[...].astype(jnp.float32)    # (k_b, k_b)
-
-    def newton(j, carry):
-        w, r1, e = carry
-        psi_j = jax.lax.dynamic_index_in_dim(psi, j, axis=1, keepdims=False)
-        w_j = jax.lax.dynamic_slice_in_dim(w, j, 1, axis=1)       # (bc, 1)
-        r1_j = jax.lax.dynamic_slice_in_dim(r1, j, 1, axis=1)     # (bc, 1)
-        j_row = jax.lax.dynamic_slice_in_dim(jblk, j, 1, axis=0)  # (1, k_b)
-        jff = jax.lax.dynamic_slice_in_dim(j_row, j, 1, axis=1)   # (1, 1)
-
-        lp = jnp.sum(alpha * e * psi_j, axis=1, keepdims=True)            # L'/2
-        lpp = jnp.sum(alpha * psi_j * psi_j, axis=1, keepdims=True)       # L''/2
-        num = lp + alpha0 * r1_j + l2 * w_j
-        den = lpp + alpha0 * jff + l2
-        delta = -eta * num / jnp.maximum(den, 1e-12)
-
-        w = jax.lax.dynamic_update_slice_in_dim(w, w_j + delta, j, axis=1)
-        e = e + delta * psi_j
-        r1 = r1 + delta * j_row
-        return w, r1, e
-
-    w, r1, e = jax.lax.fori_loop(0, k_b, newton, (w, r1, e))
-    w_out_ref[...] = w
-    e_out_ref[...] = e
+    _sweep_rows(alpha0, l2, eta, k_b,
+                lambda sl, j: psi_ref[sl, j, :].astype(jnp.float32),
+                lambda sl, j: jblk[j:j + 1, :],  # shared (1, k_b) Gram row
+                alpha_ref, e_ref, w_ref, r1_ref, w_out_ref, e_out_ref)
 
 
 def cd_block_sweep_pallas(
@@ -173,35 +202,10 @@ def cd_block_sweep_pallas(
 def _sweep_rowpatch_kernel(alpha0, l2, eta, k_b, psi_ref, alpha_ref, e_ref,
                            w_ref, r1_ref, p_ref, w_out_ref, e_out_ref):
     """Block sweep with a per-row R' patch tensor (PARAFAC/Tucker modes)."""
-    psi = psi_ref[...].astype(jnp.float32)      # (bc, k_b, d_pad)
-    alpha = alpha_ref[...].astype(jnp.float32)  # (bc, d_pad)
-    e = e_ref[...].astype(jnp.float32)          # (bc, d_pad)
-    w = w_ref[...].astype(jnp.float32)          # (bc, k_b)
-    r1 = r1_ref[...].astype(jnp.float32)        # (bc, k_b)
-    p = p_ref[...].astype(jnp.float32)          # (bc, k_b, k_b)
-
-    def newton(j, carry):
-        w, r1, e = carry
-        psi_j = jax.lax.dynamic_index_in_dim(psi, j, axis=1, keepdims=False)
-        w_j = jax.lax.dynamic_slice_in_dim(w, j, 1, axis=1)       # (bc, 1)
-        r1_j = jax.lax.dynamic_slice_in_dim(r1, j, 1, axis=1)     # (bc, 1)
-        p_j = jax.lax.dynamic_index_in_dim(p, j, axis=1, keepdims=False)  # (bc, k_b)
-        p_jj = jax.lax.dynamic_slice_in_dim(p_j, j, 1, axis=1)    # (bc, 1) = R''/2
-
-        lp = jnp.sum(alpha * e * psi_j, axis=1, keepdims=True)            # L'/2
-        lpp = jnp.sum(alpha * psi_j * psi_j, axis=1, keepdims=True)       # L''/2
-        num = lp + alpha0 * r1_j + l2 * w_j
-        den = lpp + alpha0 * p_jj + l2
-        delta = -eta * num / jnp.maximum(den, 1e-12)
-
-        w = jax.lax.dynamic_update_slice_in_dim(w, w_j + delta, j, axis=1)
-        e = e + delta * psi_j
-        r1 = r1 + delta * p_j     # Gauss–Seidel: row-local coupling patch
-        return w, r1, e
-
-    w, r1, e = jax.lax.fori_loop(0, k_b, newton, (w, r1, e))
-    w_out_ref[...] = w
-    e_out_ref[...] = e
+    _sweep_rows(alpha0, l2, eta, k_b,
+                lambda sl, j: psi_ref[sl, j, :].astype(jnp.float32),
+                lambda sl, j: p_ref[sl, j, :].astype(jnp.float32),
+                alpha_ref, e_ref, w_ref, r1_ref, w_out_ref, e_out_ref)
 
 
 def cd_block_sweep_rowpatch_pallas(
@@ -321,11 +325,20 @@ def cd_slab_reduce_pallas(
     return q[:c], p[:c]
 
 
-def _resid_patch_kernel(psi_ref, e_ref, dphi_ref, e_out_ref):
-    psi = psi_ref[...].astype(jnp.float32)      # (bc, m, d_pad)
-    e = e_ref[...].astype(jnp.float32)          # (bc, d_pad)
-    dphi = dphi_ref[...].astype(jnp.float32)    # (bc, m)
-    e_out_ref[...] = e + jnp.einsum("bm,bmd->bd", dphi, psi)
+def _resid_rows(m, psi_col, e_ref, dphi_ref, e_out_ref):
+    def rows(sl):
+        e = e_ref[sl, :].astype(jnp.float32)        # (rows, d_pad)
+        dphi = dphi_ref[sl, :].astype(jnp.float32)  # (rows, m)
+        for j in range(m):                          # static unroll
+            e = e + _col(dphi, j) * psi_col(sl, j)
+        e_out_ref[sl, :] = e
+
+    _by_rows(e_ref.shape[0], rows)
+
+
+def _resid_patch_kernel(m, psi_ref, e_ref, dphi_ref, e_out_ref):
+    _resid_rows(m, lambda sl, j: psi_ref[sl, j, :].astype(jnp.float32),
+                e_ref, dphi_ref, e_out_ref)
 
 
 def cd_resid_patch_pallas(
@@ -353,7 +366,7 @@ def cd_resid_patch_pallas(
 
     grid = (c_pad // block_ctx,)
     e_new = pl.pallas_call(
-        _resid_patch_kernel,
+        partial(_resid_patch_kernel, m),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_ctx, m, d_pad), lambda i: (i, 0, 0)),
@@ -391,39 +404,19 @@ def _pad_gather_operands(psi_tab, ids, row_arrays, block_ctx):
     return psi_tab, ids, row_arrays, c_pad
 
 
+def _gather_col(tab_ref, ids_ref):
+    """Column j of the gathered Ψ for a row slice: ``tab[ids, j]`` — the
+    interpret-only value-level gather (module docstring)."""
+    tab = tab_ref[...].astype(jnp.float32)      # (n_src_pad, m) ψ slab
+    return lambda sl, j: jnp.take(tab[:, j], ids_ref[sl, :], mode="clip")
+
+
 def _sweep_gather_kernel(alpha0, l2, eta, k_b, tab_ref, ids_ref, alpha_ref,
                          e_ref, w_ref, r1_ref, jblk_ref, w_out_ref, e_out_ref):
-    tab = tab_ref[...].astype(jnp.float32)      # (n_src_pad, k_b) ψ slab
-    ids = ids_ref[...]                          # (bc, d_pad) int32
-    alpha = alpha_ref[...].astype(jnp.float32)  # (bc, d_pad)
-    e = e_ref[...].astype(jnp.float32)          # (bc, d_pad)
-    w = w_ref[...].astype(jnp.float32)          # (bc, k_b)
-    r1 = r1_ref[...].astype(jnp.float32)        # (bc, k_b)
     jblk = jblk_ref[...].astype(jnp.float32)    # (k_b, k_b)
-
-    def newton(j, carry):
-        w, r1, e = carry
-        tab_j = jax.lax.dynamic_index_in_dim(tab, j, axis=1, keepdims=False)
-        psi_j = jnp.take(tab_j, ids, mode="clip")  # per-row gather (bc, d_pad)
-        w_j = jax.lax.dynamic_slice_in_dim(w, j, 1, axis=1)       # (bc, 1)
-        r1_j = jax.lax.dynamic_slice_in_dim(r1, j, 1, axis=1)     # (bc, 1)
-        j_row = jax.lax.dynamic_slice_in_dim(jblk, j, 1, axis=0)  # (1, k_b)
-        jff = jax.lax.dynamic_slice_in_dim(j_row, j, 1, axis=1)   # (1, 1)
-
-        lp = jnp.sum(alpha * e * psi_j, axis=1, keepdims=True)            # L'/2
-        lpp = jnp.sum(alpha * psi_j * psi_j, axis=1, keepdims=True)       # L''/2
-        num = lp + alpha0 * r1_j + l2 * w_j
-        den = lpp + alpha0 * jff + l2
-        delta = -eta * num / jnp.maximum(den, 1e-12)
-
-        w = jax.lax.dynamic_update_slice_in_dim(w, w_j + delta, j, axis=1)
-        e = e + delta * psi_j
-        r1 = r1 + delta * j_row
-        return w, r1, e
-
-    w, r1, e = jax.lax.fori_loop(0, k_b, newton, (w, r1, e))
-    w_out_ref[...] = w
-    e_out_ref[...] = e
+    _sweep_rows(alpha0, l2, eta, k_b, _gather_col(tab_ref, ids_ref),
+                lambda sl, j: jblk[j:j + 1, :],
+                alpha_ref, e_ref, w_ref, r1_ref, w_out_ref, e_out_ref)
 
 
 def cd_block_sweep_gather_pallas(
@@ -483,37 +476,9 @@ def cd_block_sweep_gather_pallas(
 def _sweep_rowpatch_gather_kernel(alpha0, l2, eta, k_b, tab_ref, ids_ref,
                                   alpha_ref, e_ref, w_ref, r1_ref, p_ref,
                                   w_out_ref, e_out_ref):
-    tab = tab_ref[...].astype(jnp.float32)      # (n_src_pad, k_b) ψ slab
-    ids = ids_ref[...]                          # (bc, d_pad) int32
-    alpha = alpha_ref[...].astype(jnp.float32)  # (bc, d_pad)
-    e = e_ref[...].astype(jnp.float32)          # (bc, d_pad)
-    w = w_ref[...].astype(jnp.float32)          # (bc, k_b)
-    r1 = r1_ref[...].astype(jnp.float32)        # (bc, k_b)
-    p = p_ref[...].astype(jnp.float32)          # (bc, k_b, k_b)
-
-    def newton(j, carry):
-        w, r1, e = carry
-        tab_j = jax.lax.dynamic_index_in_dim(tab, j, axis=1, keepdims=False)
-        psi_j = jnp.take(tab_j, ids, mode="clip")  # per-row gather (bc, d_pad)
-        w_j = jax.lax.dynamic_slice_in_dim(w, j, 1, axis=1)       # (bc, 1)
-        r1_j = jax.lax.dynamic_slice_in_dim(r1, j, 1, axis=1)     # (bc, 1)
-        p_j = jax.lax.dynamic_index_in_dim(p, j, axis=1, keepdims=False)  # (bc, k_b)
-        p_jj = jax.lax.dynamic_slice_in_dim(p_j, j, 1, axis=1)    # (bc, 1) = R''/2
-
-        lp = jnp.sum(alpha * e * psi_j, axis=1, keepdims=True)            # L'/2
-        lpp = jnp.sum(alpha * psi_j * psi_j, axis=1, keepdims=True)       # L''/2
-        num = lp + alpha0 * r1_j + l2 * w_j
-        den = lpp + alpha0 * p_jj + l2
-        delta = -eta * num / jnp.maximum(den, 1e-12)
-
-        w = jax.lax.dynamic_update_slice_in_dim(w, w_j + delta, j, axis=1)
-        e = e + delta * psi_j
-        r1 = r1 + delta * p_j
-        return w, r1, e
-
-    w, r1, e = jax.lax.fori_loop(0, k_b, newton, (w, r1, e))
-    w_out_ref[...] = w
-    e_out_ref[...] = e
+    _sweep_rows(alpha0, l2, eta, k_b, _gather_col(tab_ref, ids_ref),
+                lambda sl, j: p_ref[sl, j, :].astype(jnp.float32),
+                alpha_ref, e_ref, w_ref, r1_ref, w_out_ref, e_out_ref)
 
 
 def cd_block_sweep_rowpatch_gather_pallas(
@@ -629,18 +594,7 @@ def cd_slab_reduce_gather_pallas(
 
 
 def _resid_patch_gather_kernel(m, tab_ref, ids_ref, e_ref, dphi_ref, e_out_ref):
-    tab = tab_ref[...].astype(jnp.float32)      # (n_src_pad, m) ψ slab
-    ids = ids_ref[...]                          # (bc, d_pad) int32
-    e = e_ref[...].astype(jnp.float32)          # (bc, d_pad)
-    dphi = dphi_ref[...].astype(jnp.float32)    # (bc, m)
-
-    def add_col(j, e):
-        tab_j = jax.lax.dynamic_index_in_dim(tab, j, axis=1, keepdims=False)
-        psi_j = jnp.take(tab_j, ids, mode="clip")  # per-row gather (bc, d_pad)
-        dphi_j = jax.lax.dynamic_slice_in_dim(dphi, j, 1, axis=1)  # (bc, 1)
-        return e + dphi_j * psi_j
-
-    e_out_ref[...] = jax.lax.fori_loop(0, m, add_col, e)
+    _resid_rows(m, _gather_col(tab_ref, ids_ref), e_ref, dphi_ref, e_out_ref)
 
 
 def cd_resid_patch_gather_pallas(

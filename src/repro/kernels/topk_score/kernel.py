@@ -18,8 +18,9 @@ read. This kernel fuses the two:
                                  block_items) int8, or the per-row exclude
                                  ID tile (block_b, L_pad) int32
     compute per step:  S = φ·ψᵀ (MXU), mask exclusions/padding to −inf,
-                       merge: top_k over [running K_pad | S] — scores and
-                       ids together, in registers/VMEM
+                       merge: insert the tile's entrants into the sorted
+                       running K_pad state — scores and ids together, in
+                       registers/VMEM (``_merge_tile``)
 
   The ``(B, n_items)`` score matrix NEVER exists: per step only the
   (block_b, block_items) tile is alive, and the merged state written back
@@ -44,14 +45,15 @@ Exclusion comes in two forms:
 
 Semantics (pinned by ``ref.topk_score_ref`` and the parity tests):
 
-  * EXACT ``lax.top_k`` parity: scores and ids equal the dense
-    ``lax.top_k(Φ·Ψᵀ, K)`` whenever at least K admissible candidates
-    exist.
+  * ``lax.top_k`` parity: ids equal the dense ``lax.top_k(Φ·Ψᵀ, K)``
+    whenever at least K admissible candidates exist, and scores agree
+    under the fp32 score contract of ``ref.py``.
   * Tie policy (stable): equal scores rank in ascending item id, exactly
     like ``lax.top_k`` over an id-ordered dense row. This holds because
-    ``lax.top_k`` is positionally stable, item blocks arrive in ascending
-    id order, and the running state sits BEFORE the fresh tile in the
-    merge concat — earlier (smaller-id) candidates always win ties.
+    item blocks arrive in ascending id order, a tile candidate enters only
+    strictly above the running k-th score and lands after every slot
+    scoring ≥ it, and within a tile the smaller id is inserted first.
+    Scores rank in ``lax.top_k``'s total order (NaN highest).
   * Inadmissible slots: when a row has fewer than K admissible candidates
     (exclude mask covers the row, or K > n_items), the tail slots return
     id −1 with score −inf — excluded items never leak their ids, unlike a
@@ -71,11 +73,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import vmem
+from repro.kernels.topk_score.ref import SCORE_PRECISION
 
 
-def _score_and_merge(block_items, k_pad, meta_ref, psi_ref, phi_ref, s_ref,
+def _score_and_merge(block_items, k, meta_ref, psi_ref, phi_ref, s_ref,
                      i_ref, excl_ref=None, exclid_ref=None, scale_ref=None):
     """One grid step: score the ψ tile and merge into the running top-K.
 
@@ -102,40 +106,104 @@ def _score_and_merge(block_items, k_pad, meta_ref, psi_ref, phi_ref, s_ref,
     if scale_ref is not None:
         psi = psi * scale_ref[...]           # per-row dequant, broadcast (.,1)
     scores = jax.lax.dot_general(
-        phi, psi, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        phi, psi, (((1,), (1,)), ((), ())), precision=SCORE_PRECISION,
+        preferred_element_type=jnp.float32,
     )                                        # (block_b, block_items)
     offset = meta_ref[0, 0]
     n_valid = meta_ref[0, 1]
-    local = step * block_items + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 1
-    )
+    lane = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    local = step * block_items + lane
     admissible = local < n_valid
     ids = offset + local                     # GLOBAL catalogue ids
     if excl_ref is not None:
         admissible &= excl_ref[...] == 0
     if exclid_ref is not None:
-        # per-row exclude ID list (block_b, L_pad), −1 padding: a candidate
-        # is excluded iff its GLOBAL id appears in its row's list — the
-        # (block_b, block_items) admissibility tile is built right here,
-        # so no (B, n_items) mask ever exists
-        excl_ids = exclid_ref[...]           # (block_b, l_pad) int32
-        hit = (ids[:, None, :] == excl_ids[:, :, None]).any(axis=1)
-        admissible &= ~hit
-    # inadmissible candidates keep −inf; they lose every tie against the
-    # −inf/id−1 init state (which sits first in the concat), so their ids
-    # never surface in the output
+        admissible &= ~_excluded(exclid_ref[...], offset + step * block_items,
+                                 lane)
+    # inadmissible candidates keep −inf, and a −inf candidate never enters
+    # the running state (entry needs a score STRICTLY above its k-th slot)
     scores = jnp.where(admissible, scores, -jnp.inf)
-
-    # merge-in-registers: running state FIRST so positional stability of
-    # top_k implements the ascending-id tie policy (blocks arrive id-sorted)
-    cat_s = jnp.concatenate([s_ref[...], scores], axis=1)
-    cat_i = jnp.concatenate([i_ref[...], ids], axis=1)
-    new_s, sel = jax.lax.top_k(cat_s, k_pad)
-    s_ref[...] = new_s
-    i_ref[...] = jnp.take_along_axis(cat_i, sel, axis=1)
+    s_ref[...], i_ref[...] = _merge_tile(k, scores, ids, s_ref[...],
+                                         i_ref[...])
 
 
-def _topk_kernel(block_items, k_pad, has_scale, excl_kind, *refs):
+def _excluded(excl_ids, base, lane):
+    """(block_b, block_items) hit mask: candidate ``base + lane`` appears in
+    its row's −1-padded exclude-id list (block_b, L_pad).
+
+    One list column per loop step, picked by a one-hot lane select and a
+    lane reduction (Mosaic lowers neither a dynamic lane slice nor the 3-D
+    (block_b, L_pad, block_items) broadcast-compare), then compared against
+    the tile's local positions."""
+    pos = jnp.where(excl_ids >= 0, excl_ids - base, -1)   # tile-local slot
+    col_lane = jax.lax.broadcasted_iota(jnp.int32, pos.shape, 1)
+
+    def one(col, hit):
+        p = jnp.sum(jnp.where(col_lane == col, pos, 0), axis=1, keepdims=True)
+        return jnp.where(lane == p, 1, hit)
+
+    hit = jax.lax.fori_loop(0, pos.shape[1], one,
+                            jnp.zeros(lane.shape, jnp.int32))
+    return hit > 0
+
+
+def _order_key(x):
+    """Total-order int32 key of fp32 scores — the order ``lax.top_k`` ranks
+    by (NaN above +inf, −0 below +0). The map is its own inverse."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _from_key(key):
+    return jax.lax.bitcast_convert_type(_order_key(key), jnp.float32)
+
+
+def _merge_tile(k, scores, ids, run_s, run_i):
+    """Insert one scored tile into the sorted running top-K.
+
+    The running state (block_b, K_pad) is sorted by (score desc, id asc),
+    ranked on :func:`_order_key`. Every tile id exceeds every id already in
+    the state (item blocks arrive in ascending id order), so a tile
+    candidate enters iff its score ranks STRICTLY above the state's k-th
+    score, and it goes in after every slot ranking ≥ it — the ascending-id
+    tie policy. Each loop round takes every row's best remaining tile
+    candidate (top score, ties to the smaller id), inserts it where it
+    enters by a one-lane shift (``pltpu.roll``), and retires it from the
+    tile; the loop ends once no row has an entrant, so a tile costs as many
+    rounds as its most-improved row gains entries."""
+    slot = jax.lax.broadcasted_iota(jnp.int32, run_s.shape, 1)
+    lo, hi = jnp.iinfo(jnp.int32).min, jnp.iinfo(jnp.int32).max
+
+    def kth(s):                              # (block_b, 1) k-th best key
+        return jnp.min(jnp.where(slot < k, s, hi), axis=1, keepdims=True)
+
+    def best(keys):
+        m = jnp.max(keys, axis=1, keepdims=True)
+        c = jnp.min(jnp.where(keys == m, ids, hi), axis=1, keepdims=True)
+        return m, c
+
+    def body(carry):
+        s, i, keys, m, c, _ = carry
+        enter = m > kth(s)
+        pos = jnp.sum((s >= m).astype(jnp.int32), axis=1, keepdims=True)
+        ins_s = jnp.where(slot < pos, s,
+                          jnp.where(slot == pos, m, pltpu.roll(s, 1, 1)))
+        ins_i = jnp.where(slot < pos, i,
+                          jnp.where(slot == pos, c, pltpu.roll(i, 1, 1)))
+        s = jnp.where(enter, ins_s, s)
+        i = jnp.where(enter, ins_i, i)
+        keys = jnp.where(ids == c, lo, keys)
+        m, c = best(keys)
+        return s, i, keys, m, c, jnp.any(m > kth(s))
+
+    run_k, keys = _order_key(run_s), _order_key(scores)
+    m, c = best(keys)
+    init = (run_k, run_i, keys, m, c, jnp.any(m > kth(run_k)))
+    s, i, *_ = jax.lax.while_loop(lambda carry: carry[-1], body, init)
+    return _from_key(s), i
+
+
+def _topk_kernel(block_items, k, has_scale, excl_kind, *refs):
     """Generic ref unpacker for every (scale?, exclusion-form) variant.
 
     Ref order mirrors the in_specs the wrapper builds: meta, ψ,
@@ -148,7 +216,7 @@ def _topk_kernel(block_items, k_pad, has_scale, excl_kind, *refs):
     excl_ref = next(it) if excl_kind == 1 else None
     exclid_ref = next(it) if excl_kind == 2 else None
     s_ref, i_ref = next(it), next(it)
-    _score_and_merge(block_items, k_pad, meta_ref, psi_ref, phi_ref, s_ref,
+    _score_and_merge(block_items, k, meta_ref, psi_ref, phi_ref, s_ref,
                      i_ref, excl_ref=excl_ref, exclid_ref=exclid_ref,
                      scale_ref=scale_ref)
 
@@ -281,7 +349,7 @@ def topk_score_pallas(
         ))
 
     scores, ids = pl.pallas_call(
-        partial(_topk_kernel, block_items, k_pad, psi_scale is not None,
+        partial(_topk_kernel, block_items, k, psi_scale is not None,
                 excl_kind),
         grid=grid,
         in_specs=in_specs,

@@ -12,6 +12,16 @@ ascending-id order, (−inf, −1) on inadmissible slots):
   arbitrary ``score_fn`` (moved here from ``serve/recsys_serve.py``; the
   serving tier re-exports it): never materializes all scores, so it also
   serves as the huge-catalogue baseline.
+
+Score contract between the kernel and these references: both compute
+every ⟨φ, ψ⟩ in fp32 at :data:`SCORE_PRECISION` (``HIGHEST``: on the TPU
+MXU a plain fp32 dot would round its inputs to bf16, and the kernel and
+XLA need not choose the same pass count). Only the summation order
+differs, so kernel scores agree with :func:`topk_score_ref` within
+:data:`SCORE_RTOL`/:data:`SCORE_ATOL`, and ids agree exactly wherever the
+reference's scores are not tied within that tolerance
+(:func:`topk_mismatches`). Two runs of the kernel itself on the same
+inputs (engine, cluster, mesh, any shard count) stay bit-identical.
 """
 from __future__ import annotations
 
@@ -19,16 +29,23 @@ from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+SCORE_PRECISION = jax.lax.Precision.HIGHEST
+# fp32 reassociation of a D≤1024 dot product stays well inside 1e-5
+# relative to the terms' magnitude (~D·2⁻²⁴ in the worst case).
+SCORE_RTOL = 1e-5
+SCORE_ATOL = 1e-5
 
 
 def exclude_ids_to_mask(exclude_ids, n_items: int):
     """Dense (B, n_items) bool mask from −1-padded per-row global id lists
     (oracle/test helper — the kernel never builds this)."""
     ids = jnp.asarray(exclude_ids, jnp.int32)
-    onehot = (ids[:, :, None] == jnp.arange(n_items, dtype=jnp.int32)) & (
-        ids[:, :, None] >= 0
-    )
-    return onehot.any(axis=1)
+    rows = jnp.broadcast_to(jnp.arange(ids.shape[0])[:, None], ids.shape)
+    cols = jnp.where(ids >= 0, ids, n_items)       # −1 padding → dropped
+    return jnp.zeros((ids.shape[0], n_items), bool).at[rows, cols].set(
+        True, mode="drop")
 
 
 def topk_score_ref(phi, psi, k, exclude_mask=None, *, exclude_ids=None):
@@ -36,7 +53,8 @@ def topk_score_ref(phi, psi, k, exclude_mask=None, *, exclude_ids=None):
     ascending-id order (``lax.top_k`` positional stability over the
     id-ordered row) and (−inf, −1) on slots with no admissible candidate."""
     n_items = psi.shape[0]
-    scores = phi.astype(jnp.float32) @ psi.astype(jnp.float32).T
+    scores = jnp.dot(phi.astype(jnp.float32), psi.astype(jnp.float32).T,
+                     precision=SCORE_PRECISION)
     if exclude_ids is not None:
         assert exclude_mask is None, "pass exclude_mask OR exclude_ids"
         exclude_mask = exclude_ids_to_mask(exclude_ids, n_items)
@@ -48,6 +66,28 @@ def topk_score_ref(phi, psi, k, exclude_mask=None, *, exclude_ids=None):
     top_s, top_i = jax.lax.top_k(scores, k)
     top_i = jnp.where(jnp.isneginf(top_s), -1, top_i).astype(jnp.int32)
     return top_s, top_i
+
+
+def topk_mismatches(scores, ids, ref_scores, ref_ids, *, rtol=SCORE_RTOL,
+                    atol=SCORE_ATOL) -> dict:
+    """Count departures of a top-K result from the reference under the
+    score contract above: ``scores`` outside the tolerance, and ``ids`` that
+    differ at a slot whose reference score is NOT tied (within tolerance)
+    with a neighbouring slot or with the first score below the list — a
+    near-tie may legally rank either way. Returns the two counts."""
+    s, i = np.asarray(scores, np.float64), np.asarray(ids)
+    rs, ri = np.asarray(ref_scores, np.float64), np.asarray(ref_ids)
+    tol = atol + rtol * np.abs(rs)
+    with np.errstate(invalid="ignore"):     # −inf − −inf on empty slots
+        close = (np.isneginf(s) & np.isneginf(rs)) | (np.abs(s - rs) <= tol)
+        gap = rs[:, :-1] - rs[:, 1:]        # slot j vs slot j+1
+        edge = rs[:, -1:] - s[:, -1:]       # the list's last slot
+        tied = np.zeros(rs.shape, bool)
+        tied[:, 1:] |= gap <= 2 * tol[:, 1:]
+        tied[:, :-1] |= gap <= 2 * tol[:, :-1]
+        tied[:, -1:] |= edge <= 2 * tol[:, -1:]
+    return {"score_mismatches": int((~close).sum()),
+            "id_mismatches": int(((i != ri) & ~tied).sum())}
 
 
 def retrieval_topk(

@@ -194,20 +194,32 @@ def topk_block_items(
     fit only has to keep working under the same budget.
 
     ``excl_l_pad`` models the exclude-ID variant: the resident (block_b,
-    L_pad) id tile is FIXED and the in-kernel membership compare adds a
-    (block_b, L_pad) bool column per candidate row.
+    L_pad) id tile and its tile-local copy are FIXED, and the membership
+    walk over the list accumulates a (block_b,) int32 hit column per
+    candidate row (:func:`excl_costs`).
 
     Raises :class:`VmemBudgetError` at large ``block_b·k_pad`` (the fixed
     φ/top-k state alone busts the budget); ``topk_score_pallas`` catches
     it and halves ``block_b``."""
     stored = psi_bytes * d_pad + (4 * d_pad if psi_bytes < 4 else 0)
-    per_row = stored + 16 * block_b + block_b * excl_l_pad
+    excl_fixed, excl_row = excl_costs(block_b, excl_l_pad)
+    per_row = stored + 16 * block_b + excl_row
     if per_row_scale:
         per_row += 4
-    fixed = 4 * (block_b * d_pad + 4 * block_b * k_pad + block_b * excl_l_pad)
+    fixed = 4 * (block_b * d_pad + 4 * block_b * k_pad) + excl_fixed
     return fit_block_rows(
         per_row, fixed_bytes=fixed, n_rows=n_items, multiple=128, lo=128, hi=4096
     )
+
+
+def excl_costs(block_b: int, excl_l_pad: int) -> tuple[int, int]:
+    """(fixed, per ψ row) VMEM bytes of the ``topk_score`` exclude-ID
+    membership test: the (block_b, L_pad) id tile plus its tile-local copy,
+    and a (block_b,) int32 hit column per candidate row. Zero without a
+    list."""
+    if not excl_l_pad:
+        return 0, 0
+    return 2 * 4 * block_b * excl_l_pad, 4 * block_b
 
 
 def psi_row_bytes(d: int, *, psi_bytes: int = 4,
@@ -252,9 +264,10 @@ def cluster_block_items(
     (re-shard coarser, or lower K) instead of silently shrinking the tile
     below one ψ block and overflowing VMEM."""
     merge_scratch = 2 * 4 * block_b * n_shards * k_pad
-    per_row = 4 * (d_pad + 4 * block_b) + block_b * excl_l_pad
+    excl_fixed, excl_row = excl_costs(block_b, excl_l_pad)
+    per_row = 4 * (d_pad + 4 * block_b) + excl_row
     fixed = (
-        4 * (block_b * d_pad + 4 * block_b * k_pad + block_b * excl_l_pad)
+        4 * (block_b * d_pad + 4 * block_b * k_pad) + excl_fixed
         + merge_scratch
     )
     return fit_block_rows(

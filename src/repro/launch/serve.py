@@ -77,6 +77,7 @@ def main():
         )
 
     from repro.core.models import mf
+    from repro.launch.compile_cache import use_compile_cache
     from repro.obs import MetricsRegistry, Tracer, write_metrics, write_trace
     from repro.serve.batcher import MicroBatcher
     from repro.serve.mesh import (
@@ -85,6 +86,7 @@ def main():
         RetryPolicy,
     )
 
+    use_compile_cache()
     # one registry + tracer for the whole serving stack, on the SAME clock
     # as the batcher so queue latencies and span times line up
     registry = MetricsRegistry(clock=time.perf_counter)
@@ -96,7 +98,7 @@ def main():
     mesh = FaultTolerantRetrievalMesh(
         lambda ctx: mf.build_phi(params, ctx),
         n_shards=args.shards, n_replicas=args.replicas, k=k,
-        policy=args.policy, injector=injector,
+        devices=jax.devices(), policy=args.policy, injector=injector,
         # a shard's retries share the batcher's latency bound: a request
         # can burn at most max_delay on backoff before degrading instead
         retry=RetryPolicy(max_attempts=3, deadline=args.max_delay),

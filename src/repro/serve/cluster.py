@@ -380,7 +380,6 @@ def _shard_map_program(mesh, rows_per, n_items, k, block_items, has_eids,
     geometry, k) — ``jax.jit``'s cache keys on function identity, so a
     per-call closure would retrace and recompile on EVERY query; this
     cache makes repeat queries hit the compiled program."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -399,13 +398,8 @@ def _shard_map_program(mesh, rows_per, n_items, k, block_items, has_eids,
     n_in = 2 + bool(has_eids)
     in_specs = (P(axis),) + (P(),) * (n_in - 1)
     out_specs = (P(axis), P(axis))
-    try:
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
-    return jax.jit(fn)
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 class ShardedRetrievalCluster:

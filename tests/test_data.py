@@ -201,3 +201,26 @@ else:
     @pytest.mark.parametrize("seed,n", [(0, 1), (1, 5), (2, 12)])
     def test_design_matmul_matches_dense(seed, n):
         _design_matmul_case(seed, n)
+
+
+def test_powerlaw_interactions_distinct_exact_and_seeded():
+    """The deployment-scale generator: exactly nnz distinct (user, item)
+    pairs, sorted, degrees in [1, cap] with a heavy tail, Zipf-skewed item
+    popularity, and the same pairs for the same seed."""
+    from repro.data.synthetic import MAX_DEGREE_SHARE, make_powerlaw_interactions
+
+    n_users, n_items, nnz = 3000, 2000, 90_000
+    u, i = make_powerlaw_interactions(n_users, n_items, nnz, seed=5)
+    keys = u.astype(np.int64) * n_items + i
+    assert len(u) == nnz and np.all(np.diff(keys) > 0)   # sorted, distinct
+    assert u.min() >= 0 and u.max() < n_users and i.max() < n_items
+    deg = np.bincount(u, minlength=n_users)
+    assert deg.min() >= 1 and deg.max() <= MAX_DEGREE_SHARE * n_items
+    assert deg.max() > 4 * np.median(deg)                # power-law tail
+    pop = np.sort(np.bincount(i, minlength=n_items))[::-1]
+    assert pop[0] > 20 * np.median(pop)                  # Zipf head
+    u2, i2 = make_powerlaw_interactions(n_users, n_items, nnz, seed=5)
+    np.testing.assert_array_equal(u, u2)
+    np.testing.assert_array_equal(i, i2)
+    with pytest.raises(ValueError):
+        make_powerlaw_interactions(10, 100, 10 * 6, seed=0)  # > cap of 5

@@ -23,7 +23,8 @@ SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
+    from jax.sharding import AxisType
     from functools import partial
     import sys
     sys.path.insert(0, "src")
@@ -32,7 +33,7 @@ SCRIPT = textwrap.dedent(
 
     # ---- 1. sharded gram == global gram ---------------------------------
     from repro.core.gram import gram, sharded_gram
-    mesh = jax.make_mesh((8,), ("rows",))
+    mesh = jax.make_mesh((8,), ("rows",), axis_types=(AxisType.Auto,))
     m = jax.random.normal(jax.random.PRNGKey(0), (64, 6))
     f = shard_map(partial(sharded_gram, axis_name="rows"), mesh=mesh,
                   in_specs=P("rows", None), out_specs=P())
@@ -53,7 +54,8 @@ SCRIPT = textwrap.dedent(
     e = mf.residuals(params, data)
     ref_p, ref_e = mf.epoch(params, data, e, hp)
 
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = jax.make_mesh((4, 2), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2)
     dsh = lambda spec: NamedSharding(mesh2, spec)
     p_sh = mf.MFParams(w=dsh(P("data", None)), h=dsh(P("model", None)))
     import dataclasses

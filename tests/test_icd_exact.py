@@ -79,3 +79,37 @@ def test_damped_step_also_converges():
     for _ in range(8):
         params, e = mf.epoch(params, data, e, hp)
     assert float(mf.objective(params, data, hp)) < start
+
+
+def test_fit_compiles_one_epoch_program():
+    """Unscheduled epochs pass the same static sweep index, so a 3-epoch
+    fit traces and compiles the epoch once."""
+    rng = np.random.default_rng(3)
+    n_ctx, n_items, nnz = 9, 7, 30
+    cells = rng.choice(n_ctx * n_items, nnz, replace=False)
+    data = build_interactions(cells // n_items, cells % n_items,
+                              np.ones(nnz), np.full(nnz, 2.0), n_ctx, n_items,
+                              alpha0=1.0)
+    hp = mf.MFHyperParams(k=3, alpha0=1.0, l2=0.1)
+    params = mf.init(jax.random.PRNGKey(0), n_ctx, n_items, 3)
+    before = mf.epoch._cache_size()
+    mf.fit(params, data, hp, 3)
+    assert mf.epoch._cache_size() - before == 1
+
+
+def test_residuals_batched_gather_matches_direct():
+    """Residuals run in fixed-size pair batches (full-width gathers would
+    not fit one chip); a count that is not a batch multiple must still give
+    the direct Σ φ·ψ − ȳ."""
+    from repro.core import sweeps
+
+    rng = np.random.default_rng(4)
+    nnz = sweeps._RESID_BATCH + 123
+    phi = jnp.asarray(rng.normal(size=(50, 4)), jnp.float32)
+    psi = jnp.asarray(rng.normal(size=(40, 4)), jnp.float32)
+    ctx = jnp.asarray(rng.integers(0, 50, nnz), jnp.int32)
+    item = jnp.asarray(rng.integers(0, 40, nnz), jnp.int32)
+    y = jnp.asarray(rng.normal(size=nnz), jnp.float32)
+    got = sweeps.residuals_from_factors(phi, psi, ctx, item, y)
+    want = np.sum(np.asarray(phi)[ctx] * np.asarray(psi)[item], axis=1) - y
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)

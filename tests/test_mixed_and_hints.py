@@ -46,7 +46,9 @@ def test_hints_noop_without_context():
 
 
 def test_hints_apply_inside_mesh():
-    mesh = jax.make_mesh((1,), ("model",))
+    # sharding hints are GSPMD constraints: the mesh axes must be Auto
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
     def f(x):
         return constrain(x, ("expert", None)) * 2

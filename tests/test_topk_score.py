@@ -8,6 +8,7 @@ import pytest
 from _zoo import ZOO, model_phi_psi, _rand
 
 from repro.kernels.topk_score import topk_merge_shards, topk_score, topk_score_ref
+from repro.kernels.topk_score.ref import SCORE_ATOL, SCORE_RTOL
 from repro.serve.engine import (
     RetrievalEngine,
     exclude_ids_from_lists,
@@ -92,7 +93,9 @@ def test_id_offset_and_n_valid_shard_semantics():
     rs, ri = topk_score_ref(phi, psi[40:], 30)
     ri_global = np.where(np.asarray(ri) >= 0, np.asarray(ri) + 40, -1)
     np.testing.assert_array_equal(np.asarray(i), ri_global)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(rs), rtol=1e-6)
+    # kernel vs reference: the stated fp32 score contract (ref.py)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(rs),
+                               rtol=SCORE_RTOL, atol=SCORE_ATOL)
     # pad rows (global id >= 64) never surface
     assert (np.asarray(i) < 64).all()
     # traced offsets hit the same jit cache (one program serves all shards)
@@ -163,3 +166,48 @@ def test_streaming_matches_dense_topk_all_models(name):
     s3, i3 = engine.topk(exclude_ids=exclude_ids_from_lists(excl_lists))
     np.testing.assert_array_equal(np.asarray(i3), np.asarray(i2))
     np.testing.assert_array_equal(np.asarray(s3), np.asarray(s2))
+
+
+def test_nan_scores_rank_like_dense_top_k():
+    """A NaN ψ row ranks first, as in lax.top_k's total order — so a bad
+    table surfaces as non-finite scores instead of vanishing."""
+    phi, psi = _rand((4, 8), 30), _rand((150, 8), 31)
+    psi = psi.at[77].set(jnp.nan)
+    s, i = topk_score(phi, psi, 10, block_items=128)
+    rs, ri = topk_score_ref(phi, psi, 10)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
+    assert (np.asarray(i)[:, 0] == 77).all()
+    assert np.isnan(np.asarray(s)[:, 0]).all()
+
+
+def test_exclude_ids_wider_than_one_lane_tile():
+    """Exclude lists longer than 128 ids (several lane tiles of the list)
+    agree with the dense-mask oracle."""
+    rng = np.random.default_rng(32)
+    phi, psi = _rand((6, 16), 33), _rand((700, 16), 34)
+    lists = [rng.choice(700, size=int(n), replace=False)
+             for n in rng.integers(150, 300, size=6)]
+    eids = exclude_ids_from_lists(lists)
+    assert eids.shape[1] > 128
+    s, i = topk_score(phi, psi, 20, exclude_ids=eids, block_items=128)
+    rs, ri = topk_score_ref(phi, psi, 20, exclude_ids=eids)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
+    np.testing.assert_allclose(np.asarray(s), np.asarray(rs),
+                               rtol=SCORE_RTOL, atol=SCORE_ATOL)
+
+
+def test_topk_mismatches_forgives_only_near_ties():
+    from repro.kernels.topk_score.ref import topk_mismatches
+
+    ref_s = np.asarray([[3.0, 2.0, 2.0 + 1e-7, -np.inf]], np.float32)
+    ref_i = np.asarray([[5, 9, 4, -1]], np.int32)
+    # a near-tie ranked the other way, within the score tolerance: clean
+    got_i = np.asarray([[5, 4, 9, -1]], np.int32)
+    assert topk_mismatches(ref_s, got_i, ref_s, ref_i) == {
+        "score_mismatches": 0, "id_mismatches": 0}
+    # a clear winner swapped out, and a score off by 1e-3: both counted
+    bad_s = ref_s.copy()
+    bad_s[0, 0] += 1e-3
+    got_i = np.asarray([[7, 9, 4, -1]], np.int32)
+    assert topk_mismatches(bad_s, got_i, ref_s, ref_i) == {
+        "score_mismatches": 1, "id_mismatches": 1}

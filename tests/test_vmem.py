@@ -118,8 +118,8 @@ def test_topk_block_items_overflow_raises():
 
 
 def test_topk_block_items_exclude_id_tile_charged():
-    """The exclude-ID variant's resident (block_b, L_pad) tile and per-row
-    membership compare must shrink the ψ tile, not ride for free."""
+    """The exclude-ID variant's resident (block_b, L_pad) tiles and per-row
+    hit column must shrink the ψ tile, not ride for free."""
     free = vmem.topk_block_items(block_b=128, d_pad=128, k_pad=128)
     with_ids = vmem.topk_block_items(block_b=128, d_pad=128, k_pad=128,
                                      excl_l_pad=256)
@@ -128,7 +128,7 @@ def test_topk_block_items_exclude_id_tile_charged():
         # a pathologically wide exclude list busts even the minimal tile
         # (the kernel wrapper's block_b-halving loop is the way out)
         vmem.topk_block_items(block_b=128, d_pad=128, k_pad=128,
-                              excl_l_pad=2048)
+                              excl_l_pad=8192)
 
 
 def test_cluster_block_items_merge_scratch_is_fixed_cost():
