@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_PRECISION = jax.lax.Precision.HIGHEST  # fp32-exact, as core.gram.PRECISION
+from repro.core.gram import PRECISION
 
 
 def _gram_kernel(x_ref, o_ref):
@@ -32,7 +32,7 @@ def _gram_kernel(x_ref, o_ref):
 
     x = x_ref[...].astype(jnp.float32)
     o_ref[...] += jax.lax.dot_general(
-        x, x, (((0,), (0,)), ((), ())), precision=_PRECISION,
+        x, x, (((0,), (0,)), ((), ())), precision=PRECISION,
         preferred_element_type=jnp.float32,
     )
 
@@ -45,7 +45,7 @@ def _gram_weighted_kernel(x_ref, w_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
     wx = x * w_ref[:, 0:1].astype(jnp.float32)  # (block_rows, 1) broadcast
     o_ref[...] += jax.lax.dot_general(
-        x, wx, (((0,), (0,)), ((), ())), precision=_PRECISION,
+        x, wx, (((0,), (0,)), ((), ())), precision=PRECISION,
         preferred_element_type=jnp.float32,
     )
 
