@@ -1,0 +1,4 @@
+"""Chip benchmark: one cell (configuration x traffic mix) per run.
+
+``python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>``
+"""
