@@ -28,9 +28,8 @@ Tracks ``BENCH_topk_score.json`` at the repo root:
     bit-identical to exact, recall@K >= 0.95 at >= 4x analytic byte
     reduction on the probe sweep, int8-per-row-scale ψ within 5% relative
     score error and >= 3x rows per HBM shard;
-  * HARD observability asserts (``repro.obs``) — the kernel cost counters
-    recorded at dispatch sites reproduce the ``kernels/vmem.py`` byte
-    model exactly, instrumented-vs-bare overhead < 3%, and one batched
+  * HARD observability asserts (``repro.obs``) — instrumented-vs-bare
+    overhead < 3%, and one batched
     request under an injected replica kill exports a single
     ticket-correlated trace (request → queue → flush → dispatch →
     failover → merge) without changing a bit of the results.
@@ -581,11 +580,6 @@ def _eval_harness_parity(quick: bool) -> dict:
 def _obs_bench(quick: bool) -> dict:
     """Observability acceptance gates (repro.obs), all HARD asserts:
 
-      * ``obs_cost_model_ok`` — the kernel cost counters recorded at the
-        engine dispatch site reproduce the ``kernels/vmem.py`` analytic
-        byte model EXACTLY on the benched shapes (same closed form this
-        bench has always priced with: ψ stream at ``psi_row_bytes`` + φ +
-        2·(B, K_pad) result blocks);
       * ``obs_overhead_ok`` — instrumented (live registry + tracer) vs
         bare (NULL_REGISTRY, no tracer) wall time over the same
         batcher→mesh traffic stays within 3% (median of interleaved
@@ -597,9 +591,7 @@ def _obs_bench(quick: bool) -> dict:
         the bare run).
     """
     from repro.obs import MetricsRegistry, Tracer, trace_for_ticket
-    from repro.obs.costs import topk_score_cost
     from repro.obs.metrics import NULL_REGISTRY
-    from repro.kernels.vmem import psi_row_bytes
     from repro.serve.batcher import MicroBatcher
     from repro.serve.engine import RetrievalEngine
     from repro.serve.mesh import (
@@ -612,31 +604,6 @@ def _obs_bench(quick: bool) -> dict:
     b, n_items, d, kk = (8, 96, 16, 10) if quick else (32, 2048, 32, 100)
     phi = jnp.asarray(rng.normal(size=(b, d)), jnp.float32)
     psi = jnp.asarray(rng.normal(size=(n_items, d)), jnp.float32)
-
-    # --- cost-counter parity vs the vmem byte model ----------------------
-    reg = MetricsRegistry()
-    engine = RetrievalEngine(psi, lambda p=phi: p, k=kk, block_items=32,
-                             registry=reg)
-    n_calls = 3
-    for _ in range(n_calls):
-        engine.topk_phi(phi)
-    counted_calls = reg.get("kernel_calls_total", kernel="topk_score")
-    counted_bytes = reg.get("kernel_hbm_bytes_total", kernel="topk_score")
-    model = topk_score_cost(b, n_items, d, kk)
-    # the same closed form, recomputed inline from kernels/vmem.py
-    k_pad = -(-kk // 128) * 128
-    inline = (n_items * psi_row_bytes(d) + 4.0 * b * d
-              + 2 * 4.0 * b * k_pad)
-    if not (counted_calls == n_calls
-            and counted_bytes == n_calls * model["hbm_bytes"]
-            and model["hbm_bytes"] == inline):
-        raise AssertionError(
-            "serve bench FAILED: kernel cost counters diverge from the "
-            f"vmem byte model — counted {counted_bytes} over "
-            f"{counted_calls} calls, model {model['hbm_bytes']}/call, "
-            f"inline {inline}/call"
-        )
-    obs_cost_model_ok = True
 
     # --- overhead gate: instrumented vs bare, same traffic ---------------
     # sized so the measurement is kernel-bound (production-shaped ψ, small
@@ -772,15 +739,8 @@ def _obs_bench(quick: bool) -> dict:
         )
     obs_trace_ok = True
     return {
-        "obs_cost_model_ok": obs_cost_model_ok,
         "obs_overhead_ok": obs_overhead_ok,
         "obs_trace_ok": obs_trace_ok,
-        "cost_parity": {
-            "shape": dict(b=b, n_items=n_items, d=d, k=kk),
-            "counted_calls": int(counted_calls),
-            "counted_hbm_bytes": float(counted_bytes),
-            "model_hbm_bytes_per_call": float(model["hbm_bytes"]),
-        },
         "overhead": {
             "bare_s": float(bare_s),
             "instrumented_s": float(instr_s),
@@ -900,7 +860,6 @@ def serve_topk_bench(quick: bool = True, out_path: Optional[str] = None) -> dict
             "ann_recall_floor": ann["ann_recall_floor"],
             "quant_parity": ann["quant_parity"],
             "int8_capacity_x": ann["int8_capacity_x"],
-            "obs_cost_model_ok": obs["obs_cost_model_ok"],
             "obs_overhead_ok": obs["obs_overhead_ok"],
             "obs_trace_ok": obs["obs_trace_ok"],
             "target":">= 2x fewer HBM bytes per retrieval batch at B >= 256 "
@@ -918,8 +877,7 @@ def serve_topk_bench(quick: bool = True, out_path: Optional[str] = None) -> dict
                       "n_probe=n_clusters bit-identical to exact, recall@K "
                       ">= 0.95 at >= 4x analytic byte reduction, int8 ψ "
                       "scores within 5% relative + >= 3x rows per shard; "
-                      "observability: kernel cost counters == the vmem "
-                      "byte model, instrumented vs bare < 3% overhead, "
+                      "observability: instrumented vs bare < 3% overhead, "
                       "one ticket-correlated trace through an injected "
                       "kill (request/queue/flush/dispatch/failover/merge) "
                       "with bit-invisible instrumentation",
@@ -937,7 +895,6 @@ def serve_topk_bench(quick: bool = True, out_path: Optional[str] = None) -> dict
                    and ann["ann_recall_floor"]
                    and ann["quant_parity"]
                    and ann["int8_capacity_x"] >= 3.0
-                   and obs["obs_cost_model_ok"]
                    and obs["obs_overhead_ok"]
                    and obs["obs_trace_ok"],
         },

@@ -4,13 +4,13 @@ faults -> export metrics (JSONL + Prometheus text) and a Perfetto trace.
 One registry and one tracer (``repro.obs``) thread through every layer:
 
   * training — ``fit_metrics_callback`` records epoch wall time, the loss
-    trajectory, SweepSchedule block visits, and the analytic cd_sweep
-    kernel cost, composed with a ``PsiPublisher`` that snapshots ψ into
+    trajectory and SweepSchedule block visits, composed with a
+    ``PsiPublisher`` that snapshots ψ into
     the live mesh at each epoch boundary;
   * serving — the ``MicroBatcher`` and ``FaultTolerantRetrievalMesh``
     share the registry (queue depth, flush reasons, cache hits, dispatch/
-    failover/retry counters, per-replica latency histograms, kernel HBM/
-    FLOP cost counters) and the tracer, so one batched request under an
+    failover/retry counters, per-replica latency histograms) and the
+    tracer, so one batched request under an
     injected replica kill exports as a single correlated trace:
     submit -> queue -> flush -> dispatch -> failover -> merge;
   * export — ``results/obs/metrics.jsonl``, ``metrics.prom``, and
@@ -77,12 +77,10 @@ def main():
     schedule = SweepSchedule(kind="rotating", block=k_b)
     publisher = PsiPublisher(mesh, model.export_psi, every=1,
                              registry=registry)
-    d_pad = -(-n_items // 128) * 128
     cb = compose_callbacks(
         fit_metrics_callback(
             registry=registry, objective=model.objective,
             schedule=schedule, n_dims=k, block=k_b,
-            cd_shape=(n_users, d_pad, k),
         ),
         publisher,
     )

@@ -107,27 +107,42 @@ def _side_sweep(
     """One dimension sweep over one side; returns (new_side, new_e).
 
     With a ``schedule`` the sweep covers only the scheduled subspace blocks
-    for this ``sweep_index`` (iALS++-style); ``None`` is a full pass."""
+    for this ``sweep_index`` (iALS++-style); ``None`` is a full pass.
+
+    Each phase of a column update runs under a named scope — ``icd.gather``
+    (the other side's column through the pair layout), ``icd.segsum`` (the
+    L'/L'' segment sums), ``icd.implicit`` (the R' product with the Gram),
+    ``icd.newton`` (the step and the column write), ``icd.patch`` (the
+    residual patch) — which the op metadata, and so a profiler trace of
+    the compiled step, carries."""
 
     def body(f, carry):
         side_m, e = carry
-        o_col = other_cols_nnz(f)                      # (nnz,)
-        s_col = sweeps.take_col(side_m, f)             # (n,)
+        with jax.named_scope("icd.gather"):
+            o_col = other_cols_nnz(f)                  # (nnz,)
+        with jax.named_scope("icd.newton"):
+            s_col = sweeps.take_col(side_m, f)         # (n,)
         # explicit parts (L'/2, L''/2) from the residual cache
-        lp = segment_sum(alpha * e * o_col, rows_nnz, n_rows)
-        lpp = segment_sum(alpha * o_col * o_col, rows_nnz, n_rows)
+        with jax.named_scope("icd.segsum"):
+            lp = segment_sum(alpha * e * o_col, rows_nnz, n_rows)
+            lpp = segment_sum(alpha * o_col * o_col, rows_nnz, n_rows)
         # implicit parts (R'/2, R''/2) via the opposite Gram — Lemma 3
-        rp = jnp.dot(side_m, sweeps.take_col(other_j, f),  # Σ_f' J(f',f)·w_{·,f'}
-                     precision=gram_precision)
-        rpp = other_j[f, f]
-        delta = sweeps.newton_delta(
-            sweeps.NewtonParts(lp + hp.alpha0 * rp, lpp + hp.alpha0 * rpp),
-            s_col,
-            hp.l2,
-            hp.eta,
-        )
-        e = e + jnp.take(delta, rows_nnz) * o_col      # rank-1 residual patch
-        return sweeps.put_col(side_m, f, s_col + delta), e
+        with jax.named_scope("icd.implicit"):
+            rp = jnp.dot(side_m, sweeps.take_col(other_j, f),  # Σ_f' J(f',f)·w_{·,f'}
+                         precision=gram_precision)
+            rpp = other_j[f, f]
+        with jax.named_scope("icd.newton"):
+            delta = sweeps.newton_delta(
+                sweeps.NewtonParts(lp + hp.alpha0 * rp, lpp + hp.alpha0 * rpp),
+                s_col,
+                hp.l2,
+                hp.eta,
+            )
+        with jax.named_scope("icd.patch"):
+            e = e + jnp.take(delta, rows_nnz) * o_col  # rank-1 residual patch
+        with jax.named_scope("icd.newton"):
+            side_m = sweeps.put_col(side_m, f, s_col + delta)
+        return side_m, e
 
     return sweeps.sweep_columns(
         side.shape[1], body, (side, e), unroll=hp.unroll,
@@ -165,7 +180,8 @@ def epoch(
     w, h = params
 
     # --- context side: J_I from the fixed item factors -------------------
-    j_i = gram(h, implementation=hp.implementation)
+    with jax.named_scope("icd.gram"):
+        j_i = gram(h, implementation=hp.implementation)
     h_cols = lambda f: jnp.take(sweeps.take_col(h, f), data.item)
     w, e = _side_sweep(
         w, j_i, h_cols, data.ctx, data.alpha, e, data.n_ctx, hp,
@@ -173,7 +189,8 @@ def epoch(
     )
 
     # --- item side: J_C from the (just-updated) context factors ----------
-    j_c = gram(w, implementation=hp.implementation)
+    with jax.named_scope("icd.gram"):
+        j_c = gram(w, implementation=hp.implementation)
     e_t = sweeps.to_item_major(e, data.t_perm)
     alpha_t = sweeps.to_item_major(data.alpha, data.t_perm)
     w_cols = lambda f: jnp.take(sweeps.take_col(w, f), data.t_ctx)

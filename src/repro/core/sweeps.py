@@ -269,10 +269,13 @@ def residuals_from_factors(
 
 
 def to_item_major(e_ctx_major: jax.Array, t_perm: jax.Array) -> jax.Array:
-    """Permute a per-nnz vector from context-major to item-major order."""
-    return jnp.take(e_ctx_major, t_perm)
+    """Permute a per-nnz vector from context-major to item-major order
+    (named scope ``icd.permute``, as is the inverse)."""
+    with jax.named_scope("icd.permute"):
+        return jnp.take(e_ctx_major, t_perm)
 
 
 def to_ctx_major(e_item_major: jax.Array, t_perm: jax.Array) -> jax.Array:
     """Inverse permutation of :func:`to_item_major`."""
-    return jnp.zeros_like(e_item_major).at[t_perm].set(e_item_major)
+    with jax.named_scope("icd.permute"):
+        return jnp.zeros_like(e_item_major).at[t_perm].set(e_item_major)

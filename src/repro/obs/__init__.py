@@ -1,13 +1,12 @@
-"""Observability spine: metrics, request tracing, kernel cost accounting.
+"""Observability spine: metrics and request tracing.
 
   metrics.py  label-aware Counter/Gauge/Histogram registry (injectable
               clock, per-instance labels on a process-global default,
               NULL_REGISTRY bare mode, StatsView back-compat mapping)
   trace.py    spans (context-manager + explicit begin/end), parent/child
-              links, batcher-ticket correlation
+              links, batcher-ticket correlation; each span is also a
+              ``repro.<name>`` annotation in a running profiler trace
   export.py   JSONL + Prometheus text exposition; Chrome-trace JSON
-  costs.py    dispatch-site shim over the kernels/vmem.py analytic cost
-              models (HBM bytes / FLOPs / VMEM per kernel dispatch)
   train.py    fit-callback metrics for the training spine (epoch wall
               time, loss trajectory, SweepSchedule block visits)
 
@@ -18,7 +17,6 @@ benches (instrumented-vs-bare overhead hard-gated < 3%), and
 Perfetto trace). See ``serve/README.md`` § "Metrics & tracing" for the
 metric catalogue and label conventions.
 """
-from repro.obs.costs import KernelCostRecorder, cd_sweep_cost, topk_score_cost
 from repro.obs.export import (
     chrome_trace,
     metrics_jsonl,
@@ -41,13 +39,11 @@ from repro.obs.train import compose_callbacks, fit_metrics_callback
 
 __all__ = [
     "DEFAULT_BUCKETS",
-    "KernelCostRecorder",
     "MetricsRegistry",
     "NULL_REGISTRY",
     "Span",
     "StatsView",
     "Tracer",
-    "cd_sweep_cost",
     "chrome_trace",
     "compose_callbacks",
     "default_registry",
@@ -57,7 +53,6 @@ __all__ = [
     "prometheus_text",
     "resolve_registry",
     "set_default_registry",
-    "topk_score_cost",
     "trace_for_ticket",
     "write_metrics",
     "write_trace",

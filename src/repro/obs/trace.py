@@ -36,6 +36,14 @@ injectable clock so tests drive it under simulated time; tracing is
 OPT-IN per component (``tracer=None`` skips every span) and never
 touches result values — instrumentation bit-identity is pinned in
 ``tests/test_obs.py``.
+
+Profiler clock: every span also opens a ``jax.profiler.TraceAnnotation``
+named ``repro.<name>`` (its begin attributes as the event's stats), and
+closes it when the span ends. While a profiler trace runs, the spans land
+in it on the same clock as the device's operations, so an idle gap on the
+device can be put down to the span the host was in. Explicit begin/end
+need not nest: each span owns its own annotation object. With no trace
+running an annotation records nothing.
 """
 from __future__ import annotations
 
@@ -43,11 +51,16 @@ import time
 from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+PROFILER_PREFIX = "repro."   # name prefix of the spans in a profiler trace
+
 
 class Span:
     """One timed operation. ``t1 is None`` while still open."""
 
-    __slots__ = ("span_id", "parent_id", "name", "t0", "t1", "attrs")
+    __slots__ = ("span_id", "parent_id", "name", "t0", "t1", "attrs",
+                 "_annotation")
 
     def __init__(self, span_id: int, parent_id: Optional[int], name: str,
                  t0: float, attrs: Dict[str, object]):
@@ -57,6 +70,7 @@ class Span:
         self.t0 = t0
         self.t1: Optional[float] = None
         self.attrs = attrs
+        self._annotation = None   # the open profiler annotation
 
     @property
     def duration(self) -> float:
@@ -99,10 +113,15 @@ class Tracer:
         )
         self._next_id += 1
         self.spans.append(sp)
+        sp._annotation = TraceAnnotation(PROFILER_PREFIX + name, **attrs)
+        sp._annotation.__enter__()
         return sp
 
     def end(self, span: Span, **attrs) -> Span:
         span.t1 = self.clock()
+        if span._annotation is not None:
+            span._annotation.__exit__(None, None, None)
+            span._annotation = None
         if attrs:
             span.attrs.update(attrs)
         return span

@@ -18,12 +18,8 @@ that is where the registry gets fed:
   * ``train_block_seconds_est``    histogram: epoch time / blocks visited
                                    — an ESTIMATE of per-k_b-block cost
                                    (jit hides true per-block times; the
-                                   analytic cd_sweep cost below carries
-                                   the modelled split)
-  * ``kernel_*_total{kernel="cd_sweep"}`` — the analytic cost model
-                                   (``obs/costs.py``) recorded per epoch
-                                   when ``cd_shape=(C, D_pad, k)`` is
-                                   given: 2 sides × the fused sweep bytes
+                                   step's ``icd.*`` named scopes split
+                                   its device time in a profiler trace)
 
 Compose with the existing eval hook::
 
@@ -36,9 +32,8 @@ Compose with the existing eval hook::
 """
 from __future__ import annotations
 
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence
 
-from repro.obs.costs import KernelCostRecorder
 from repro.obs.metrics import next_instance_id, resolve_registry
 
 # epoch timing buckets: interpret-mode epochs run ~ms..minutes
@@ -68,7 +63,6 @@ def fit_metrics_callback(
     schedule=None,
     n_dims: Optional[int] = None,
     block: int = 1,
-    cd_shape: Optional[Tuple[int, int, int]] = None,
     sides: int = 2,
     labels: Optional[dict] = None,
 ) -> Callable:
@@ -77,9 +71,8 @@ def fit_metrics_callback(
     ``schedule``+``n_dims``+``block`` resolve each epoch's block plan via
     ``SweepSchedule.blocks`` (a pure host-side function of the epoch
     index — the same static plan the jitted epoch traced), feeding the
-    block-visit counters. ``cd_shape=(C, D_pad, k)`` opts into the
-    analytic cd_sweep cost accounting (``sides`` sweeps per epoch — 2
-    for two-sided models like MF). ``objective(params) -> loss`` records
+    block-visit counters; ``sides`` is the sweeps per epoch (2 for
+    two-sided models like MF). ``objective(params) -> loss`` records
     the loss trajectory. The callback exposes ``history`` —
     ``[(epoch, seconds, loss | None), ...]``."""
     reg = resolve_registry(registry)
@@ -102,7 +95,6 @@ def fit_metrics_callback(
         "train_block_visits_total",
         "SweepSchedule k_b-block visits by starting dim f0 (one side)",
         labels=lnames + ("f0",))
-    costs = KernelCostRecorder(reg)
     state = {"t": clk()}
 
     def callback(epoch: int, params) -> None:
@@ -123,10 +115,6 @@ def fit_metrics_callback(
             visits_f.labels(**inst, f0=str(f0)).inc()
         if plan:
             block_h.observe(dt / (sides * len(plan)))
-        if cd_shape is not None:
-            c_rows, d_pad, k = cd_shape
-            costs.record_cd_sweep(
-                c_rows, d_pad, k, max(block, 1), sweeps=sides)
         loss = None
         if objective is not None:
             loss = float(objective(params))

@@ -263,8 +263,7 @@ class PsiIndex:
         (the bit-exact oracle path).
 
         ``registry`` (an ``obs.metrics`` registry) opts into query/probe
-        counters and per-block kernel cost accounting at the stored quant
-        width. Unlike the serving components, ``None`` here means NO
+        counters. Unlike the serving components, ``None`` here means NO
         recording — a hot library function must not reach for process
         globals behind its caller's back (the engine/mesh thread their own
         registries through)."""
@@ -272,13 +271,9 @@ class PsiIndex:
         b = int(phi_rows.shape[0])
         c = self.n_clusters
         n_probe = self.cfg.resolve_probe(c) if n_probe is None else n_probe
-        costs = None
         if registry is not None and registry:   # NULL_REGISTRY is falsy
-            from repro.obs.costs import KernelCostRecorder
-
             registry.counter(
                 "ann_queries_total", "PsiIndex.topk dispatches").inc()
-            costs = KernelCostRecorder(registry)
         if n_probe >= c:
             probe_mask = np.ones((b, c), bool)       # oracle: prune nothing
         else:
@@ -287,8 +282,6 @@ class PsiIndex:
             probe_mask = np.zeros((b, c), bool)
             np.put_along_axis(probe_mask, sel, True, axis=1)
         excl_pos = self._map_exclude(exclude_ids)
-        excl_l = 0 if excl_pos is None else int(excl_pos.shape[1])
-        psi_bytes = {"none": 4, "bf16": 2, "int8": 1}[self.cfg.quant]
         probed = 0
         parts_s, parts_i = [], []
         for cl in np.nonzero(probe_mask.any(axis=0))[0]:
@@ -304,12 +297,6 @@ class PsiIndex:
                 block_items=block_items, interpret=interpret,
             )
             probed += 1
-            if costs is not None:
-                costs.record_topk(
-                    b, self.block_rows, self.d, k,
-                    kernel="topk_score_ivf", psi_bytes=psi_bytes,
-                    per_row_scale=self.cfg.quant == "int8", excl_l=excl_l,
-                )
             mask = jnp.asarray(probe_mask[:, cl])
             ss = jnp.where(mask[:, None], ss, -jnp.inf)
             ii = jnp.where(mask[:, None], ii, -1)

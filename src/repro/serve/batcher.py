@@ -58,6 +58,7 @@ batch per kernel dispatch:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import OrderedDict
@@ -70,6 +71,12 @@ from repro.obs.metrics import StatsView, next_instance_id, resolve_registry
 from repro.serve.cluster import TopKResult
 
 _FLUSH_REASONS = ("size", "deadline", "forced", "drained")
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_span(name: str):
+    """The flush's phase wrapper when tracing is off: records nothing."""
+    return _NO_SPAN
 
 
 @dataclasses.dataclass
@@ -137,7 +144,8 @@ class MicroBatcher:
         # pre-registry caller (tests, benches, drivers) keeps working.
         # ``registry=None`` → the process default (per-instance labels
         # keep two batchers' counters apart); NULL_REGISTRY → bare mode.
-        # ``tracer`` (obs/trace.py) opts into per-request spans.
+        # ``tracer`` (obs/trace.py) opts into per-request spans and the
+        # flush's phase spans.
         self.registry = resolve_registry(registry)
         self.tracer = tracer
         self._spans: Dict[int, tuple] = {}   # ticket -> (request, queue) spans
@@ -315,57 +323,73 @@ class MicroBatcher:
         self._m_queue_depth.set(len(self._queue))
         b = len(batch)
         b_pad = -(-b // self.pad_to) * self.pad_to
-        phi = np.zeros((b_pad, batch[0].phi_row.shape[0]), np.float32)
-        for r, req in enumerate(batch):
-            phi[r] = req.phi_row
-        excl_ids = None
-        l_max = max((req.exclude.shape[0] for req in batch
-                     if req.exclude is not None), default=0)
-        if l_max > 0:
-            excl_ids = np.full((b_pad, l_max), -1, np.int32)
-            for r, req in enumerate(batch):
-                if req.exclude is not None:
-                    excl_ids[r, : req.exclude.shape[0]] = req.exclude
-            excl_ids = jnp.asarray(excl_ids)
-        fs = None
-        if self.tracer is not None:
+        if self.tracer is None:
+            self._serve(batch, b_pad, now, _no_span, None)
+        else:
             # explicit begin/end (not a context manager): _flush is
             # non-reentrant via the trailing step() and the span must
             # close before that follow-up flush opens its own
             fs = self.tracer.begin("flush", parent=None, reason=reason,
                                    batch=b, batch_padded=b_pad)
-            with self.tracer.activate(fs):   # mesh spans nest under it
-                res = self.topk_phi(jnp.asarray(phi), excl_ids)
-        else:
-            res = self.topk_phi(jnp.asarray(phi), excl_ids)
-        scores, ids = res  # TopKResult or a bare (scores, ids) tuple
-        coverage = float(getattr(res, "coverage", 1.0))
-        dead_ranges = tuple(getattr(res, "dead_ranges", ()))
-        scores = np.asarray(scores)
-        ids = np.asarray(ids)
-        if coverage < 1.0:
-            self._m_degraded.inc(len(batch))
-        for r, req in enumerate(batch):  # route rows back to their tickets
-            out = TopKResult(scores[r], ids[r], coverage, dead_ranges)
-            self._results[req.ticket] = out
-            self._completed_at[req.ticket] = now
-            self._m_queue_lat.observe(now - req.t_submit)
-            spans = self._spans.pop(req.ticket, None)
-            if spans is not None:
-                rq, qs = spans
-                self.tracer.end(qs)
-                self.tracer.end(rq, flush_span=fs.span_id,
-                                coverage=coverage)
-            # degraded answers are never cached: the hole they carry must
-            # not outlive the replica failure that caused it
-            if req.key is not None and coverage == 1.0:
-                self._cache_put(self._cache_key(req.key, req.exclude), out)
-        if fs is not None:
+            with self.tracer.activate(fs):   # phase and mesh spans nest
+                coverage = self._serve(batch, b_pad, now, self.tracer.span,
+                                       fs)
             self.tracer.end(fs, coverage=coverage)
         self._m_flushed_rows.inc(b)
         self._m_flush[reason].inc()
         if self._queue:  # drain backlog left by a size-capped flush
             self.step(now)
+
+    def _serve(self, batch: List[_Pending], b_pad: int, now: float, phase,
+               fs) -> float:
+        """Run one flushed batch through the executor and route its rows;
+        returns the coverage. ``phase(name)`` wraps each phase of the
+        flush (``assemble``, ``transfer``, ``wait``, ``route``; the
+        executor's own spans fall between transfer and wait) — a tracer
+        span, or a no-op when tracing is off."""
+        with phase("assemble"):
+            phi = np.zeros((b_pad, batch[0].phi_row.shape[0]), np.float32)
+            for r, req in enumerate(batch):
+                phi[r] = req.phi_row
+            excl_ids = None
+            l_max = max((req.exclude.shape[0] for req in batch
+                         if req.exclude is not None), default=0)
+            if l_max > 0:
+                excl_ids = np.full((b_pad, l_max), -1, np.int32)
+                for r, req in enumerate(batch):
+                    if req.exclude is not None:
+                        excl_ids[r, : req.exclude.shape[0]] = req.exclude
+        with phase("transfer"):
+            if excl_ids is not None:
+                excl_ids = jnp.asarray(excl_ids)
+            phi = jnp.asarray(phi)
+        res = self.topk_phi(phi, excl_ids)
+        scores, ids = res  # TopKResult or a bare (scores, ids) tuple
+        coverage = float(getattr(res, "coverage", 1.0))
+        dead_ranges = tuple(getattr(res, "dead_ranges", ()))
+        with phase("wait"):
+            scores = np.asarray(scores)
+            ids = np.asarray(ids)
+        with phase("route"):
+            if coverage < 1.0:
+                self._m_degraded.inc(len(batch))
+            for r, req in enumerate(batch):  # route rows back to tickets
+                out = TopKResult(scores[r], ids[r], coverage, dead_ranges)
+                self._results[req.ticket] = out
+                self._completed_at[req.ticket] = now
+                self._m_queue_lat.observe(now - req.t_submit)
+                spans = self._spans.pop(req.ticket, None)
+                if spans is not None:
+                    rq, qs = spans
+                    self.tracer.end(qs)
+                    self.tracer.end(rq, flush_span=fs.span_id,
+                                    coverage=coverage)
+                # degraded answers are never cached: the hole they carry
+                # must not outlive the replica failure that caused it
+                if req.key is not None and coverage == 1.0:
+                    self._cache_put(self._cache_key(req.key, req.exclude),
+                                    out)
+        return coverage
 
     def _cache_key(self, key, excl: Optional[np.ndarray]):
         """(caller key, table version, exclude list) — version comes from

@@ -435,7 +435,6 @@ class ShardedRetrievalCluster:
         ann=None,                                  # serve.ann.AnnConfig
         registry=None,
     ):
-        from repro.obs.costs import KernelCostRecorder
         from repro.obs.metrics import next_instance_id, resolve_registry
         from repro.serve.publish import VersionedTable
 
@@ -451,7 +450,6 @@ class ShardedRetrievalCluster:
         self._ivf: dict = {}      # table version → per-shard PsiIndex tuple
         self._table = VersionedTable()
         self.registry = resolve_registry(registry)
-        self._costs = KernelCostRecorder(self.registry)
         self._m_queries = self.registry.counter(
             "serve_cluster_queries_total", "cluster topk_phi requests",
             labels=("instance",)).labels(instance=next_instance_id())
@@ -599,18 +597,6 @@ class ShardedRetrievalCluster:
                 table, self._ivf_indexes(table), phi_rows, k,
                 exclude_ids=exclude_ids, registry=self.registry,
             )
-        from repro.obs.costs import topk_score_cost
-
-        b = int(jnp.shape(phi_rows)[0])
-        excl_l = 0 if exclude_ids is None else int(exclude_ids.shape[1])
-        cost = topk_score_cost(b, table.rows_per, int(table.shards[0].shape[1]),
-                               k, excl_l=excl_l)
-        # one per-shard kernel dispatch each: S× the streams, same tile
-        self._costs.record("topk_score", {
-            "hbm_bytes": cost["hbm_bytes"] * table.n_shards,
-            "flops": cost["flops"] * table.n_shards,
-            "vmem_tile_bytes": cost["vmem_tile_bytes"],
-        }, calls=table.n_shards)
         return cluster_topk(
             table, phi_rows, k, exclude_mask=exclude_mask,
             exclude_ids=exclude_ids, block_items=self.block_items,

@@ -81,7 +81,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.topk_score.ops import topk_score
-from repro.obs.costs import KernelCostRecorder
 from repro.obs.metrics import resolve_registry
 from repro.serve.cluster import TopKResult
 
@@ -148,11 +147,7 @@ class RetrievalEngine:
         self.block_items = block_items
         self.model = None   # set by from_model: enables fold_in_phi
         self._params = None
-        # kernel cost accounting (obs/costs.py): every topk_phi dispatch
-        # records the analytic HBM/FLOP/VMEM model at this host call site
-        # (the kernel itself is jitted — see the costs module docstring)
         self.registry = resolve_registry(registry)
-        self._costs = KernelCostRecorder(self.registry)
         if retrieval not in ("exact", "ivf"):
             raise ValueError(f"retrieval must be 'exact' or 'ivf', got {retrieval!r}")
         self.retrieval = retrieval
@@ -269,12 +264,6 @@ class RetrievalEngine:
                 block_items=self.block_items, registry=self.registry,
             )
             return TopKResult(s, i)
-        b = int(jnp.shape(phi_rows)[0])
-        excl_l = 0 if exclude_ids is None else int(exclude_ids.shape[1])
-        self._costs.record_topk(
-            b, self.n_items, int(self.psi.shape[1]), k or self.k,
-            excl_l=excl_l,
-        )
         s, i = topk_score(
             phi_rows, self.psi, k or self.k, exclude_mask,
             exclude_ids=exclude_ids, block_items=self.block_items,

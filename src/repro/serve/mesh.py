@@ -71,7 +71,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.costs import KernelCostRecorder
 from repro.obs.metrics import StatsView, next_instance_id, resolve_registry
 from repro.runtime.health import StragglerWatchdog
 from repro.serve.cluster import (
@@ -492,7 +491,6 @@ class FaultTolerantRetrievalMesh:
             "per-(shard,replica) dispatch wall time (the health monitor's "
             "own observations)", labels=("instance", "shard", "replica"))
         self._lat_children: Dict[Tuple[int, int], object] = {}
-        self._costs = KernelCostRecorder(reg)
         if psi_table is not None:
             self.publish(psi_table)
 
@@ -743,12 +741,6 @@ class FaultTolerantRetrievalMesh:
                             registry=self.registry,
                         )
                 else:
-                    self._costs.record_topk(
-                        int(phi_rows.shape[0]), rs.table.rows_per,
-                        int(rep.slab.shape[1]), k,
-                        excl_l=0 if exclude_ids is None
-                        else int(exclude_ids.shape[1]),
-                    )
                     ss, ii = shard_topk(
                         rs.table, s, phi_rows, k, slab=rep.slab,
                         exclude_mask=exclude_mask, exclude_ids=exclude_ids,

@@ -2,15 +2,20 @@
 clocks, instrumentation back-compat on the serving components, and the
 bit-identity guard (metrics/tracing must never change results).
 
-Everything runs on injected clocks — no sleeps, no wall-time flakiness.
+Everything runs on injected clocks — no sleeps, no wall-time flakiness —
+except the checks that spans reach a real profiler trace on the CPU.
 """
+import glob
 import json
+import os
+import re
+import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.obs.costs import KernelCostRecorder, cd_sweep_cost, topk_score_cost
 from repro.obs.export import (
     chrome_trace,
     metrics_jsonl,
@@ -26,10 +31,9 @@ from repro.obs.metrics import (
     default_registry,
     resolve_registry,
 )
+from repro.obs import trace as obs_trace
 from repro.obs.trace import Tracer, trace_for_ticket
-from repro.kernels.vmem import psi_row_bytes
 from repro.serve.batcher import MicroBatcher
-from repro.serve.engine import RetrievalEngine
 from repro.serve.mesh import (
     FaultInjector,
     FaultTolerantRetrievalMesh,
@@ -201,47 +205,125 @@ class TestTracing:
         assert trace_for_ticket(tr, 99) == []
 
 
-# ------------------------------------------------------------- kernel costs
-class TestKernelCosts:
-    def test_topk_cost_matches_vmem_byte_model(self):
-        b, n, d, k = 32, 4096, 64, 100
-        cost = topk_score_cost(b, n, d, k)
-        k_pad = -(-k // 128) * 128
-        assert cost["hbm_bytes"] == (n * psi_row_bytes(d) + 4.0 * b * d
-                                     + 2 * 4.0 * b * k_pad)
-        assert cost["flops"] == 2.0 * b * n * d
-        # quantized ψ stream: bf16 halves, int8 quarters + a scale column
-        assert (topk_score_cost(b, n, d, k, psi_bytes=2)["hbm_bytes"]
-                < cost["hbm_bytes"])
+def _profiled(tmp_path, body):
+    """Run ``body`` under a profiler trace on the CPU; returns the trace's
+    ``repro.*`` host events as ``{name: [(start_ns, duration_ns, stats)]}``."""
+    d = str(tmp_path / "trace")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(d, profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                         "*.xplane.pb")))[-1]
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(obs_trace.PROFILER_PREFIX):
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.duration_ns, dict(e.stats)))
+    return out
 
-    def test_recorder_accumulates_per_kernel(self):
-        reg = MetricsRegistry()
-        rec = KernelCostRecorder(reg)
-        rec.record_topk(8, 1024, 16, 10)
-        rec.record_topk(8, 1024, 16, 10)
-        rec.record_cd_sweep(100, 256, 16, 4)
-        assert reg.get("kernel_calls_total", kernel="topk_score") == 2
-        assert reg.get("kernel_calls_total", kernel="cd_sweep") == 1
-        one = topk_score_cost(8, 1024, 16, 10)
-        assert reg.get("kernel_hbm_bytes_total",
-                       kernel="topk_score") == 2 * one["hbm_bytes"]
-        assert reg.get("kernel_flops_total",
-                       kernel="topk_score") == 2 * one["flops"]
-        sweep = cd_sweep_cost(100, 256, 16, 4)
-        assert reg.get("kernel_hbm_bytes_total",
-                       kernel="cd_sweep") == sweep["hbm_bytes"]
 
-    def test_engine_dispatch_site_records_costs(self):
-        rng = np.random.default_rng(3)
-        phi = jnp.asarray(rng.normal(size=(4, 16)), jnp.float32)
-        psi = jnp.asarray(rng.normal(size=(64, 16)), jnp.float32)
-        reg = MetricsRegistry()
-        eng = RetrievalEngine(psi, lambda p=phi: p, k=5, block_items=32,
-                              registry=reg)
-        eng.topk_phi(phi)
-        assert reg.get("kernel_calls_total", kernel="topk_score") == 1
-        assert reg.get("kernel_hbm_bytes_total", kernel="topk_score") == (
-            topk_score_cost(4, 64, 16, 5)["hbm_bytes"])
+class TestProfilerSpans:
+    def test_no_annotation_without_tracer(self, monkeypatch):
+        made = []
+
+        class Counting:
+            def __init__(self, name, **attrs):
+                made.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(obs_trace, "TraceAnnotation", Counting)
+        clock = {"t": 0.0}
+
+        def serve(tracer):
+            b = MicroBatcher(_fake_topk, max_batch=2, max_delay=1.0,
+                             clock=lambda: clock["t"], version_fn=lambda: 0,
+                             registry=NULL_REGISTRY, tracer=tracer)
+            for _ in range(3):
+                b.submit(np.ones(8, np.float32))
+            b.flush()
+
+        serve(None)
+        assert made == []
+        serve(Tracer(clock=lambda: clock["t"]))
+        assert {"repro.request", "repro.queue", "repro.flush",
+                "repro.assemble", "repro.transfer", "repro.wait",
+                "repro.route"} == set(made)
+
+    def test_unnested_spans_reach_the_profiler(self, tmp_path):
+        tr = Tracer(clock=time.perf_counter)
+
+        def body():
+            a = tr.begin("first", parent=None, batch=3, reason="size")
+            b = tr.begin("second", parent=None)
+            time.sleep(0.02)
+            tr.end(a)        # the first begun ends first: not LIFO
+            time.sleep(0.01)
+            tr.end(b)
+
+        ev = _profiled(tmp_path, body)
+        (a0, ad, a_stats), = ev["repro.first"]
+        (b0, bd, _), = ev["repro.second"]
+        sa, sb = tr.spans
+        assert ad == pytest.approx(sa.duration * 1e9, abs=5e6)
+        assert bd == pytest.approx(sb.duration * 1e9, abs=5e6)
+        assert a0 <= b0 < a0 + ad < b0 + bd
+        assert ad >= 0.02e9 and bd >= 0.03e9
+        assert a_stats["batch"] == 3 and a_stats["reason"] == "size"
+
+    @pytest.mark.parametrize("n_shards, middle", [
+        (1, ["dispatch"]), (2, ["dispatch", "dispatch", "merge"])])
+    def test_flush_phases_nest_in_order(self, n_shards, middle):
+        tr = Tracer()
+        phi, _, mesh = _mesh_pair(n_shards=n_shards, n_replicas=1,
+                                  tracer=tr)
+        b = MicroBatcher(
+            lambda rows, eids: mesh.topk_phi(rows, exclude_ids=eids),
+            max_batch=4, max_delay=1.0, version_fn=lambda: mesh.version,
+            tracer=tr)
+        for r in range(3):
+            b.submit(np.asarray(phi[r]), exclude=[r, r + 1])
+        b.flush()
+        fl, = [s for s in tr.spans if s.name == "flush"]
+        kids = [s for s in tr.spans if s.parent_id == fl.span_id]
+        assert [s.name for s in kids] == [
+            "assemble", "transfer", *middle, "wait", "route"]
+        assert all(fl.t0 <= s.t0 <= s.t1 <= fl.t1 for s in kids)
+        assert all(x.t1 <= y.t0 for x, y in zip(kids, kids[1:]))
+        assert fl.attrs["batch"] == 3 and fl.attrs["batch_padded"] == 8
+
+
+class TestStepScopes:
+    @pytest.mark.parametrize("scheduled", [False, True])
+    def test_epoch_lowering_carries_every_icd_scope(self, scheduled):
+        from repro.core.models import mf
+        from repro.core.sweeps import SweepSchedule
+        from repro.sparse.interactions import build_interactions
+
+        rng = np.random.default_rng(0)
+        ctx, item = rng.integers(0, 20, 200), rng.integers(0, 15, 200)
+        data = build_interactions(ctx, item, np.ones(200),
+                                  np.full(200, 2.0), 20, 15)
+        params = mf.init(jax.random.key(0), 20, 15, 4)
+        e = mf.residuals(params, data)
+        sched = SweepSchedule("full", block=2, blocks_per_sweep=1) \
+            if scheduled else None
+        text = mf.epoch.lower(params, data, e, mf.MFHyperParams(k=4),
+                              sched).as_text(debug_info=True)
+        assert set(re.findall(r"icd\.[a-z]+", text)) == {
+            "icd.gram", "icd.gather", "icd.segsum", "icd.implicit",
+            "icd.newton", "icd.patch", "icd.permute"}
 
 
 # ------------------------------------------- component instrumentation
@@ -377,17 +459,35 @@ class TestMeshInstrumentation:
         assert b.stats["degraded_results"] == 3
         assert b.stats["cache_hits"] == 0
 
-    def test_bit_identity_guard(self):
+    def test_bit_identity_guard(self, tmp_path):
         # the whole point of opt-in observability: a fully instrumented
         # mesh returns bit-identical results to a bare one
         phi, _, bare = _mesh_pair(registry=NULL_REGISTRY)
-        _, _, instr = _mesh_pair(registry=MetricsRegistry(),
-                                 tracer=Tracer())
+        tr = Tracer()
+        _, _, instr = _mesh_pair(registry=MetricsRegistry(), tracer=tr)
         r0, r1 = bare.topk_phi(phi), instr.topk_phi(phi)
         np.testing.assert_array_equal(np.asarray(r0.ids),
                                       np.asarray(r1.ids))
         np.testing.assert_array_equal(np.asarray(r0.scores),
                                       np.asarray(r1.scores))
+
+        # and through the batcher's flush: tracer off against on, the
+        # latter with its spans going into a running profiler trace
+        def served(mesh, tracer):
+            b = MicroBatcher(
+                lambda rows, eids: mesh.topk_phi(rows, exclude_ids=eids),
+                max_batch=4, max_delay=1.0, version_fn=lambda: mesh.version,
+                registry=NULL_REGISTRY, tracer=tracer)
+            tickets = [b.submit(np.asarray(phi[r]), exclude=[r])
+                       for r in range(4)]
+            return [b.result(t) for t in tickets]
+
+        off, on = served(bare, None), []
+        ev = _profiled(tmp_path, lambda: on.extend(served(instr, tr)))
+        assert len(ev["repro.flush"]) == 1
+        for a, b in zip(off, on):
+            np.testing.assert_array_equal(a.ids, b.ids)
+            np.testing.assert_array_equal(a.scores, b.scores)
 
     def test_replica_latency_histogram_exists(self):
         reg = MetricsRegistry()
