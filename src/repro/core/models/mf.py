@@ -29,7 +29,7 @@ from repro.core.gram import PRECISION as gram_precision
 from repro.core.gram import gram
 from repro.core.implicit import implicit_objective
 from repro.sparse.interactions import Interactions
-from repro.sparse.segment import segment_sum
+from repro.sparse.segment import segment_broadcast_sorted, segment_sum
 
 
 class MFParams(NamedTuple):
@@ -96,10 +96,10 @@ def _side_sweep(
     side: jax.Array,        # (n, k) parameters being updated
     other_j: jax.Array,     # (k, k) Gram of the fixed side  (J_I for ctx sweep)
     other_cols_nnz,         # callable f -> (nnz,) ψ_{f}(item of nnz)
-    rows_nnz: jax.Array,    # (nnz,) row id (this side) per observation
+    rows_nnz: jax.Array,    # (nnz,) row id (this side) per observation, sorted
+    indptr: jax.Array,      # (n_rows+1,) run offsets of rows_nnz
     alpha: jax.Array,       # (nnz,)
     e: jax.Array,           # (nnz,) residual cache, this side's sort order
-    n_rows: int,
     hp: MFHyperParams,
     schedule: Optional[sweeps.SweepSchedule] = None,
     sweep_index: int = 0,
@@ -115,6 +115,8 @@ def _side_sweep(
     ``icd.newton`` (the step and the column write), ``icd.patch`` (the
     residual patch) — which the op metadata, and so a profiler trace of
     the compiled step, carries."""
+
+    n_rows = indptr.shape[0] - 1
 
     def body(f, carry):
         side_m, e = carry
@@ -139,7 +141,7 @@ def _side_sweep(
                 hp.eta,
             )
         with jax.named_scope("icd.patch"):
-            e = e + jnp.take(delta, rows_nnz) * o_col  # rank-1 residual patch
+            e = e + segment_broadcast_sorted(delta, rows_nnz, indptr) * o_col
         with jax.named_scope("icd.newton"):
             side_m = sweeps.put_col(side_m, f, s_col + delta)
         return side_m, e
@@ -184,7 +186,7 @@ def epoch(
         j_i = gram(h, implementation=hp.implementation)
     h_cols = lambda f: jnp.take(sweeps.take_col(h, f), data.item)
     w, e = _side_sweep(
-        w, j_i, h_cols, data.ctx, data.alpha, e, data.n_ctx, hp,
+        w, j_i, h_cols, data.ctx, data.indptr, data.alpha, e, hp,
         schedule, sweep_index,
     )
 
@@ -195,7 +197,7 @@ def epoch(
     alpha_t = sweeps.to_item_major(data.alpha, data.t_perm)
     w_cols = lambda f: jnp.take(sweeps.take_col(w, f), data.t_ctx)
     h, e_t = _side_sweep(
-        h, j_c, w_cols, data.t_item, alpha_t, e_t, data.n_items, hp,
+        h, j_c, w_cols, data.t_item, data.t_indptr, alpha_t, e_t, hp,
         schedule, sweep_index,
     )
     e = sweeps.to_ctx_major(e_t, data.t_perm)

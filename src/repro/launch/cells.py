@@ -78,6 +78,8 @@ def _icd_cell(arch: str, shape_spec, mesh) -> Cell:
         y=_sds((nnz,), jnp.float32), alpha=_sds((nnz,), jnp.float32),
         t_ctx=_sds((nnz,), jnp.int32), t_item=_sds((nnz,), jnp.int32),
         t_perm=_sds((nnz,), jnp.int32),
+        indptr=_sds((n_ctx + 1,), jnp.int32),
+        t_indptr=_sds((n_items + 1,), jnp.int32),
         n_ctx=n_ctx, n_items=n_items,
     )
     e_abs = _sds((nnz,), jnp.float32)
@@ -87,6 +89,7 @@ def _icd_cell(arch: str, shape_spec, mesh) -> Cell:
         ctx=d_spec_dict["ctx"], item=d_spec_dict["item"], y=d_spec_dict["y"],
         alpha=d_spec_dict["alpha"], t_ctx=d_spec_dict["t_ctx"],
         t_item=d_spec_dict["t_item"], t_perm=d_spec_dict["t_perm"],
+        indptr=d_spec_dict["indptr"], t_indptr=d_spec_dict["t_indptr"],
         n_ctx=n_ctx, n_items=n_items,
     )
 

@@ -67,13 +67,14 @@ def zero1_state_specs(fsdp_param_specs):
 # ------------------------------------------------------------------ icd ---
 def icd_mf_specs(mesh):
     """W rows (contexts) over dp; H rows (items) over model; observation
-    arrays over dp. The k×k Grams replicate — Lemma 2's k² all-reduce."""
+    arrays over dp; the run offsets replicate. The k×k Grams replicate —
+    Lemma 2's k² all-reduce."""
     dp = dp_axes(mesh)
     from repro.core.models.mf import MFParams
 
     params = MFParams(w=P(dp, None), h=P("model", None))
     data = dict(
         ctx=P(dp), item=P(dp), y=P(dp), alpha=P(dp),
-        t_ctx=P(dp), t_item=P(dp), t_perm=P(dp),
+        t_ctx=P(dp), t_item=P(dp), t_perm=P(dp), indptr=P(), t_indptr=P(),
     )
     return params, data

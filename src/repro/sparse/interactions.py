@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.sparse.segment import run_offsets
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -26,6 +28,12 @@ class Interactions:
     Item-major view of the same triplets (sorted by item):
       t_ctx, t_item, t_perm — ``t_perm`` maps item-major position → context-
       major position so residual caches can be permuted between sweeps.
+
+    Run offsets of the two sorted layouts (for the sorted-run broadcast in
+    ``repro.sparse.segment``): context c's pairs are ``[indptr[c],
+    indptr[c+1])`` of the context-major arrays, item i's ``[t_indptr[i],
+    t_indptr[i+1])`` of the item-major ones.
+      indptr: (n_ctx+1,) int32;  t_indptr: (n_items+1,) int32
     """
 
     ctx: jax.Array
@@ -35,6 +43,8 @@ class Interactions:
     t_ctx: jax.Array
     t_item: jax.Array
     t_perm: jax.Array
+    indptr: jax.Array
+    t_indptr: jax.Array
     n_ctx: int = dataclasses.field(metadata=dict(static=True))
     n_items: int = dataclasses.field(metadata=dict(static=True))
 
@@ -75,14 +85,17 @@ def build_interactions(
     ctx, item, y, alpha = ctx[order], item[order], y[order], alpha[order]
 
     t_order = np.lexsort((ctx, item))
+    t_item = item[t_order]
     return Interactions(
         ctx=jnp.asarray(ctx, dtype=jnp.int32),
         item=jnp.asarray(item, dtype=jnp.int32),
         y=jnp.asarray(y, dtype=jnp.float32),
         alpha=jnp.asarray(alpha, dtype=jnp.float32),
         t_ctx=jnp.asarray(ctx[t_order], dtype=jnp.int32),
-        t_item=jnp.asarray(item[t_order], dtype=jnp.int32),
+        t_item=jnp.asarray(t_item, dtype=jnp.int32),
         t_perm=jnp.asarray(t_order, dtype=jnp.int32),
+        indptr=jnp.asarray(run_offsets(ctx, n_ctx)),
+        t_indptr=jnp.asarray(run_offsets(t_item, n_items)),
         n_ctx=int(n_ctx),
         n_items=int(n_items),
     )

@@ -11,10 +11,64 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def segment_sum(data: jax.Array, segment_ids: jax.Array, num_segments: int) -> jax.Array:
     return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
+
+
+# ---------------------------------------------------------------------------
+# Sorted-run broadcast. Where the pair list is sorted by row, each row's pairs
+# form one contiguous run, so a per-row value reaches its pairs by streaming
+# the list in fixed tiles instead of a gather per pair: within a tile, a
+# log2(tile)-level scan copies each run's first value along the run (sorted
+# ids: equal ids d apart means one run between them).
+# ---------------------------------------------------------------------------
+
+SORTED_TILE = 128  # pairs per tile: log2 of it is the in-tile scan depth
+
+
+def run_offsets(ids_sorted, n_rows: int):
+    """``indptr`` of a row-sorted id list: row r's run is
+    ``[indptr[r], indptr[r+1])`` (host numpy; empty rows give empty runs)."""
+    return np.searchsorted(np.asarray(ids_sorted), np.arange(n_rows + 1),
+                           side="left").astype(np.int32)
+
+
+def _shift(x: jax.Array, d: int, fill) -> jax.Array:
+    """x moved ``d`` places up its last axis: out[..., j] = x[..., j-d]."""
+    return jnp.pad(x[..., :-d], [(0, 0)] * (x.ndim - 1) + [(d, 0)],
+                   constant_values=fill)
+
+
+def segment_broadcast_sorted(vals: jax.Array, ids_sorted: jax.Array,
+                             indptr: jax.Array) -> jax.Array:
+    """``jnp.take(vals, ids_sorted)``, bit for bit, for ``ids_sorted``
+    ascending with run offsets ``indptr`` (:func:`run_offsets`): each run's
+    value is written at its first pair (one write per row and one per tile)
+    and copied along the run, Hillis–Steele: at distance d = 1, 2, 4, …
+    a pair takes the value d places back where that pair is in its run."""
+    n_rows, nnz, tile = indptr.shape[0] - 1, ids_sorted.shape[0], SORTED_TILE
+    if nnz == 0:
+        return jnp.zeros((0,), vals.dtype)
+    n_tiles = -(-nnz // tile)
+    ids = jnp.pad(ids_sorted, (0, n_tiles * tile - nnz),
+                  constant_values=n_rows).reshape(n_tiles, tile)
+    start = indptr[:-1]
+    # empty rows write past the end, where the scatter drops them
+    at = jnp.where(indptr[1:] > start, start,
+                   n_tiles * tile + jnp.arange(n_rows, dtype=start.dtype))
+    out = jnp.zeros((n_tiles * tile,), vals.dtype).at[at].set(
+        vals, mode="drop", unique_indices=True).reshape(n_tiles, tile)
+    first = jnp.take(vals, ids[:, 0], mode="fill", fill_value=0,
+                     indices_are_sorted=True)
+    out = jnp.where(jnp.arange(tile) == 0, first[:, None], out)
+    d = 1
+    while d < tile:
+        out = jnp.where(_shift(ids, d, -1) == ids, _shift(out, d, 0), out)
+        d *= 2
+    return out.reshape(-1)[:nnz]
 
 
 def segment_mean(data: jax.Array, segment_ids: jax.Array, num_segments: int) -> jax.Array:

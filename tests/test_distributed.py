@@ -59,15 +59,17 @@ SCRIPT = textwrap.dedent(
     dsh = lambda spec: NamedSharding(mesh2, spec)
     p_sh = mf.MFParams(w=dsh(P("data", None)), h=dsh(P("model", None)))
     import dataclasses
-    d_sharded = jax.device_put(data, jax.tree_util.tree_map(
-        lambda _: dsh(P("data")), data))
+    # pair arrays over "data"; the run offsets (one per row) replicate
+    d_sh = dataclasses.replace(
+        jax.tree_util.tree_map(lambda _: dsh(P("data")), data),
+        indptr=dsh(P()), t_indptr=dsh(P()))
+    d_sharded = jax.device_put(data, d_sh)
     p_sharded = jax.device_put(params, p_sh)
     e_sharded = jax.device_put(e, dsh(P("data")))
     with mesh2:
         got_p, got_e = jax.jit(
             lambda p, d, ee: mf.epoch(p, d, ee, hp),
-            in_shardings=(p_sh, jax.tree_util.tree_map(lambda _: dsh(P("data")), data),
-                          dsh(P("data"))),
+            in_shardings=(p_sh, d_sh, dsh(P("data"))),
             out_shardings=(p_sh, dsh(P("data"))),
         )(p_sharded, d_sharded, e_sharded)
     np.testing.assert_allclose(np.asarray(got_p.w), np.asarray(ref_p.w),

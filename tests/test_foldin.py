@@ -98,8 +98,8 @@ def test_one_sweep_matches_mf_side_sweep_single_row():
     side, _ = _side_sweep(
         jnp.zeros((1, k), jnp.float32), gram(h_j),
         lambda f: h_j[jnp.asarray(ids), f],
-        jnp.zeros(m, jnp.int32), jnp.asarray(alpha), jnp.asarray(-y),
-        1, hp,
+        jnp.zeros(m, jnp.int32), jnp.asarray([0, m], jnp.int32),
+        jnp.asarray(alpha), jnp.asarray(-y), hp,
     )
     np.testing.assert_allclose(got.row, np.asarray(side[0]),
                                rtol=2e-5, atol=2e-6)

@@ -17,6 +17,7 @@ from repro.sparse import (
 )
 from repro.sparse.csr import transpose_csr_host
 from repro.sparse.sampler import sample_neighbors
+from repro.sparse.segment import SORTED_TILE, run_offsets, segment_broadcast_sorted
 
 
 def test_csr_roundtrip_and_row_ids():
@@ -95,3 +96,48 @@ def test_sampler_isolated_nodes_self_loop():
     assert np.all(np.asarray(neigh[0]) == 2)
     assert np.all(np.asarray(neigh[1]) == 3)
     assert np.all(np.asarray(neigh[2]) == 1)
+
+
+# --- sorted-run broadcast -----------------------------------------------------
+SORTED_CASES = ("empty_rows", "long_row", "singletons", "ragged_tail",
+                "nnz0", "nnz1", "zipf")
+
+
+def _sorted_layout(case, rng, tile=SORTED_TILE):
+    """(sorted ids, n_rows) of one layout, sized against the tile."""
+    if case == "empty_rows":        # empty rows first, in the middle, last
+        counts = [0, 0, 3, 0, tile + 1, 0, 0, 2, 1, 0, 0]
+    elif case == "long_row":        # one run across several tiles
+        counts = [2, 3 * tile + 5, 1, 0, 4]
+    elif case == "singletons":      # every pair its own row
+        counts = [1] * (2 * tile + 3)
+    elif case == "ragged_tail":     # nnz not a multiple of the tile
+        counts = list(rng.integers(0, 4, size=tile))
+        counts[-1] += 1 + (sum(counts) % tile == tile - 1)
+    elif case == "nnz0":
+        counts = [0, 0, 0]
+    elif case == "nnz1":
+        counts = [0, 1, 0]
+    else:                           # Zipf(1.0) row popularity, as items are
+        n_rows = 50
+        p = 1.0 / np.arange(1, n_rows + 1)
+        ids = rng.choice(n_rows, size=4 * tile + 7, p=p / p.sum())
+        return np.sort(ids).astype(np.int32), n_rows
+    return np.repeat(np.arange(len(counts)), counts).astype(np.int32), len(counts)
+
+
+@pytest.mark.parametrize("case", SORTED_CASES)
+def test_segment_broadcast_sorted_is_take(case):
+    """Each pair gets its row's value: ``jnp.take`` bit for bit, signed
+    zeros and NaNs included."""
+    rng = np.random.default_rng(len(case))
+    ids, n_rows = _sorted_layout(case, rng)
+    vals = rng.normal(size=n_rows).astype(np.float32)
+    vals[::5] = -0.0
+    vals[3::7] = np.nan
+    got = np.asarray(segment_broadcast_sorted(
+        jnp.asarray(vals), jnp.asarray(ids),
+        jnp.asarray(run_offsets(ids, n_rows))))
+    want = np.asarray(jnp.take(jnp.asarray(vals), jnp.asarray(ids)))
+    assert got.shape == want.shape == (ids.size,)
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
