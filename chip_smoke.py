@@ -382,6 +382,8 @@ def four_chip_phase(cfg, seed: int):
     placed = slab_devices(m22)
     check(all(placed[2 * s] != placed[2 * s + 1] for s in range(2)),
           f"replicas of a shard on distinct devices {[str(x) for x in placed]}")
+    check(len(set(placed)) == 4,
+          f"one slab per device {[str(x) for x in placed]}")
     injector.fail(0, 0, "error")
     t0 = now()
     res = ready(m22.topk_phi(phi, exclude_ids=eids))

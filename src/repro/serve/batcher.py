@@ -108,6 +108,11 @@ class MicroBatcher:
     The batcher is deliberately single-threaded and clock-injected: the
     serving loop owns the cadence (call ``step`` between admissions), and
     the unit tests replay traces under a simulated clock.
+
+    ``host_inputs=True`` hands the executor the batch as host (numpy)
+    arrays instead of putting it on the default device first: an executor
+    over several devices (the mesh with ``devices``) then sends it to each
+    dispatched replica's device, so no fixed device is on the path.
     """
 
     def __init__(
@@ -122,10 +127,12 @@ class MicroBatcher:
         version_fn: Optional[Callable[[], int]] = None,
         registry=None,
         tracer=None,
+        host_inputs: bool = False,
     ):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         self.topk_phi = topk_phi
+        self.host_inputs = bool(host_inputs)
         self.max_batch = int(max_batch)
         self.max_delay = float(max_delay)
         self.pad_to = int(pad_to)
@@ -345,8 +352,9 @@ class MicroBatcher:
         """Run one flushed batch through the executor and route its rows;
         returns the coverage. ``phase(name)`` wraps each phase of the
         flush (``assemble``, ``transfer``, ``wait``, ``route``; the
-        executor's own spans fall between transfer and wait) — a tracer
-        span, or a no-op when tracing is off."""
+        executor's own spans fall between transfer and wait; no
+        ``transfer`` with ``host_inputs``) — a tracer span, or a no-op
+        when tracing is off."""
         with phase("assemble"):
             phi = np.zeros((b_pad, batch[0].phi_row.shape[0]), np.float32)
             for r, req in enumerate(batch):
@@ -359,10 +367,11 @@ class MicroBatcher:
                 for r, req in enumerate(batch):
                     if req.exclude is not None:
                         excl_ids[r, : req.exclude.shape[0]] = req.exclude
-        with phase("transfer"):
-            if excl_ids is not None:
-                excl_ids = jnp.asarray(excl_ids)
-            phi = jnp.asarray(phi)
+        if not self.host_inputs:
+            with phase("transfer"):
+                if excl_ids is not None:
+                    excl_ids = jnp.asarray(excl_ids)
+                phi = jnp.asarray(phi)
         res = self.topk_phi(phi, excl_ids)
         scores, ids = res  # TopKResult or a bare (scores, ids) tuple
         coverage = float(getattr(res, "coverage", 1.0))

@@ -132,11 +132,13 @@ def empty_topk(b: int, k: int) -> Tuple[jax.Array, jax.Array]:
 def colocate_parts(parts: List[jax.Array]) -> List[jax.Array]:
     """Per-shard results are committed to their shard's (or replica's)
     device; ``jnp.stack`` refuses a cross-device concatenate, so the merge
-    input must first land on one device. No-op in the single-device case."""
+    input must first land on one device: that of the first part, a device
+    that has just answered (never a fixed one, which may be lost). No-op
+    in the single-device case."""
     devs = {getattr(p, "device", None) for p in parts}
     if len(devs) <= 1:
         return parts
-    dev = jax.devices()[0]
+    dev = parts[0].device
     return [jax.device_put(p, dev) for p in parts]
 
 
@@ -164,6 +166,9 @@ def shard_topk(
         mask_s = _shard_exclude_mask(exclude_mask, lo, table.rows_per)
     dev = getattr(shard, "device", None)
     phi_s = phi_rows if dev is None else jax.device_put(phi_rows, dev)
+    if dev is not None and exclude_ids is not None and getattr(
+            exclude_ids, "device", None) != dev:
+        exclude_ids = jax.device_put(exclude_ids, dev)
     return topk_score(
         phi_s, shard, k, mask_s, exclude_ids=exclude_ids,
         id_offset=lo, n_valid=table.valid_rows(s),
@@ -226,9 +231,15 @@ def shard_psi(
     """Row-range-partition ``psi_table`` into ``n_shards`` uniform slabs.
 
     ``devices`` (optional) places shard s on ``devices[s % len(devices)]``
-    — the multi-device layout; without it all shards share the default
-    device (the parity-test / single-host layout)."""
-    psi_table = jnp.asarray(psi_table, jnp.float32)
+    — the multi-device layout: each cut goes to its device on its own, and
+    a host (numpy) table is cut on the host, so the whole table never
+    lands on a device. Without it all shards share the default device
+    (the parity-test / single-host layout)."""
+    xp = jnp
+    if devices is None or isinstance(psi_table, jax.Array):
+        psi_table = jnp.asarray(psi_table, jnp.float32)
+    else:
+        psi_table, xp = np.asarray(psi_table, np.float32), np
     n_items, _ = psi_table.shape
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
@@ -238,7 +249,7 @@ def shard_psi(
         lo = s * rows_per
         blk = psi_table[lo : lo + rows_per]
         if blk.shape[0] < rows_per:  # last shard: pad to the uniform size
-            blk = jnp.pad(blk, ((0, rows_per - blk.shape[0]), (0, 0)))
+            blk = xp.pad(blk, ((0, rows_per - blk.shape[0]), (0, 0)))
         if devices is not None:
             blk = jax.device_put(blk, devices[s % len(devices)])
         shards.append(blk)

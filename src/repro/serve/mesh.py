@@ -8,10 +8,13 @@ regime implies (Rendle 2021 frames large-catalogue retrieval as exactly
 this availability/tail-latency problem):
 
   replication — :class:`ReplicaSet` places each row range on R replica
-    slabs (round-robin across devices so copies of the same shard land on
-    DIFFERENT devices), with per-replica health state and two routing
-    policies: ``round_robin`` (throughput fan-out) and
-    ``least_outstanding`` (tail-latency under skew). Every replica runs
+    slabs (numbered shard by shard and dealt round the devices: every
+    device holds at most ⌈S·R/D⌉ slabs, and copies of the same shard land
+    on DIFFERENT devices), with per-replica health state and two routing
+    policies: ``round_robin`` (throughput fan-out; within one query each
+    shard takes the live replica whose device has served the fewest of
+    that query's dispatches) and ``least_outstanding`` (tail-latency under
+    skew). Every replica runs
     the identical fused-kernel program (``cluster.shard_topk``) with the
     same ``id_offset``/``n_valid`` meta, so WHICH replica answered is
     unobservable in the results — failover is bit-invisible.
@@ -31,9 +34,13 @@ this availability/tail-latency problem):
     replica of the same range (no backoff for failover: another copy is
     already warm). A replica struck out ``fail_threshold`` times is marked
     dead; :meth:`FaultTolerantRetrievalMesh.heal` then re-places the
-    orphaned row range onto a surviving device from the publisher's
-    authoritative copy — the ``ElasticMeshManager`` recovery shape
-    (rebuild placement over the surviving device set), applied per shard.
+    orphaned row range by copying a live replica of it onto a device that
+    saw no death and holds no live copy of that range — the
+    ``ElasticMeshManager`` recovery shape (rebuild placement over the
+    surviving device set), applied per shard. The copy runs in the
+    background: the new replica enters routing only once its slab is
+    resident (``jax.Array.is_ready``), and until then the range is served
+    by its surviving copies.
 
   bounded, deadline-aware retries — :class:`RetryPolicy` gives each
     request a budget: at most ``max_attempts`` dispatches per shard,
@@ -191,6 +198,20 @@ class RetryPolicy:
 
 
 # ------------------------------------------------------------------ replicas
+def slab_device_index(s: int, r: int, n_replicas: int, n_devices: int) -> int:
+    """Device index of replica ``r`` of shard ``s``: slabs are numbered
+    shard by shard (``s·R + r``) and dealt round the D devices in turn, so
+    every device holds at most ⌈S·R/D⌉ of them, and the R copies of one
+    shard (R consecutive numbers) sit on R distinct devices whenever
+    R ≤ D. Numbering replica by replica (``r·S + s``) would put both
+    copies of a shard on one device at S = D."""
+    return (s * n_replicas + r) % n_devices
+
+
+def _slab_ready(slab) -> bool:
+    return slab.is_ready()
+
+
 @dataclasses.dataclass
 class Replica:
     """One placed copy of one ψ row-range, with live health state."""
@@ -202,10 +223,13 @@ class Replica:
     version: int
     alive: bool = True
     canary: bool = False          # staged next-version copy; not routed
+    ready: bool = True            # False while a heal copies its slab in
     outstanding: int = 0          # in-flight dispatches (least_outstanding)
     served: int = 0
     failures: int = 0             # consecutive failures (reset on success)
     dead_reason: Optional[str] = None
+    device_id: int = -1           # id of the device holding the slab
+    admitted_at: Optional[float] = None   # heal: routed from (mesh clock)
 
     @property
     def key(self) -> Tuple[int, int]:
@@ -215,15 +239,21 @@ class Replica:
 class ReplicaSet:
     """R health-tracked replicas of every shard of one table snapshot.
 
-    Placement: replica r of shard s goes on ``devices[(s + r) % D]`` — the
-    rotation guarantees (whenever R ≤ D) that copies of the SAME row range
-    live on DIFFERENT devices, so one device loss never kills a range.
+    Placement: replica r of shard s goes on
+    ``devices[slab_device_index(s, r, R, D)]`` — at most ⌈S·R/D⌉ slabs per
+    device, and (whenever R ≤ D) copies of the SAME row range on
+    DIFFERENT devices, so one device loss never kills a range. Replica 0
+    of each shard IS the table's shard (``publish`` cuts it onto that
+    device), so no further copy of the catalogue is pinned anywhere.
 
     Routing (:meth:`pick`): ``round_robin`` cycles the live replicas of a
-    shard (throughput); ``least_outstanding`` picks the live replica with
-    the fewest in-flight dispatches (tail latency). Dead replicas are
-    never picked; a shard with zero live replicas has no route and the
-    query layer degrades.
+    shard (throughput); given the query's per-device dispatch counts, it
+    takes the replica on the device that query has used least;
+    ``least_outstanding`` picks the live replica with the fewest in-flight
+    dispatches (tail latency). Dead replicas are never picked, nor are
+    re-placed ones whose slab is still being copied while another copy can
+    answer; a shard with zero live replicas has no route and the query
+    layer degrades.
     """
 
     def __init__(
@@ -238,11 +268,14 @@ class ReplicaSet:
             raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
         if policy not in ("round_robin", "least_outstanding"):
             raise ValueError(f"unknown routing policy {policy!r}")
-        self.table = table              # authoritative source copy
+        self.table = table              # the published cut (replica 0s)
         self.n_replicas = int(n_replicas)
         self.devices = list(devices) if devices is not None else None
         self.policy = policy
+        self.is_ready = _slab_ready     # is a copied-in slab resident yet
+        self.pending: List[Replica] = []   # re-placed, not yet routed
         self._rr = [0] * table.n_shards
+        self._device_use: Dict[int, int] = {}   # device id -> picks
         self.replicas: List[List[Replica]] = [
             [self._place(s, r) for r in range(self.n_replicas)]
             for s in range(table.n_shards)
@@ -252,15 +285,17 @@ class ReplicaSet:
     def _device_for(self, s: int, r: int):
         if not self.devices:
             return None
-        return self.devices[(s + r) % len(self.devices)]
+        return self.devices[slab_device_index(s, r, self.n_replicas,
+                                              len(self.devices))]
 
-    def _place(self, s: int, r: int, device=None) -> Replica:
+    def _place(self, s: int, r: int, device=None, source=None) -> Replica:
         dev = device if device is not None else self._device_for(s, r)
-        slab = self.table.shards[s]
+        slab = self.table.shards[s] if source is None else source
         if dev is not None:
             slab = jax.device_put(slab, dev)
         return Replica(shard=s, idx=r, slab=slab, device=dev,
-                       version=self.table.version)
+                       version=self.table.version,
+                       device_id=next(iter(slab.devices())).id)
 
     # ------------------------------------------------------------- health
     @property
@@ -272,7 +307,15 @@ class ReplicaSet:
         return self.table.version
 
     def live(self, s: int) -> List[Replica]:
+        """Live replicas of shard ``s``, those still being copied in too."""
         return [r for r in self.replicas[s] if r.alive and not r.canary]
+
+    def routable(self, s: int) -> List[Replica]:
+        """Live replicas that may answer: the resident ones, or, where a
+        shard has none, those still being copied in (better late than a
+        hole in the catalogue)."""
+        live = self.live(s)
+        return [r for r in live if r.ready] or live
 
     def dead_shards(self) -> List[int]:
         return [s for s in range(self.n_shards) if not self.live(s)]
@@ -290,30 +333,74 @@ class ReplicaSet:
                 rep.failures = 0
                 rep.dead_reason = None
 
+    def admit_ready(self) -> Tuple[List[Replica], List[Replica]]:
+        """Admit re-placed replicas whose slab is resident to routing;
+        drop those that died while being copied in. Returns (admitted,
+        dropped). Never blocks: it asks ``is_ready``."""
+        admitted, dropped, waiting = [], [], []
+        for rep in self.pending:
+            if not rep.alive:
+                dropped.append(rep)
+            elif self.is_ready(rep.slab):
+                rep.ready = True
+                admitted.append(rep)
+            else:
+                waiting.append(rep)
+        self.pending = waiting
+        return admitted, dropped
+
     # ------------------------------------------------------------- routing
-    def pick(self, s: int) -> Replica:
-        live = self.live(s)
+    def pick(self, s: int, load: Optional[Dict[int, int]] = None) -> Replica:
+        """The replica shard ``s``'s next dispatch goes to. ``load`` (device
+        id → dispatches so far in this query) steers ``round_robin`` to the
+        device least used by this query; ties go to the device that has
+        taken fewer of the set's dispatches, then in turn (turns alone can
+        fall into step with another shard's and load one device)."""
+        live = self.routable(s)
         if not live:
             raise ReplicaFailure(f"shard {s} has no live replica")
         if self.policy == "least_outstanding":
             return min(live, key=lambda r: (r.outstanding, r.idx))
-        rep = live[self._rr[s] % len(live)]
+        start = self._rr[s] % len(live)
         self._rr[s] += 1
+        turn = live[start:] + live[:start]
+        if load is None:
+            return turn[0]
+        use = self._device_use
+        rep = min(turn, key=lambda r: (load.get(r.device_id, 0),
+                                       use.get(r.device_id, 0)))
+        use[rep.device_id] = use.get(rep.device_id, 0) + 1
         return rep
 
     # ----------------------------------------------------- re-placement
-    def replace(self, s: int, *, device=None) -> Replica:
+    def heal_source(self, s: int) -> Optional[Replica]:
+        """The replica a heal of shard ``s`` copies from: a live, resident
+        one; None where there is none (the table's cut is copied then)."""
+        return next((r for r in self.live(s) if r.ready), None)
+
+    def replace(self, s: int, *, device=None,
+                source: Optional[Replica] = None) -> Replica:
         """Re-place shard ``s``'s orphaned row range as a fresh replica
-        built from the authoritative table copy, on a SURVIVING device —
-        the per-shard mirror of ``ElasticMeshManager.on_failure`` (rebuild
-        placement over the device set minus the casualties). The new
-        replica takes the lowest free slot index."""
+        copied from ``source`` (a live replica; by default
+        :meth:`heal_source`) — the per-shard mirror of
+        ``ElasticMeshManager.on_failure`` (rebuild placement over the
+        device set minus the casualties). The target is the least-loaded
+        device that holds no dead replica and no live copy of ``s``;
+        failing that, one with no dead replica; failing that, any. The
+        copy is asynchronous: the replica counts as live at once but is
+        routed only after :meth:`admit_ready` finds its slab resident. The
+        new replica takes the lowest free slot index."""
+        if source is None:
+            source = self.heal_source(s)
         if device is None and self.devices:
-            tainted = {id(r.device) for r in self.replicas[s]
+            tainted = {id(r.device) for row in self.replicas for r in row
                        if not r.alive and r.device is not None}
-            candidates = [d for d in self.devices if id(d) not in tainted]
-            if not candidates:       # every device saw a death: any port
-                candidates = list(self.devices)
+            holding = {id(r.device) for r in self.live(s)}
+            candidates = (
+                [d for d in self.devices
+                 if id(d) not in tainted and id(d) not in holding]
+                or [d for d in self.devices if id(d) not in tainted]
+                or list(self.devices))
             loads: Dict[int, int] = {}
             for row in self.replicas:
                 for rep in row:
@@ -322,7 +409,10 @@ class ReplicaSet:
             device = min(candidates, key=lambda d: loads.get(id(d), 0))
         used = {r.idx for r in self.replicas[s]}
         idx = next(i for i in itertools.count() if i not in used)
-        rep = self._place(s, idx, device=device)
+        rep = self._place(s, idx, device=device,
+                          source=None if source is None else source.slab)
+        rep.ready = False
+        self.pending.append(rep)
         self.replicas[s].append(rep)
         return rep
 
@@ -491,6 +581,12 @@ class FaultTolerantRetrievalMesh:
             "per-(shard,replica) dispatch wall time (the health monitor's "
             "own observations)", labels=("instance", "shard", "replica"))
         self._lat_children: Dict[Tuple[int, int], object] = {}
+        self._dev_fam = reg.counter(
+            "serve_mesh_device_dispatches_total",
+            "per-replica dispatch attempts by the device holding the slab",
+            labels=("instance", "device"))
+        self._dev_children: Dict[int, object] = {}
+        self._healing: Dict[Tuple[int, int], object] = {}   # open heal spans
         if psi_table is not None:
             self.publish(psi_table)
 
@@ -498,10 +594,21 @@ class FaultTolerantRetrievalMesh:
     def publish(self, psi_table: jax.Array) -> int:
         """Shard, replicate, version, and atomically flip a ψ snapshot
         live (the unstaged path — see :meth:`begin_canary` for the staged
-        rollout). Returns the new version."""
+        rollout). Returns the new version.
+
+        With ``devices``, each shard is cut straight onto the device of
+        its replica 0 and the other replicas copy it from there: no device
+        holds a cut beside the whole table, and a host (numpy) table never
+        lands whole on any device."""
+        first = None
+        if self.devices:
+            first = [self.devices[slab_device_index(
+                s, 0, self.n_replicas, len(self.devices))]
+                for s in range(self.n_shards)]
         version = self._set.publish(
             lambda version: ReplicaSet(
-                shard_psi(psi_table, self.n_shards, version=version),
+                shard_psi(psi_table, self.n_shards, devices=first,
+                          version=version),
                 self.n_replicas, devices=self.devices, policy=self.policy,
             )
         )
@@ -596,17 +703,39 @@ class FaultTolerantRetrievalMesh:
 
     def heal(self) -> List[Tuple[int, int]]:
         """Re-place orphaned capacity: every shard below its replication
-        target gets fresh replicas rebuilt from the authoritative table
-        copy on surviving devices. Returns the new (shard, idx) pairs."""
+        target gets fresh replicas, each copied from a live replica of the
+        shard onto a device with no dead replica and no live copy of it
+        (:meth:`ReplicaSet.replace`). The copies run in the background;
+        each replica is routed once its slab is resident. With a tracer,
+        a ``heal`` span runs from the copy's start to that admission.
+        Returns the new (shard, idx) pairs."""
         rs = self._set.active
         self._m["heals"].inc()
         placed = []
         for s in range(rs.n_shards):
             while len(rs.live(s)) < self.n_replicas:
-                rep = rs.replace(s)
+                src = rs.heal_source(s)
+                src_slab = rs.table.shards[s] if src is None else src.slab
+                rep = rs.replace(s, source=src)
                 self._m["replicas_replaced"].inc()
                 placed.append(rep.key)
+                if self.tracer is not None:
+                    self._healing[rep.key] = self.tracer.begin(
+                        "heal", parent=None, shard=s, replica=rep.idx,
+                        src_device=next(iter(src_slab.devices())).id,
+                        dst_device=rep.device_id,
+                        bytes=int(rep.slab.nbytes))
         return placed
+
+    def _admit(self, rs: ReplicaSet) -> None:
+        """Route re-placed replicas whose slab has become resident."""
+        admitted, dropped = rs.admit_ready()
+        for rep in admitted:
+            rep.admitted_at = self.clock()
+        for rep in admitted + dropped:
+            sp = self._healing.pop(rep.key, None)
+            if sp is not None:
+                self.tracer.end(sp, admitted=rep.alive)
 
     def _replica_latency(self, s: int, idx: int):
         ch = self._lat_children.get((s, idx))
@@ -645,9 +774,15 @@ class FaultTolerantRetrievalMesh:
         retries stay inside ``max_delay``. The whole request is served
         from ONE ReplicaSet snapshot (version-consistent)."""
         rs = self._set.active  # one snapshot end-to-end
+        if rs.pending:
+            self._admit(rs)
         table = rs.table
         k = k or self.k
-        phi_rows = jnp.asarray(phi_rows, jnp.float32)
+        if self.devices and isinstance(phi_rows, np.ndarray):
+            # host rows go straight to each dispatched replica's device
+            phi_rows = phi_rows.astype(np.float32, copy=False)
+        else:
+            phi_rows = jnp.asarray(phi_rows, jnp.float32)
         b = int(phi_rows.shape[0])
         indexes = None
         block_items = self.block_items
@@ -668,10 +803,13 @@ class FaultTolerantRetrievalMesh:
         self._m["queries"].inc()
         budget = self.retry.deadline if budget is None else budget
         parts_s, parts_i, dead = [], [], []
+        # device id -> this query's dispatches; a device still receiving a
+        # heal's copy counts as used, so the query goes elsewhere if it can
+        load: Dict[int, int] = {r.device_id: 1 for r in rs.pending}
         for s in range(table.n_shards):
             out = self._query_shard(
                 rs, s, phi_rows, k, exclude_mask, exclude_ids,
-                block_items, budget, indexes=indexes,
+                block_items, budget, load, indexes=indexes,
             )
             if out is None:
                 dead.append(s)
@@ -701,8 +839,16 @@ class FaultTolerantRetrievalMesh:
         return TopKResult(ms, mi, coverage, ranges)
 
     # ----------------------------------------------------------- internals
+    def _device_dispatches(self, device_id: int):
+        ch = self._dev_children.get(device_id)
+        if ch is None:
+            ch = self._dev_fam.labels(instance=self._inst,
+                                      device=str(device_id))
+            self._dev_children[device_id] = ch
+        return ch
+
     def _query_shard(self, rs, s, phi_rows, k, exclude_mask, exclude_ids,
-                     block_items, budget, indexes=None):
+                     block_items, budget, load, indexes=None):
         """One shard's dispatch with failover + bounded deadline-aware
         retries. Returns (scores, ids) or None (shard unavailable for this
         request — the degradation path). ``indexes`` (IVF mode) swaps the
@@ -717,12 +863,14 @@ class FaultTolerantRetrievalMesh:
             if not live:
                 return None
             attempt += 1
-            rep = rs.pick(s)
+            rep = rs.pick(s, load)
             rep.outstanding += 1
+            load[rep.device_id] = load.get(rep.device_id, 0) + 1
+            self._device_dispatches(rep.device_id).inc()
             sp = None
             if tr is not None:
                 sp = tr.begin("dispatch", shard=s, replica=rep.idx,
-                              attempt=attempt)
+                              attempt=attempt, device=rep.device_id)
             t0 = self.clock()
             try:
                 if self.injector is not None:
@@ -775,6 +923,8 @@ class FaultTolerantRetrievalMesh:
                     self._m["replicas_died"].inc()
                     if self.auto_heal:
                         self.heal()
+                        for r in rs.pending:    # the copies' targets
+                            load.setdefault(r.device_id, 1)
             finally:
                 rep.outstanding -= 1
             # burned latency (real + injected) already exhausted the

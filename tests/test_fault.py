@@ -270,9 +270,13 @@ def test_routing_policies_spread_and_prefer_idle():
     assert rs.pick(0).idx == 0  # idx tiebreak
 
 
-def test_replica_set_places_copies_on_distinct_devices():
-    """The (s + r) % D rotation: copies of one shard must land on
-    different devices whenever R <= D."""
+@pytest.mark.parametrize("n_shards,n_replicas,n_devices",
+                         [(2, 2, 4), (4, 1, 4), (4, 2, 4), (3, 2, 4),
+                          (2, 2, 2)])
+def test_replica_set_places_copies_on_distinct_devices(n_shards, n_replicas,
+                                                       n_devices):
+    """Even placement: every device holds at most ⌈S·R/D⌉ slabs, and
+    copies of one shard land on different devices whenever R <= D."""
     psi = _rand((40, 8), 18)
 
     class FakeDev:  # placement bookkeeping only — never dispatched to
@@ -282,13 +286,19 @@ def test_replica_set_places_copies_on_distinct_devices():
         def __repr__(self):
             return f"dev{self.i}"
 
-    devices = [FakeDev(i) for i in range(4)]
-    table = shard_psi(psi, 4)
+    devices = [FakeDev(i) for i in range(n_devices)]
+    table = shard_psi(psi, n_shards)
     # avoid jax.device_put on fakes: check the placement map only
     rs = ReplicaSet.__new__(ReplicaSet)
-    rs.table, rs.n_replicas, rs.devices = table, 2, devices
-    for s in range(4):
-        assert rs._device_for(s, 0).i != rs._device_for(s, 1).i
+    rs.table, rs.n_replicas, rs.devices = table, n_replicas, devices
+    per_device = [0] * n_devices
+    for s in range(n_shards):
+        devs = [rs._device_for(s, r).i for r in range(n_replicas)]
+        assert len(set(devs)) == len(devs), (s, devs)
+        for d in devs:
+            per_device[d] += 1
+    assert max(per_device) <= -(-n_shards * n_replicas // n_devices), \
+        per_device
 
 
 def test_staged_rollout_promotes_good_and_rolls_back_bad():
