@@ -72,6 +72,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -129,20 +130,30 @@ def _score_and_merge(block_items, k, meta_ref, psi_ref, phi_ref, s_ref,
 
 def _excluded(excl_ids, base, lane):
     """(block_b, block_items) hit mask: candidate ``base + lane`` appears in
-    its row's −1-padded exclude-id list (block_b, L_pad).
+    its row's exclude-id list (block_b, L_pad), which the wrapper sorts
+    ascending, so the −1 pads come first.
 
-    One list column per loop step, picked by a one-hot lane select and a
-    lane reduction (Mosaic lowers neither a dynamic lane slice nor the 3-D
-    (block_b, L_pad, block_items) broadcast-compare), then compared against
-    the tile's local positions."""
+    A row's ids inside the tile ``[base, base + block_items)`` are then one
+    run of list columns: it starts after the ``lo`` ids below the tile and
+    holds ``cnt`` ids. The walk visits only those columns: as many rounds
+    as the block's fullest row has ids in this tile, not ``L_pad``, and
+    none where the block has no id in it. Each round picks a column by a
+    one-hot lane select and a lane reduction (Mosaic lowers neither a
+    dynamic lane slice nor the 3-D (block_b, L_pad, block_items)
+    broadcast-compare), then compares it against the tile's local
+    positions; a row whose run is shorter marks nothing."""
     pos = jnp.where(excl_ids >= 0, excl_ids - base, -1)   # tile-local slot
     col_lane = jax.lax.broadcasted_iota(jnp.int32, pos.shape, 1)
+    lo = jnp.sum((pos < 0).astype(jnp.int32), axis=1, keepdims=True)
+    cnt = jnp.sum(((pos >= 0) & (pos < lane.shape[1])).astype(jnp.int32),
+                  axis=1, keepdims=True)
 
-    def one(col, hit):
-        p = jnp.sum(jnp.where(col_lane == col, pos, 0), axis=1, keepdims=True)
-        return jnp.where(lane == p, 1, hit)
+    def one(j, hit):
+        p = jnp.sum(jnp.where(col_lane == lo + j, pos, 0), axis=1,
+                    keepdims=True)
+        return jnp.where(lane == jnp.where(j < cnt, p, -1), 1, hit)
 
-    hit = jax.lax.fori_loop(0, pos.shape[1], one,
+    hit = jax.lax.fori_loop(0, jnp.max(cnt), one,
                             jnp.zeros(lane.shape, jnp.int32))
     return hit > 0
 
@@ -342,11 +353,12 @@ def topk_score_pallas(
     elif exclude_ids is not None:
         excl_kind = 2
         in_specs.append(pl.BlockSpec((block_b, l_pad), lambda bb, ii: (bb, 0)))
-        args.append(jnp.pad(
+        # sorted rows, pads first: each tile's ids are one run (_excluded)
+        args.append(jnp.sort(jnp.pad(
             exclude_ids.astype(jnp.int32),
             ((0, b_pad - b), (0, l_pad - exclude_ids.shape[1])),
             constant_values=-1,
-        ))
+        ), axis=1))
 
     scores, ids = pl.pallas_call(
         partial(_topk_kernel, block_items, k, psi_scale is not None,
@@ -358,3 +370,33 @@ def topk_score_pallas(
         interpret=interpret,
     )(*args)
     return scores[:b, :k], ids[:b, :k]
+
+
+def excl_walk_rounds(exclude_ids, n_rows: int, block_items: int, *,
+                     id_offset: int = 0, block_b: int = 128) -> tuple:
+    """``(rounds, rounds_full)`` of the exclude-id walk in one
+    :func:`topk_score_pallas` call over ``n_rows`` ψ rows, counted on the
+    host from the (B, L) id lists (numpy; a device array is read back).
+
+    ``rounds`` is what :func:`_excluded` runs: for each row block and ψ
+    tile, the most ids one of the block's rows has in that tile.
+    ``rounds_full`` is what a walk over every list column runs: row blocks
+    × tiles × L_pad. ``block_b`` is the call's (before it is cut to the
+    batch), ``block_items`` its resolved tile."""
+    ids = np.asarray(exclude_ids, np.int64)
+    b, l = ids.shape
+    block_b = min(block_b, -(-b // 8) * 8)
+    n_blocks = -(-b // block_b)
+    n_tiles = -(-n_rows // block_items)
+    l_pad = -(-max(1, l) // 128) * 128
+    full = n_blocks * n_tiles * l_pad
+    local = ids - id_offset
+    rows, cols = np.nonzero((ids >= 0) & (local >= 0)
+                            & (local < n_tiles * block_items))
+    tile = local[rows, cols] // block_items
+    # ids per (row, tile), then the fullest row of each (row block, tile)
+    row_tile, per_row = np.unique(rows * n_tiles + tile, return_counts=True)
+    most = np.zeros(n_blocks * n_tiles, np.int64)
+    np.maximum.at(most, row_tile // n_tiles // block_b * n_tiles
+                  + row_tile % n_tiles, per_row)
+    return int(most.sum()), full

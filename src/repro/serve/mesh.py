@@ -91,7 +91,7 @@ from repro.serve.cluster import (
     shard_psi,
     shard_topk,
 )
-from repro.kernels.topk_score.ops import topk_merge_shards
+from repro.kernels.topk_score import excl_walk_rounds, topk_merge_shards
 
 
 # ------------------------------------------------------------------ failures
@@ -869,8 +869,14 @@ class FaultTolerantRetrievalMesh:
             self._device_dispatches(rep.device_id).inc()
             sp = None
             if tr is not None:
+                walk = {}
+                if exclude_ids is not None and indexes is None:
+                    rows = rs.table.rows_per
+                    walk["excl_rounds"], walk["excl_rounds_full"] = (
+                        excl_walk_rounds(exclude_ids, rows, block_items,
+                                         id_offset=s * rows))
                 sp = tr.begin("dispatch", shard=s, replica=rep.idx,
-                              attempt=attempt, device=rep.device_id)
+                              attempt=attempt, device=rep.device_id, **walk)
             t0 = self.clock()
             try:
                 if self.injector is not None:

@@ -498,6 +498,75 @@ class TestMeshInstrumentation:
         assert sum(ch.count for ch in fam.children()) == 2
 
 
+def _walk_rounds_brute(ids, n_rows, block_items, off, block_b):
+    """The exclude-id walk's rounds, tile by tile and row by row."""
+    b, l = ids.shape
+    block_b = min(block_b, -(-b // 8) * 8)
+    n_tiles = -(-n_rows // block_items)
+    rounds = 0
+    for lo in range(0, b, block_b):
+        for t in range(n_tiles):
+            base = off + t * block_items
+            inside = (ids[lo:lo + block_b] >= base) & (
+                ids[lo:lo + block_b] < base + block_items)
+            rounds += int(inside.sum(axis=1).max())
+    return rounds, -(-b // block_b) * n_tiles * (-(-l // 128) * 128)
+
+
+class TestExclWalkRounds:
+    @pytest.mark.parametrize("b, l, n_rows, block_items, off, block_b", [
+        (5, 128, 640, 128, 0, 128),       # one row block, lists of a lane
+        (21, 200, 1000, 256, 0, 8),       # three row blocks, two lanes
+        (16, 60, 900, 128, 500, 16),      # a shard: ids below and above it
+    ])
+    def test_matches_brute_force(self, b, l, n_rows, block_items, off,
+                                 block_b):
+        from repro.kernels.topk_score import excl_walk_rounds
+
+        rng = np.random.default_rng(b * l)
+        ids = rng.integers(-1, off + n_rows + 300, size=(b, l))
+        ids[rng.random((b, l)) < 0.25] = -1
+        ids[0, : min(l, 40)] = off + np.arange(min(l, 40))   # one tile
+        got = excl_walk_rounds(ids, n_rows, block_items, id_offset=off,
+                               block_b=block_b)
+        assert got == _walk_rounds_brute(ids, n_rows, block_items, off,
+                                         block_b)
+        assert 0 < got[0] <= got[1]
+
+    def test_traced_dispatch_carries_rounds_untraced_counts_none(
+            self, monkeypatch):
+        from repro.serve import mesh as mesh_mod
+
+        calls = []
+        real = mesh_mod.excl_walk_rounds
+        monkeypatch.setattr(mesh_mod, "excl_walk_rounds",
+                            lambda *a, **kw: calls.append(1) or real(*a, **kw))
+
+        def flush(tracer):
+            phi, _, mesh = _mesh_pair(tracer=tracer)
+            b = MicroBatcher(
+                lambda rows, eids: mesh.topk_phi(rows, exclude_ids=eids),
+                max_batch=4, max_delay=1.0, version_fn=lambda: mesh.version,
+                registry=NULL_REGISTRY, tracer=tracer, host_inputs=True)
+            for r in range(3):
+                b.submit(np.asarray(phi[r]), exclude=[r, 50 + r, 90])
+            b.flush()
+
+        flush(None)
+        assert calls == []
+        tr = Tracer()
+        flush(tr)
+        disp = [sp for sp in tr.spans if sp.name == "dispatch"]
+        assert len(disp) == 2 == len(calls)        # one per shard
+        for sp in disp:
+            assert 0 < sp.attrs["excl_rounds"] <= sp.attrs["excl_rounds_full"]
+        # 48-row shards in two 32-item tiles. Shard 0: each row's id r in
+        # tile 0, and 50 + r in its padded tail tile [32, 64), which the
+        # walk visits too. Shard 1: 50 + r in tile 0, 90 in tile 1.
+        assert [sp.attrs["excl_rounds"] for sp in disp] == [2, 2]
+        assert disp[0].attrs["excl_rounds_full"] == 2 * 128
+
+
 # ------------------------------------------------------------------- export
 class TestExport:
     def _populated(self):

@@ -211,3 +211,72 @@ def test_topk_mismatches_forgives_only_near_ties():
     got_i = np.asarray([[7, 9, 4, -1]], np.int32)
     assert topk_mismatches(bad_s, got_i, ref_s, ref_i) == {
         "score_mismatches": 1, "id_mismatches": 1}
+
+
+def _walk_case(case):
+    """Inputs of one exclude-id walk case: (phi, psi, psi_scale, ids,
+    id_offset, n_valid, block_b); tiles are 128 items."""
+    rng = np.random.default_rng(41)
+    b, n_rows, off, n_valid, l, block_b = 5, 640, 0, 640, 128, 128
+    if case == "several_row_blocks":
+        b, block_b = 21, 8
+    if case == "n_valid_short":
+        n_valid = 555
+    if case == "other_shards":
+        off = 1000
+    if case == "l_not_lane_multiple":
+        l = 200
+    ids = np.full((b, l), -1, np.int64)
+    for r in range(b):
+        ids[r, : l - 7 * r % 40] = rng.choice(
+            np.arange(off, off + n_rows), size=l - 7 * r % 40, replace=False)
+    if case == "one_tile":        # every id of row 1 in tile 2: all rounds
+        ids[1] = np.arange(256, 384)
+    if case == "straddle":        # ids on both sides of tile boundaries
+        ids[:, :12] = np.r_[122:134][None] + 128 * (np.arange(b) % 4)[:, None]
+    if case == "unsorted_duplicated":
+        ids[:, 40:80] = ids[:, :40]
+        ids = rng.permuted(ids, axis=1)
+    if case == "all_pad_rows":
+        ids[[0, 3]] = -1
+    if case == "other_shards":    # ids of the shards below and above
+        ids[:, ::3] = rng.integers(0, 3000, size=ids[:, ::3].shape)
+    phi, psi = _rand((b, 32), 42), _rand((n_rows, 32), 43)
+    scale = None
+    if case == "bf16":
+        psi = psi.astype(jnp.bfloat16)
+    if case == "int8":
+        from repro.core.quant import int8_quantize_rows
+
+        psi, scale = int8_quantize_rows(psi)
+    return phi, psi, scale, jnp.asarray(ids, jnp.int32), off, n_valid, block_b
+
+
+@pytest.mark.parametrize("case", [
+    "one_tile", "straddle", "unsorted_duplicated", "all_pad_rows",
+    "other_shards", "n_valid_short", "l_not_lane_multiple",
+    "several_row_blocks", "bf16", "int8"])
+def test_exclude_id_walk_matches_mask_and_ref(case):
+    """The exclude-id form, which walks only each tile's ids, returns what
+    the dense-mask form and the dense reference return, bit for bit."""
+    phi, psi, scale, ids, off, n_valid, block_b = _walk_case(case)
+    k, n_rows = 40, psi.shape[0]
+    kw = dict(psi_scale=scale, id_offset=off, n_valid=n_valid,
+              block_b=block_b, block_items=128)
+    s, i = topk_score(phi, psi, k, exclude_ids=ids, **kw)
+    local = np.asarray(ids) - off
+    in_shard = (np.asarray(ids) >= 0) & (local >= 0) & (local < n_rows)
+    mask = np.zeros((phi.shape[0], n_rows), np.int8)
+    mask[np.nonzero(in_shard)[0], local[in_shard]] = 1
+    ms, mi = topk_score(phi, psi, k, jnp.asarray(mask), **kw)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(mi))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(ms))
+    deq = psi.astype(jnp.float32)
+    if scale is not None:
+        deq = deq * scale[:, None]
+    rs, ri = topk_score_ref(phi, deq[:n_valid], k,
+                            exclude_ids=np.where(in_shard, local, -1))
+    ri = np.asarray(ri)
+    np.testing.assert_array_equal(np.asarray(i), np.where(ri >= 0, ri + off,
+                                                          -1))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(rs))

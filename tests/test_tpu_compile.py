@@ -55,17 +55,20 @@ def _s(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("variant", ["plain", "exclude_ids", "int8"])
+@pytest.mark.parametrize("variant", ["plain", "exclude_ids", "int8",
+                                     "exclude_ids_l200"])
 def test_topk_score_compiles(one_chip, variant):
     s = lambda shape, dt=jnp.float32: _s(one_chip, shape, dt)  # noqa: E731
     phi, psi = s((B, D)), s((N_ITEMS, D))
     if variant == "plain":
         fn = lambda p, q: topk_score_pallas(p, q, K, interpret=False)  # noqa: E731
         compile_holds_kernel(fn, phi, psi)
-    elif variant == "exclude_ids":
+    elif variant.startswith("exclude_ids"):
+        # 68k items are 17 tiles of 4,096; 200 ids are two lanes of the list
         fn = lambda p, q, e: topk_score_pallas(  # noqa: E731
             p, q, K, exclude_ids=e, interpret=False)
-        compile_holds_kernel(fn, phi, psi, s((B, 128), jnp.int32))
+        l = 200 if variant.endswith("l200") else 128
+        compile_holds_kernel(fn, phi, psi, s((B, l), jnp.int32))
     else:
         fn = lambda p, q, sc: topk_score_pallas(  # noqa: E731
             p, q, K, psi_scale=sc, interpret=False)
