@@ -15,9 +15,9 @@ Tracks ``BENCH_topk_score.json`` at the repo root:
     so the numbers are not emulation-bound), with every routed result
     HARD-asserted against the per-row dense oracle;
   * HARD parity asserts — streaming kernel vs dense ``lax.top_k`` ids for
-    every k-separable model, with and without exclude masks, the sharded
-    cluster vs the single-device engine (ids AND scores bit-identical at
-    shard counts {1,2,3,4}), plus the streaming ranking-eval harness vs
+    every k-separable model, with and without exclude-id lists, the sharded
+    serving mesh vs one kernel call on a single device (ids AND scores
+    bit-identical at shard counts {1,2,3,4}), plus the streaming ranking-eval harness vs
     dense metrics. A broken kernel, merge, or export contract fails the
     whole bench (the CI serve-smoke gate);
   * HARD fault-tolerance asserts (``serve/mesh.py``) — replica kills under
@@ -110,12 +110,13 @@ def _zoo_models(quick: bool):
     }
 
 
-def _assert_topk_parity(name, phi, psi, k, exclude_mask=None, block_items=32):
+def _assert_topk_parity(name, phi, psi, k, exclude_ids=None, block_items=32):
     """Streaming kernel vs dense lax.top_k/oracle: ids exact, scores close."""
     from repro.kernels.topk_score import topk_score, topk_score_ref
 
-    s, i = topk_score(phi, psi, k, exclude_mask, block_items=block_items)
-    rs, ri = topk_score_ref(phi, psi, k, exclude_mask)
+    s, i = topk_score(phi, psi, k, exclude_ids=exclude_ids,
+                      block_items=block_items)
+    rs, ri = topk_score_ref(phi, psi, k, exclude_ids=exclude_ids)
     if not (np.asarray(i) == np.asarray(ri)).all():
         raise AssertionError(f"serve bench parity FAILED for {name}: top-k ids "
                              "diverge from the dense oracle")
@@ -124,7 +125,7 @@ def _assert_topk_parity(name, phi, psi, k, exclude_mask=None, block_items=32):
                        rtol=1e-5, atol=1e-6):
         raise AssertionError(f"serve bench parity FAILED for {name}: top-k "
                              "scores diverge from the dense oracle")
-    if exclude_mask is None:
+    if exclude_ids is None:
         ds, di = jax.lax.top_k(phi @ psi.T, min(k, psi.shape[0]))
         if not (np.asarray(i)[:, : di.shape[1]] == np.asarray(di)).all():
             raise AssertionError(f"serve bench parity FAILED for {name}: ids "
@@ -132,66 +133,58 @@ def _assert_topk_parity(name, phi, psi, k, exclude_mask=None, block_items=32):
 
 
 def _zoo_parity(quick: bool) -> Dict[str, dict]:
-    """Every model through its export_psi/build_phi contract, masked and
-    unmasked, against the dense path."""
-    from repro.serve.engine import exclude_mask_from_lists
+    """Every model through its export_psi/build_phi contract, with and
+    without exclusion, against the dense path."""
+    from repro.kernels.topk_score.ops import exclude_ids_from_lists
 
     rng = np.random.default_rng(0)
     topk = 10 if quick else 100
     out = {}
     for name, (phi, psi) in _zoo_models(quick).items():
-        excl = exclude_mask_from_lists(
+        excl = exclude_ids_from_lists(
             [rng.choice(psi.shape[0], size=min(5, psi.shape[0] // 2),
                         replace=False) for _ in range(phi.shape[0])],
-            psi.shape[0],
         )
         kk = min(topk, psi.shape[0])
         _assert_topk_parity(name, phi, psi, kk)
-        _assert_topk_parity(f"{name}+mask", phi, psi, kk, excl)
+        _assert_topk_parity(f"{name}+exclude", phi, psi, kk, excl)
         out[name] = {"parity_ok": True, "d": int(phi.shape[1]),
                      "n_items": int(psi.shape[0]), "k": kk}
     return out
 
 
 def _cluster_parity(quick: bool) -> Dict[str, dict]:
-    """Sharded cluster vs single-device engine vs dense oracle: ids AND
-    scores BIT-identical for every model at shard counts {1, 2, 3, 4},
-    with and without per-row exclusion — the acceptance gate of the
-    sharded serving tier."""
-    from repro.kernels.topk_score import topk_score_ref
-    from repro.serve.cluster import ShardedRetrievalCluster
-    from repro.serve.engine import (
-        RetrievalEngine,
-        exclude_ids_from_lists,
-        exclude_mask_from_lists,
-    )
+    """Sharded serving mesh vs one kernel call on a single device vs the
+    dense oracle: ids AND scores BIT-identical for every model at shard
+    counts {1, 2, 3, 4}, with and without per-row exclusion — the
+    acceptance gate of the sharded serving tier."""
+    from repro.kernels.topk_score import topk_score, topk_score_ref
+    from repro.kernels.topk_score.ops import exclude_ids_from_lists
+    from repro.serve.mesh import FaultTolerantRetrievalMesh
 
     rng = np.random.default_rng(7)
     topk = 10 if quick else 100
     out = {}
     for name, (phi, psi) in _zoo_models(quick).items():
         kk = min(topk, psi.shape[0])
-        engine = RetrievalEngine(psi, lambda p=phi: p, k=kk, block_items=32)
-        es, ei = engine.topk_phi(phi)
+        es, ei = topk_score(phi, psi, kk, block_items=32)
         lists = [rng.choice(psi.shape[0], size=min(5, psi.shape[0] // 2),
                             replace=False) for _ in range(phi.shape[0])]
         eids = exclude_ids_from_lists(lists)
-        es2, ei2 = engine.topk_phi(phi, exclude_ids=eids)
-        rs2, ri2 = topk_score_ref(
-            phi, psi, kk, exclude_mask_from_lists(lists, psi.shape[0])
-        )
+        es2, ei2 = topk_score(phi, psi, kk, exclude_ids=eids, block_items=32)
+        rs2, ri2 = topk_score_ref(phi, psi, kk, exclude_ids=eids)
         for n_shards in (1, 2, 3, 4):
-            cl = ShardedRetrievalCluster(
-                lambda p=phi: p, n_shards=n_shards, k=kk, block_items=32,
-                psi_table=psi,
+            cl = FaultTolerantRetrievalMesh(
+                lambda p=phi: p, n_shards=n_shards, n_replicas=1, k=kk,
+                block_items=32, psi_table=psi,
             )
             cs, ci = cl.topk_phi(phi)
             if not ((np.asarray(ci) == np.asarray(ei)).all()
                     and (np.asarray(cs) == np.asarray(es)).all()):
                 raise AssertionError(
-                    f"serve bench parity FAILED for {name}: cluster "
-                    f"(n_shards={n_shards}) is not bit-identical to the "
-                    "single-device engine"
+                    f"serve bench parity FAILED for {name}: mesh "
+                    f"(n_shards={n_shards}) is not bit-identical to one "
+                    "kernel call on a single device"
                 )
             cs2, ci2 = cl.topk_phi(phi, exclude_ids=eids)
             if not ((np.asarray(ci2) == np.asarray(ri2)).all()
@@ -208,24 +201,24 @@ def _cluster_parity(quick: bool) -> Dict[str, dict]:
 
 def _batcher_bench(quick: bool) -> dict:
     """Open-loop single-row arrival trace through the micro-batcher over a
-    sharded cluster (simulated clock). Queue wait comes from the flush
+    sharded mesh (simulated clock). Queue wait comes from the flush
     policy; service time from the analytic per-shard traffic model (NOT
     interpret-mode wall clock). Every routed result is hard-asserted
     against the per-row dense oracle — the out-of-order-routing gate."""
     from repro.core.models import mf
     from repro.kernels.topk_score import topk_score_ref
+    from repro.kernels.topk_score.ops import exclude_ids_from_lists
     from repro.serve.batcher import MicroBatcher
-    from repro.serve.cluster import ShardedRetrievalCluster
-    from repro.serve.engine import exclude_ids_from_lists
+    from repro.serve.mesh import FaultTolerantRetrievalMesh
 
     rng = np.random.default_rng(11)
     n_ctx, n_items, k, kk = (64, 40, 8, 10) if quick else (512, 4096, 32, 100)
     n_requests = 64 if quick else 512
     n_shards, max_batch, max_delay = 2, 8, 2e-3
     params = mf.init(jax.random.PRNGKey(6), n_ctx, n_items, k)
-    cluster = ShardedRetrievalCluster(
+    cluster = FaultTolerantRetrievalMesh(
         lambda ctx: mf.build_phi(params, ctx), n_shards=n_shards,
-        k=min(kk, n_items), block_items=32,
+        n_replicas=1, k=min(kk, n_items), block_items=32,
         psi_table=mf.export_psi(params),
     )
     clock = {"t": 0.0}
@@ -538,8 +531,9 @@ def _eval_harness_parity(quick: bool) -> dict:
     from repro.core.metrics import ndcg_at_k, recall_at_k
     from repro.core.models import mf
     from repro.eval.ranking import ranking_eval
-    from repro.serve.cluster import ShardedRetrievalCluster
-    from repro.serve.engine import exclude_mask_from_lists
+    from repro.kernels.topk_score.ops import exclude_ids_from_lists
+    from repro.kernels.topk_score.ref import exclude_ids_to_mask
+    from repro.serve.mesh import FaultTolerantRetrievalMesh
 
     rng = np.random.default_rng(1)
     n_eval, n_items, k, topk = (32, 80, 8, 10) if quick else (256, 2048, 32, 100)
@@ -550,7 +544,7 @@ def _eval_harness_parity(quick: bool) -> dict:
     psi = mf.export_psi(params)
     res = ranking_eval(phi, psi, truth, k=topk, batch_rows=max(8, n_eval // 3),
                        exclude=excl, block_items=32)
-    mask = exclude_mask_from_lists(excl, n_items)
+    mask = exclude_ids_to_mask(exclude_ids_from_lists(excl), n_items)
     dense = phi @ psi.T
     r = float(recall_at_k(dense, jnp.asarray(truth), topk, mask))
     n = float(ndcg_at_k(dense, jnp.asarray(truth), topk, mask))
@@ -561,9 +555,9 @@ def _eval_harness_parity(quick: bool) -> dict:
             f"serve bench parity FAILED for ranking_eval: streaming "
             f"({res}) vs dense (recall={r}, ndcg={n})"
         )
-    # sharded eval over the cluster: same metrics past one device's HBM
-    cl = ShardedRetrievalCluster(n_shards=3, k=topk, block_items=32,
-                                 psi_table=psi)
+    # sharded eval over the mesh: same metrics past one device's HBM
+    cl = FaultTolerantRetrievalMesh(n_shards=3, n_replicas=1, k=topk,
+                                    block_items=32, psi_table=psi)
     res_sh = ranking_eval(phi, None, truth, k=topk,
                           batch_rows=max(8, n_eval // 3), exclude=excl,
                           cluster=cl)
@@ -591,9 +585,9 @@ def _obs_bench(quick: bool) -> dict:
         the bare run).
     """
     from repro.obs import MetricsRegistry, Tracer, trace_for_ticket
+    from repro.kernels.topk_score import topk_score
     from repro.obs.metrics import NULL_REGISTRY
     from repro.serve.batcher import MicroBatcher
-    from repro.serve.engine import RetrievalEngine
     from repro.serve.mesh import (
         FaultInjector,
         FaultTolerantRetrievalMesh,
@@ -727,15 +721,14 @@ def _obs_bench(quick: bool) -> dict:
             f"serve bench FAILED: ticket trace spans {sorted(names)} miss "
             f"{sorted(need - names)}"
         )
-    healthy = RetrievalEngine(psi, lambda p=phi: p, k=kk,
-                              block_items=32).topk_phi(phi)
-    if not ((np.asarray(killed.ids) == np.asarray(healthy.ids)).all()
+    healthy_s, healthy_i = topk_score(phi, psi, kk, block_items=32)
+    if not ((np.asarray(killed.ids) == np.asarray(healthy_i)).all()
             and (np.asarray(killed.scores)
-                 == np.asarray(healthy.scores)).all()):
+                 == np.asarray(healthy_s)).all()):
         raise AssertionError(
             "serve bench FAILED: traced+killed mesh diverges from the "
-            "healthy engine — failover must stay bit-invisible under "
-            "instrumentation"
+            "healthy single-device kernel — failover must stay "
+            "bit-invisible under instrumentation"
         )
     obs_trace_ok = True
     return {
@@ -865,8 +858,8 @@ def serve_topk_bench(quick: bool = True, out_path: Optional[str] = None) -> dict
             "target":">= 2x fewer HBM bytes per retrieval batch at B >= 256 "
                       "(analytic; scores never leave VMEM); streaming top-K "
                       "== dense lax.top_k ids for every k-separable model "
-                      "incl. exclude masks; sharded cluster bit-identical "
-                      "to the single-device engine at shard counts 1-4 "
+                      "incl. exclude-id lists; sharded mesh bit-identical "
+                      "to one single-device kernel call at shard counts 1-4 "
                       "(<= 1.05x byte overhead at S=4); batcher routes "
                       "out-of-order requests exactly; streaming ranking-eval "
                       "== dense metrics without a (n_eval, n_items) array, "

@@ -23,9 +23,9 @@ paper's §6 20M-interaction set, generated from ``--seed``):
 
 ``--chips 4`` runs only the multi-chip serving paths — 4 shards x 1
 replica, 2 shards x 2 replicas with one replica killed, and
-``shard_map_topk`` over a 4-device mesh — each against the single-device
-``RetrievalEngine`` on device 0 (ids equal, scores bit-identical), with the
-shard slabs on distinct devices.
+``shard_map_topk`` over a 4-device mesh — each against one ``topk_score``
+call over the whole table on device 0 (ids equal, scores bit-identical),
+with the shard slabs on distinct devices.
 
 Every check that fails exits non-zero. Only when all pass is the last line
 of stdout ``{"ok": true, "device": {"platform", "kind", "count"}}``.
@@ -326,8 +326,8 @@ def four_chip_phase(cfg, seed: int):
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.serve.cluster import shard_map_topk, shard_psi
-    from repro.serve.engine import RetrievalEngine
+    from repro.kernels.topk_score import topk_score
+    from repro.serve.cluster import TopKResult, shard_map_topk, shard_psi
     from repro.serve.mesh import FaultInjector, FaultTolerantRetrievalMesh
 
     k, b, n_excl = 100, N_REQUESTS, 128
@@ -345,10 +345,11 @@ def four_chip_phase(cfg, seed: int):
         jax.block_until_ready((res.scores, res.ids))
         return res
 
-    engine = RetrievalEngine(jax.device_put(psi, devs[0]), None, k=k)
     t0 = now()
-    base = ready(engine.topk_phi(phi, exclude_ids=eids))
-    print(f"[4] single-device engine on {devs[0]}: {now() - t0:.3f}s",
+    base = ready(TopKResult(*topk_score(
+        jax.device_put(phi, devs[0]), jax.device_put(psi, devs[0]), k,
+        exclude_ids=jax.device_put(eids, devs[0]))))
+    print(f"[4] single-device topk_score on {devs[0]}: {now() - t0:.3f}s",
           flush=True)
 
     def same(res, name):
@@ -358,7 +359,7 @@ def four_chip_phase(cfg, seed: int):
               f"scores differ at {int((s != bs).sum())} slots, max "
               f"|diff| {float(np.nanmax(np.abs(np.where(np.isinf(bs), 0, s - bs))))!r}",
               flush=True)
-        check(np.array_equal(i, bi), f"{name}: ids equal the engine's")
+        check(np.array_equal(i, bi), f"{name}: ids equal one device's")
         check(np.array_equal(s, bs), f"{name}: scores bit-identical")
 
     def slab_devices(mesh):

@@ -28,7 +28,7 @@ from repro.core.sweeps import SweepSchedule
 from repro.data.loader import interaction_stream
 from repro.data.synthetic import make_implicit_dataset
 from repro.eval.ranking import foldin_ranking_eval
-from repro.serve.cluster import ShardedRetrievalCluster
+from repro.serve.mesh import FaultTolerantRetrievalMesh
 from repro.serve.publish import PsiPublisher
 from repro.sparse.interactions import build_interactions
 
@@ -69,12 +69,13 @@ def main():
             )
         return jnp.asarray(psi)
 
-    cluster = ShardedRetrievalCluster(
-        lambda ctx: model.build_phi(params, ctx), n_shards=2, k=10,
+    mesh = FaultTolerantRetrievalMesh(
+        lambda ctx: model.build_phi(params, ctx), n_shards=2, n_replicas=1,
+        k=10,
     )
-    pub = PsiPublisher(cluster, export, every=1)
+    pub = PsiPublisher(mesh, export, every=1)
     pub(0, params)
-    print(f"live: psi v{cluster.version}, {cluster.n_items} items")
+    print(f"live: psi v{mesh.version}, {mesh.n_items} items")
 
     # --- continual phase: replay the tail as arriving traffic ------------
     # cold items were OBSERVED in the head (just excluded from training),
@@ -88,8 +89,8 @@ def main():
         # delta-append every cold item whose id is next in line and has
         # any history — appends must stay hole-free (see apply_delta)
         nonlocal folded_items
-        while item_hist.get(cluster.n_items):
-            i = cluster.n_items
+        while item_hist.get(mesh.n_items):
+            i = mesh.n_items
             row = np.asarray(model.fold_in_item(params, item_hist[i]))
             extra[i] = row
             pub.publish_delta(row, i)
@@ -109,19 +110,19 @@ def main():
                 # the frozen warm ψ — no training state touched
                 hist = hists[u][hists[u] < n_warm_items][:3]
                 phi = model.fold_in_user(params, hist)
-                res = cluster.topk_phi(jnp.asarray(phi, jnp.float32)[None])
+                res = mesh.topk_phi(jnp.asarray(phi, jnp.float32)[None])
                 assert res.ids.shape[1] == 10
                 folded_users += 1
         # subspace-scheduled warm refresh: ONE rotating k_b-block per
         # publish — a k_b/k fraction of a full epoch's column updates
         sched = SweepSchedule(kind="rotating", block=4, blocks_per_sweep=1)
         params, _ = model.epoch(params, model.residuals(params),
-                                schedule=sched, sweep_index=cluster.version)
-        pub(cluster.version, params)
+                                schedule=sched, sweep_index=mesh.version)
+        pub(mesh.version, params)
     print(f"continual: {folded_users} fold-in queries answered, "
           f"{folded_items} items delta-published "
           f"(versions {[v for v, _ in pub.deltas]}), now at "
-          f"v{cluster.version} with {cluster.n_items} items")
+          f"v{mesh.version} with {mesh.n_items} items")
 
     # --- cold-start eval: every eval user folded in from scratch ---------
     observed, true_items = [], []

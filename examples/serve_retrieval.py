@@ -1,17 +1,17 @@
 """Serving: the sharded online retrieval service end-to-end — train an
-iCD-MF model, publish its ψ table into a multi-shard cluster at every epoch
-boundary (double-buffered, versioned), answer micro-batched single-row
-queries through the admission queue, run the streaming leave-one-out
-ranking eval over the same sharded table, then harden it: replicate the
-shards into a fault-tolerant mesh, kill replicas mid-traffic (bit-identical
-failover under R=2, labeled degradation when a range loses every copy),
-heal, and gate a ψ publish behind the canary staged rollout.
+iCD-MF model, publish its ψ table into a multi-shard serving mesh at every
+epoch boundary (double-buffered, versioned), answer micro-batched
+single-row queries through the admission queue, run the streaming
+leave-one-out ranking eval over the same sharded table, then harden it:
+replicate the shards, kill replicas mid-traffic (bit-identical failover
+under R=2, labeled degradation when a range loses every copy), heal, and
+gate a ψ publish behind the canary staged rollout.
 
 Every path is the paper-native k-separable product ⟨φ(context), ψ(item)⟩
 (§5.1): per shard the fused Pallas score+top-k kernel streams ψ-table
 blocks through VMEM with a running top-K merge (the (B, n_items) score
 matrix is never materialized), and the cross-shard K-way merge reproduces
-the single-device engine bit-for-bit.
+the dense top-k.
 
     PYTHONPATH=src python examples/serve_retrieval.py
 """
@@ -24,8 +24,6 @@ import numpy as np
 from repro.core.models import mf
 from repro.eval.ranking import ranking_eval
 from repro.serve.batcher import MicroBatcher
-from repro.serve.cluster import ShardedRetrievalCluster
-from repro.serve.engine import RetrievalEngine
 from repro.serve.mesh import (
     FaultInjector,
     FaultTolerantRetrievalMesh,
@@ -47,46 +45,42 @@ def main():
         cells // n_items, cells % n_items, rng.integers(1, 5, nnz),
         1.0 + rng.random(nnz), n_users, n_items, alpha0=0.1,
     )
-    cluster = ShardedRetrievalCluster(
-        lambda ctx: mf.build_phi(params, ctx), n_shards=n_shards, k=100
+    sharded = FaultTolerantRetrievalMesh(
+        lambda ctx: mf.build_phi(params, ctx), n_shards=n_shards,
+        n_replicas=1, k=100,
     )
-    pub = PsiPublisher(cluster, mf.export_psi, every=1)
+    pub = PsiPublisher(sharded, mf.export_psi, every=1)
     hp = mf.MFHyperParams(k=k, alpha0=0.1, l2=0.05)
     params = mf.fit(params, data, hp, n_epochs=2, callback=pub)
-    cluster.phi_fn = lambda ctx: mf.build_phi(params, ctx)
+    sharded.phi_fn = lambda ctx: mf.build_phi(params, ctx)
     print(f"published versions {[v for _, v in pub.versions]}: "
           f"{n_items} items over {n_shards} shards "
-          f"(rows_per={cluster.table.rows_per})")
+          f"(rows_per={sharded.table.rows_per})")
 
     # --- batched online queries over the sharded table -------------------
     for batch in (8, 64):
         ctx = jnp.arange(batch)
-        _, warm_ids = cluster.topk(ctx)  # warmup (trace+compile)
+        _, warm_ids = sharded.topk(ctx)  # warmup (trace+compile)
         jax.block_until_ready(warm_ids)
         t0 = time.perf_counter()
-        scores, ids = cluster.topk(ctx)
+        scores, ids = sharded.topk(ctx)
         jax.block_until_ready(ids)
         dt = time.perf_counter() - t0
         print(f"batch={batch:3d}: {dt * 1e3:7.2f} ms "
               f"({batch * n_items / dt / 1e6:.1f} M cand/s over "
               f"{n_shards} shards)")
 
-    # --- sharded cluster vs single-device engine vs dense lax.top_k ------
-    engine = RetrievalEngine(
-        mf.export_psi(params), lambda ctx: mf.build_phi(params, ctx), k=100
-    )
-    cs, ci = cluster.topk(jnp.arange(8))
-    es, ei = engine.topk(jnp.arange(8))
-    assert bool((ci == ei).all()) and bool((cs == es).all())
+    # --- sharded mesh vs dense lax.top_k ---------------------------------
+    cs, ci = sharded.topk(jnp.arange(8))
     dense = jax.lax.top_k(params.w[:8] @ params.h.T, 100)[1]
     assert bool((ci == dense).all())
-    print("cluster top-k == engine top-k == dense top-k ✓")
+    print("mesh top-k == dense top-k ✓")
 
     # --- micro-batched single-row requests (the online p99 path) ---------
     batcher = MicroBatcher(
-        lambda phi, eids: cluster.topk_phi(phi, exclude_ids=eids),
+        lambda phi, eids: sharded.topk_phi(phi, exclude_ids=eids),
         max_batch=16, max_delay=2e-3,
-        version_fn=lambda: cluster.version,
+        version_fn=lambda: sharded.version,
     )
     users = rng.integers(0, n_users, size=48)
     phi_all = np.asarray(mf.build_phi(params, jnp.arange(n_users)))
@@ -110,7 +104,7 @@ def main():
     true_items = rng.integers(0, n_items, size=n_eval)
     res = ranking_eval(
         mf.build_phi(params, jnp.arange(n_eval)), None, true_items,
-        k=100, batch_rows=256, cluster=cluster,
+        k=100, batch_rows=256, cluster=sharded,
         exclude=[rng.choice(n_items, size=20, replace=False)
                  for _ in range(n_eval)],
     )
@@ -118,8 +112,8 @@ def main():
           f"ndcg@100={res['ndcg@100']:.4f} over {res['n_eval']} contexts")
 
     # --- fault tolerance: replication, failover, graceful degradation ----
-    # The mesh is the hardened superset of the cluster: each ψ row-range on
-    # R=2 replicas; retries share the batcher's max_delay latency budget.
+    # The same mesh with each ψ row-range on R=2 replicas; retries share
+    # the batcher's max_delay latency budget.
     inj = FaultInjector()
     mesh = FaultTolerantRetrievalMesh(
         lambda ctx: mf.build_phi(params, ctx), n_shards=n_shards,
@@ -161,31 +155,31 @@ def main():
     # bit-identical to the exact path, and int8 per-row-scale storage
     # multiplies rows-per-shard while keeping relative score error small.
     from repro.eval.ranking import ann_recall_curve, overlap_recall
-    from repro.serve.ann import AnnConfig
+    from repro.serve.ann import AnnConfig, PsiIndex
 
     n_c = 32
-    ivf = RetrievalEngine(
-        mf.export_psi(params), lambda ctx: mf.build_phi(params, ctx),
-        k=100, retrieval="ivf",
-        ann=AnnConfig(n_clusters=n_c, n_probe=n_c, quant="none"),
-    )
-    os_, oi = ivf.topk(jnp.arange(8))
-    assert bool((oi == ei).all()) and bool((os_ == es).all())
+    psi = mf.export_psi(params)
+
+    def ivf_mesh(quant):
+        return FaultTolerantRetrievalMesh(
+            lambda ctx: mf.build_phi(params, ctx), n_shards=1, n_replicas=1,
+            k=100, psi_table=psi, retrieval="ivf",
+            ann=AnnConfig(n_clusters=n_c, n_probe=n_c, quant=quant),
+        )
+
+    os_, oi = ivf_mesh("none").topk(jnp.arange(8))
+    assert bool((oi == ci).all()) and bool((os_ == cs).all())
     print(f"ivf oracle (n_probe=n_clusters={n_c}): bit-identical to exact ✓")
     curve = ann_recall_curve(
-        ivf.index, mf.build_phi(params, jnp.arange(8)),
-        mf.export_psi(params), k=100, n_probes=(2, 4, 8, n_c),
+        PsiIndex.build(psi, AnnConfig(n_clusters=n_c)),
+        mf.build_phi(params, jnp.arange(8)), psi, k=100,
+        n_probes=(2, 4, 8, n_c),
     )
     print("ivf recall-vs-probe:",
           {pt["n_probe"]: round(pt["recall@100"], 3) for pt in curve})
-    q8 = RetrievalEngine(
-        mf.export_psi(params), lambda ctx: mf.build_phi(params, ctx),
-        k=100, retrieval="ivf",
-        ann=AnnConfig(n_clusters=n_c, n_probe=n_c, quant="int8"),
-    )
-    _, qi = q8.topk(jnp.arange(8))
+    _, qi = ivf_mesh("int8").topk(jnp.arange(8))
     print(f"int8 ψ (per-row scales): id recall vs exact = "
-          f"{overlap_recall(np.asarray(qi), np.asarray(ei)):.3f}, "
+          f"{overlap_recall(np.asarray(qi), np.asarray(ci)):.3f}, "
           f"~3.9x rows per shard at D=128 ✓")
 
 

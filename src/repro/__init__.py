@@ -11,8 +11,8 @@ feature of a framework-scale training/inference system:
                          topk_score) with pure-jnp oracles
 - ``repro.optim``      — optimizers, schedules, gradient compression
 - ``repro.train``      — train-step builders, remat, microbatching
-- ``repro.serve``      — retrieval serving: engine / sharded cluster /
-                         fault-tolerant mesh / IVF approximate tier
+- ``repro.serve``      — retrieval serving: the fault-tolerant mesh (one
+                         device to sharded and replicated) / IVF tier
 - ``repro.checkpoint`` — fault-tolerant sharded checkpointing
 - ``repro.runtime``    — elastic mesh management, straggler watchdog
 - ``repro.configs``    — assigned architecture configs + the paper's own

@@ -4,9 +4,9 @@ Every module exposes the same low-level surface:
 
 - ``init(key, ...) -> params``            parameter pytree
 - ``phi(params, ...) / psi(params, ...)`` the k-separable decomposition
-- ``export_psi(params, ...) -> (I, D)``   ψ table for the retrieval engine
+- ``export_psi(params, ...) -> (I, D)``   ψ table for the serving mesh
 - ``build_phi(params, <query>) -> (B, D)`` φ rows for a query batch (the
-  serve/eval contract — column conventions in ``serve/engine.py``)
+  serve/eval contract — column conventions in ``serve/README.md``)
 - ``predict(params, ...)``                scores for (context, item) pairs
 - ``epoch(params, data, hp, [schedule, sweep_index]) -> params`` one iCD
   epoch (ctx + item sweep); an optional
@@ -23,7 +23,7 @@ The UNIFIED surface over these modules is the ``Model`` protocol in
 ``epoch``, ``export_psi``, ``build_phi``) plus the continual-learning
 entry points ``fold_in_user`` / ``fold_in_item`` (closed-form single-row
 CD against the frozen other side — ``core/foldin.py``). The serving
-engine (``RetrievalEngine.from_model``), ranking eval
+mesh (built from ``export_psi``/``build_phi``), ranking eval
 (``model_eval_callback`` / ``foldin_ranking_eval``), and the zoo helpers
 all construct through it, so no consumer branches on per-model
 signatures. The module-level functions here remain the public low-level

@@ -5,7 +5,7 @@ The five model modules grew drifted entry points (``mf.fit(params, data,
 hp, ...)`` vs ``fm.fit(params, x, z, data, hp, ...)`` vs ``tucker.fit(
 params, tc, data, hp, ...)``; ``build_phi`` takes ctx ids / a Design / a
 ``(c1, c2)`` pair depending on the model). This module routes them through
-ONE surface so the serving engine, ranking eval, zoo helpers, and the
+ONE surface so the serving mesh, ranking eval, zoo helpers, and the
 continual-learning tier never branch on per-model signatures:
 
     ds = Dataset(data=interactions, x=x, z=z)          # per-model bundle
@@ -25,7 +25,7 @@ the adapter.
 
 Fold-in (the continual-learning path) solves ONE embedding row in export
 coordinates against the frozen other side via :mod:`repro.core.foldin`:
-``fold_in_user`` returns a φ row ready for ``RetrievalEngine.topk_phi``;
+``fold_in_user`` returns a φ row ready for the mesh's ``topk_phi``;
 ``fold_in_item`` returns a ψ row ready for the serving tier's
 ``publish_delta``. FM's constant-1 extended columns are held fixed
 automatically (the ``free`` mask).

@@ -72,7 +72,7 @@ def psi(params: MFParams) -> jax.Array:
 
 
 def export_psi(params: MFParams) -> jax.Array:
-    """ψ table for the retrieval engine (serve/engine.py): (n_items, k)."""
+    """ψ table for the serving mesh (serve/mesh.py): (n_items, k)."""
     return params.h
 
 

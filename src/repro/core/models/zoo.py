@@ -1,6 +1,6 @@
 """Tiny reference instances of every k-separable model.
 
-Shared by the kernel/engine/cluster parity tests and the serve bench: build
+Shared by the kernel and serving-mesh parity tests and the serve bench: build
 a small instance of each zoo model through the unified
 :mod:`repro.core.models.api` ``Model`` protocol, so every consumer
 exercises the same five models via ONE surface (no per-model signature

@@ -12,16 +12,16 @@ This harness never allocates it: evaluation contexts stream in batches of
 (ψ-table blocks through VMEM, running top-K merge), so the largest live
 arrays are the (batch_rows, D) φ tile, the (batch_rows, L) −1-padded
 exclude-id tile, and the (batch_rows, K) results. Exclusion rides the
-kernel's id-list form (``serve.engine.exclude_ids_from_lists``): the
-ψ-block-aligned admissibility slices are built in-VMEM per block, so an
-exclude mask never materializes a full-catalogue row — on host OR device —
-at any ``n_items``. The per-row metric math is shared with the dense path
+kernel's id-list form (``kernels.topk_score.ops.exclude_ids_from_lists``):
+the ψ-block-aligned admissibility slices are built in-VMEM per block, so
+an exclude mask never materializes a full-catalogue row — on host OR
+device — at any ``n_items``. The per-row metric math is shared with the dense path
 (``core.metrics.*_from_topk``), so streaming and dense evaluation are
 numerically identical (parity-tested).
 
 Past one device's HBM the same loop runs against a
-``serve.cluster.ShardedRetrievalCluster`` (``cluster=``): per batch the
-cluster fans the φ tile over the ψ shards and K-way-merges the candidates
+``serve.mesh.FaultTolerantRetrievalMesh`` (``cluster=``): per batch the
+mesh fans the φ tile over the ψ shards and K-way-merges the candidates
 — bit-identical top-K to the single-table path, so the metrics are too.
 
 Per-epoch use from the sweep loops: every model's ``fit`` already takes a
@@ -37,8 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.metrics import ndcg_from_topk, recall_from_topk
-from repro.kernels.topk_score.ops import topk_score
-from repro.serve.engine import exclude_ids_from_lists
+from repro.kernels.topk_score.ops import exclude_ids_from_lists, topk_score
 
 
 def ranking_eval(
@@ -50,7 +49,7 @@ def ranking_eval(
     batch_rows: int = 256,
     exclude: Optional[Sequence] = None,  # per-row id lists to mask (train items)
     block_items: Optional[int] = None,
-    cluster=None,                 # serve.cluster.ShardedRetrievalCluster
+    cluster=None,                 # serve.mesh.FaultTolerantRetrievalMesh
 ) -> Dict[str, float]:
     """Leave-one-out Recall@K / NDCG@K over the full catalogue, streamed.
 
@@ -59,11 +58,10 @@ def ranking_eval(
     −1-padded (batch_rows, L) id tile — the full ``(n_eval, n_items)``
     mask, like the score matrix, never exists in any form.
 
-    ``cluster=`` switches the top-K to a sharded table
+    ``cluster=`` switches the top-K to the serving mesh
     (``cluster.topk_phi``; ``psi`` may be None) — the path past one
-    device's HBM, bit-identical results by the cluster's merge contract.
-    The cluster may also be the fault-tolerant mesh (``serve/mesh.py``):
-    the returned metrics then carry the degradation contract — ``coverage``
+    device's HBM, bit-identical results by the mesh's merge contract. The
+    returned metrics then carry the degradation contract — ``coverage``
     (the minimum over eval batches) and the union of ``dead_ranges`` — so
     an eval that ran against a partially-dead catalogue can never be
     mistaken for a full-catalogue number.
@@ -81,11 +79,11 @@ def ranking_eval(
             eids = exclude_ids_from_lists(exclude[lo:hi])
         if cluster is not None:
             res = cluster.topk_phi(phi[lo:hi], k=k, exclude_ids=eids)
-            top_ids = res.ids if hasattr(res, "ids") else res[1]
-            # degraded-cluster contract: metrics over a partially-dead
+            top_ids = res.ids
+            # degraded-mesh contract: metrics over a partially-dead
             # catalogue are labeled, never silently reported as full
-            coverage = min(coverage, float(getattr(res, "coverage", 1.0)))
-            dead_ranges.update(getattr(res, "dead_ranges", ()))
+            coverage = min(coverage, res.coverage)
+            dead_ranges.update(res.dead_ranges)
         else:
             _, top_ids = topk_score(
                 phi[lo:hi], psi, k, exclude_ids=eids, block_items=block_items
@@ -224,7 +222,7 @@ def foldin_ranking_eval(
     ψ table), then ranks the full catalogue exactly like the warm path.
 
     This measures what the serving tier actually does for a user with no
-    trained embedding (``RetrievalEngine.fold_in_phi``): solve the row
+    trained embedding (``Model.fold_in_user``): solve the row
     from the observed ``histories[u]``, then retrieve. With
     ``exclude_history`` the folded-in items are masked at ranking time
     (the leave-one-out protocol — the true item must NOT be in the

@@ -14,9 +14,8 @@ read. This kernel fuses the two:
     resident per (b) row-block:  φ tile (block_b, D), running top-K
                                  score/id blocks (block_b, K_pad)
     streamed per (b, i) step:    ψ tile (block_items, D)
-                                 [optional] exclude tile (block_b,
-                                 block_items) int8, or the per-row exclude
-                                 ID tile (block_b, L_pad) int32
+                                 [optional] the per-row exclude ID tile
+                                 (block_b, L_pad) int32
     compute per step:  S = φ·ψᵀ (MXU), mask exclusions/padding to −inf,
                        merge: insert the tile's entrants into the sorted
                        running K_pad state — scores and ids together, in
@@ -34,14 +33,12 @@ same program — under ``shard_map`` the offset is ``axis_index·rows_per``
 and the cross-shard K-way merge (``ops.topk_merge_shards``) combines the
 per-shard (B, K) candidates without any id rebasing.
 
-Exclusion comes in two forms:
-
-  * ``exclude_mask`` (B, n_items) int8 — the legacy dense form; fine for
-    query-batch-sized B at test scale, but one row IS the full catalogue.
-  * ``exclude_ids`` (B, L) int32, −1-padded GLOBAL ids — the web-scale
-    form: the kernel builds each (block_b, block_items) admissibility tile
-    in-VMEM by comparing candidate ids against the per-row id list, so no
-    (B, n_items) array exists on host or device.
+Exclusion is ``exclude_ids`` (B, L) int32, −1-padded GLOBAL ids
+(``ops.exclude_ids_from_lists`` builds it from ragged lists): the kernel
+builds each (block_b, block_items) admissibility tile in-VMEM by comparing
+candidate ids against the per-row id list, so no (B, n_items) array exists
+on host or device. The dense mask is the oracle's form only
+(``ref.exclude_ids_to_mask``).
 
 Semantics (pinned by ``ref.topk_score_ref`` and the parity tests):
 
@@ -55,7 +52,7 @@ Semantics (pinned by ``ref.topk_score_ref`` and the parity tests):
     scoring ≥ it, and within a tile the smaller id is inserted first.
     Scores rank in ``lax.top_k``'s total order (NaN highest).
   * Inadmissible slots: when a row has fewer than K admissible candidates
-    (exclude mask covers the row, or K > n_items), the tail slots return
+    (the exclude list covers the row, or K > n_items), the tail slots return
     id −1 with score −inf — excluded items never leak their ids, unlike a
     dense ``top_k`` over a −inf-masked matrix (which returns arbitrary
     real ids for the −inf tail). A genuinely −inf-scoring admissible item
@@ -81,7 +78,7 @@ from repro.kernels.topk_score.ref import SCORE_PRECISION
 
 
 def _score_and_merge(block_items, k, meta_ref, psi_ref, phi_ref, s_ref,
-                     i_ref, excl_ref=None, exclid_ref=None, scale_ref=None):
+                     i_ref, exclid_ref=None, scale_ref=None):
     """One grid step: score the ψ tile and merge into the running top-K.
 
     ``meta_ref`` is the (1, 2) int32 ``[id_offset, n_valid]`` pair: ids are
@@ -116,8 +113,6 @@ def _score_and_merge(block_items, k, meta_ref, psi_ref, phi_ref, s_ref,
     local = step * block_items + lane
     admissible = local < n_valid
     ids = offset + local                     # GLOBAL catalogue ids
-    if excl_ref is not None:
-        admissible &= excl_ref[...] == 0
     if exclid_ref is not None:
         admissible &= ~_excluded(exclid_ref[...], offset + step * block_items,
                                  lane)
@@ -214,22 +209,19 @@ def _merge_tile(k, scores, ids, run_s, run_i):
     return _from_key(s), i
 
 
-def _topk_kernel(block_items, k, has_scale, excl_kind, *refs):
-    """Generic ref unpacker for every (scale?, exclusion-form) variant.
+def _topk_kernel(block_items, k, has_scale, has_ids, *refs):
+    """Generic ref unpacker for every (scale?, exclude ids?) variant.
 
     Ref order mirrors the in_specs the wrapper builds: meta, ψ,
-    [per-row scale], φ, [exclude mask | exclude ids], then the two outputs.
-    ``excl_kind``: 0 none, 1 dense mask, 2 id list."""
+    [per-row scale], φ, [exclude ids], then the two outputs."""
     it = iter(refs)
     meta_ref, psi_ref = next(it), next(it)
     scale_ref = next(it) if has_scale else None
     phi_ref = next(it)
-    excl_ref = next(it) if excl_kind == 1 else None
-    exclid_ref = next(it) if excl_kind == 2 else None
+    exclid_ref = next(it) if has_ids else None
     s_ref, i_ref = next(it), next(it)
     _score_and_merge(block_items, k, meta_ref, psi_ref, phi_ref, s_ref,
-                     i_ref, excl_ref=excl_ref, exclid_ref=exclid_ref,
-                     scale_ref=scale_ref)
+                     i_ref, exclid_ref=exclid_ref, scale_ref=scale_ref)
 
 
 _QUANT_DTYPES = ("int8", "bfloat16")
@@ -239,7 +231,6 @@ def topk_score_pallas(
     phi: jax.Array,       # (B, D) query φ rows
     psi: jax.Array,       # (n_rows, D) ψ table (or one row-range shard)
     k: int,
-    exclude_mask: jax.Array | None = None,  # (B, n_rows) nonzero ⇒ never recommend
     *,
     exclude_ids: jax.Array | None = None,   # (B, L) GLOBAL ids, −1 padded
     psi_scale: jax.Array | None = None,     # (n_rows,) per-row dequant scale
@@ -266,9 +257,6 @@ def topk_score_pallas(
     b, d = phi.shape
     n_rows, d2 = psi.shape
     assert d == d2, f"phi D={d} vs psi D={d2}"
-    assert exclude_mask is None or exclude_ids is None, (
-        "pass exclude_mask OR exclude_ids, not both"
-    )
     if psi.dtype == jnp.int8 and psi_scale is None:
         raise ValueError("int8 psi needs psi_scale (per-row dequant scales)")
     if psi_scale is not None and psi_scale.shape[0] != n_rows:
@@ -340,18 +328,7 @@ def topk_score_pallas(
     in_specs.append(pl.BlockSpec((block_b, d_pad), lambda bb, ii: (bb, 0)))
     args.append(phi)
 
-    excl_kind = 0
-    if exclude_mask is not None:
-        excl_kind = 1
-        in_specs.append(
-            pl.BlockSpec((block_b, block_items), lambda bb, ii: (bb, ii))
-        )
-        args.append(jnp.pad(
-            exclude_mask.astype(jnp.int8),
-            ((0, b_pad - b), (0, n_pad - n_rows)),
-        ))
-    elif exclude_ids is not None:
-        excl_kind = 2
+    if exclude_ids is not None:
         in_specs.append(pl.BlockSpec((block_b, l_pad), lambda bb, ii: (bb, 0)))
         # sorted rows, pads first: each tile's ids are one run (_excluded)
         args.append(jnp.sort(jnp.pad(
@@ -362,7 +339,7 @@ def topk_score_pallas(
 
     scores, ids = pl.pallas_call(
         partial(_topk_kernel, block_items, k, psi_scale is not None,
-                excl_kind),
+                exclude_ids is not None),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

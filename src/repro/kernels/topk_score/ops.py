@@ -1,44 +1,61 @@
 """Jit'd public wrappers for the fused score+top-K retrieval kernel family.
 
-Two ops:
+Two ops and the exclusion form's builder:
 
   * :func:`topk_score` — the fused streaming kernel over one ψ table (or
     one row-range shard of it, via ``id_offset``/``n_valid``);
   * :func:`topk_merge_shards` — the cross-shard K-way merge that combines
     per-shard top-K candidate lists (already carrying GLOBAL ids) into the
     final (B, k), reproducing the kernel's exact tie-stable
-    ascending-global-id policy. The serving cluster (``serve/cluster.py``)
-    is ``S × topk_score  →  topk_merge_shards``.
+    ascending-global-id policy. The serving mesh (``serve/mesh.py``) is
+    ``S × topk_score  →  topk_merge_shards``;
+  * :func:`exclude_ids_from_lists` — ragged per-row excluded-id lists in
+    the kernel's (B, L) −1-padded exclusion form.
 """
 from functools import partial
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import kernel_jit
 from repro.kernels.topk_score.kernel import topk_score_pallas
 
 
+def exclude_ids_from_lists(
+    item_lists: Sequence, *, min_width: int = 1
+) -> jax.Array:
+    """(B, L) int32, −1-padded: ragged per-row GLOBAL excluded-id lists
+    (train histories) in the kernel's exclude form. L is the widest row
+    (≥ ``min_width``); host cost is O(Σ|list|) — never O(B·n_items)."""
+    width = max(min_width, max((len(ids) for ids in item_lists), default=0))
+    out = np.full((len(item_lists), width), -1, np.int32)
+    for r, ids in enumerate(item_lists):
+        ids = np.asarray(ids, np.int64).reshape(-1)
+        out[r, : ids.size] = ids
+    return jnp.asarray(out)
+
+
 @kernel_jit(static_argnames=("k", "block_b", "block_items"))
-def topk_score(phi, psi, k, exclude_mask=None, *, exclude_ids=None,
-               psi_scale=None, id_offset=0, n_valid=None, block_b=128,
-               block_items=None, interpret=None):
+def topk_score(phi, psi, k, *, exclude_ids=None, psi_scale=None,
+               id_offset=0, n_valid=None, block_b=128, block_items=None,
+               interpret=None):
     """Fused streaming top-K over the ψ table: ``(scores, ids) (B, k)``.
 
-    ``exclude_mask`` (B, n_rows), nonzero ⇒ never recommend; the web-scale
-    alternative ``exclude_ids`` (B, L) is a −1-padded per-row list of
-    GLOBAL excluded ids — the admissibility tile is built in-kernel per ψ
-    block, so no (B, n_items) mask is ever materialized. Inadmissible
-    slots come back as (−inf, −1). ``id_offset``/``n_valid`` (traced
-    scalars allowed) serve a row-range ψ shard with global output ids; see
-    ``kernel.py`` for the tie policy.
+    ``exclude_ids`` (B, L) is a −1-padded per-row list of GLOBAL excluded
+    ids (:func:`exclude_ids_from_lists`) — the admissibility tile is built
+    in-kernel per ψ block, so no (B, n_items) mask is ever materialized.
+    Inadmissible slots come back as (−inf, −1). ``id_offset``/``n_valid``
+    (traced scalars allowed) serve a row-range ψ shard with global output
+    ids; see ``kernel.py`` for the tie policy.
 
     ``psi`` may be quantized serving storage: bf16, or int8 with the
     per-row ``psi_scale`` from ``core.quant.int8_quantize_rows`` —
     dequantized in-kernel per tile, fp32 accumulate (``serve/ann.py``)."""
     return topk_score_pallas(
-        phi, psi, k, exclude_mask, exclude_ids=exclude_ids,
-        psi_scale=psi_scale, id_offset=id_offset, n_valid=n_valid,
+        phi, psi, k, exclude_ids=exclude_ids, psi_scale=psi_scale,
+        id_offset=id_offset, n_valid=n_valid,
         block_b=block_b, block_items=block_items, interpret=interpret,
     )
 

@@ -21,7 +21,7 @@ differs, so kernel scores agree with :func:`topk_score_ref` within
 :data:`SCORE_RTOL`/:data:`SCORE_ATOL`, and ids agree exactly wherever the
 reference's scores are not tied within that tolerance
 (:func:`topk_mismatches`). Two runs of the kernel itself on the same
-inputs (engine, cluster, mesh, any shard count) stay bit-identical.
+inputs (the mesh at any shard or replica count) stay bit-identical.
 """
 from __future__ import annotations
 

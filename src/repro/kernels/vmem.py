@@ -260,7 +260,7 @@ def cluster_block_items(
     of 2·4·block_b·S·K_pad bytes that grows with the shard count.
 
     Raises :class:`VmemBudgetError` when even one minimal ψ block (128
-    rows) cannot fit next to the merge scratch — the cluster PROPAGATES it
+    rows) cannot fit next to the merge scratch — the mesh PROPAGATES it
     (re-shard coarser, or lower K) instead of silently shrinking the tile
     below one ψ block and overflowing VMEM."""
     merge_scratch = 2 * 4 * block_b * n_shards * k_pad

@@ -10,9 +10,9 @@
   train.py    fit-callback metrics for the training spine (epoch wall
               time, loss trajectory, SweepSchedule block visits)
 
-Threaded through ``serve/`` (batcher, mesh, cluster, engine, publish,
-ann), ``launch/serve.py`` (``--metrics-out``/``--trace-out``), the
-benches (instrumented-vs-bare overhead hard-gated < 3%), and
+Threaded through ``serve/`` (batcher, mesh, publish, ann),
+``launch/serve.py`` (``--metrics-out``/``--trace-out``), the benches
+(instrumented-vs-bare overhead hard-gated < 3%), and
 ``examples/observability.py`` (end-to-end train → serve-under-faults →
 Perfetto trace). See ``serve/README.md`` § "Metrics & tracing" for the
 metric catalogue and label conventions.

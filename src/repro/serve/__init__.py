@@ -1,28 +1,21 @@
-"""Online retrieval serving: single-device engine, sharded cluster,
-fault-tolerant replicated mesh, request micro-batching, live ψ publish
-from training, and the IVF approximate tier with quantized ψ storage
-(see serve/README.md for the operations guide)."""
+"""Online retrieval serving: the fault-tolerant mesh (one front end for
+one device, a sharded catalogue and a replicated one), request
+micro-batching, live ψ publish from training, and the IVF approximate
+tier with quantized ψ storage (see serve/README.md for the operations
+guide)."""
 from repro.serve.ann import (  # noqa: F401
     AnnConfig,
     PsiIndex,
     build_shard_indexes,
     fold_delta_indexes,
-    ivf_cluster_topk,
     kmeans,
 )
 from repro.serve.batcher import MicroBatcher  # noqa: F401
 from repro.serve.cluster import (  # noqa: F401
     PsiShardSet,
-    ShardedRetrievalCluster,
     TopKResult,
-    cluster_topk,
     shard_map_topk,
     shard_psi,
-)
-from repro.serve.engine import (  # noqa: F401
-    RetrievalEngine,
-    exclude_ids_from_lists,
-    exclude_mask_from_lists,
 )
 from repro.serve.mesh import (  # noqa: F401
     FaultInjector,
@@ -38,5 +31,5 @@ from repro.serve.publish import (  # noqa: F401
     apply_delta,
     dense_table,
 )
+from repro.kernels.topk_score.ops import exclude_ids_from_lists  # noqa: F401
 from repro.kernels.topk_score.ref import retrieval_topk  # noqa: F401
-from repro.serve.engine import bulk_score, mf_retrieval_score_fn  # noqa: F401

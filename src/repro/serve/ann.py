@@ -1,12 +1,12 @@
 """IVF-tiered approximate retrieval: centroid pruning + exact fused re-rank.
 
-The exact serving stack (engine → cluster → mesh) streams the ENTIRE ψ
-catalogue through the fused ``kernels/topk_score`` kernel per query — the
-right oracle, and the serving wall at 10⁸+ items (ROADMAP item 4; Rendle
-2021 frames large-catalogue implicit retrieval as exactly this
-approximate-then-exact regime). Because every zoo model is k-separable
-(score = ⟨φ, ψ_i⟩), indexing the ψ SIDE once speeds up serving for the
-whole zoo: this module adds the approximate tier.
+The exact serving path (the mesh's per-shard kernel sweep) streams the
+ENTIRE ψ catalogue through the fused ``kernels/topk_score`` kernel per
+query — the right oracle, and the serving wall at 10⁸+ items (ROADMAP
+item 4; Rendle 2021 frames large-catalogue implicit retrieval as exactly
+this approximate-then-exact regime). Because every zoo model is
+k-separable (score = ⟨φ, ψ_i⟩), indexing the ψ SIDE once speeds up
+serving for the whole zoo: this module adds the approximate tier.
 
 :class:`PsiIndex` — an inverted-file (IVF) index over one ψ table (or one
 row-range shard of it):
@@ -51,8 +51,9 @@ membership compare works unchanged. An excluded id living in a pruned
 
 Sharding: each shard of a ``PsiShardSet`` gets its own index over its
 row range (:func:`build_shard_indexes`); per-shard candidates carry global
-ids, so the existing cross-shard merge works untouched
-(:func:`ivf_cluster_topk`), including the coverage/degradation contract.
+ids, so ``FaultTolerantRetrievalMesh`` (``retrieval='ivf'``) dispatches
+each shard's index in place of its slab sweep and the cross-shard merge
+and the coverage/degradation contract work untouched.
 """
 from __future__ import annotations
 
@@ -65,21 +66,14 @@ import numpy as np
 
 from repro.core.quant import int8_quantize_rows
 from repro.kernels.topk_score.ops import topk_merge_shards, topk_score
-from repro.serve.cluster import (
-    PsiShardSet,
-    TopKResult,
-    colocate_parts,
-    coverage_fraction,
-    dead_item_ranges,
-    empty_topk,
-)
+from repro.serve.cluster import PsiShardSet, empty_topk
 
 _QUANTS = ("none", "bf16", "int8")
 
 
 @dataclasses.dataclass(frozen=True)
 class AnnConfig:
-    """Knobs for the IVF tier (engine/cluster/mesh take one of these).
+    """Knobs for the IVF tier (the mesh's ``ann=`` takes one of these).
 
     ``n_clusters=0`` auto-sizes to ≈√n (the classic IVF balance point:
     centroid scan cost ≈ probed-block cost). ``n_probe=0`` auto-sizes to
@@ -265,8 +259,8 @@ class PsiIndex:
         ``registry`` (an ``obs.metrics`` registry) opts into query/probe
         counters. Unlike the serving components, ``None`` here means NO
         recording — a hot library function must not reach for process
-        globals behind its caller's back (the engine/mesh thread their own
-        registries through)."""
+        globals behind its caller's back (the mesh threads its own
+        registry through)."""
         phi_rows = jnp.asarray(phi_rows, jnp.float32)
         b = int(phi_rows.shape[0])
         c = self.n_clusters
@@ -478,45 +472,3 @@ def fold_delta_indexes(
             "budget (needs_reindex) or a newly materialized shard",
         ).inc(rebuilt)
     return tuple(out)
-
-
-def ivf_cluster_topk(
-    table: PsiShardSet,
-    indexes: Sequence[Optional[PsiIndex]],
-    phi_rows: jax.Array,
-    k: int,
-    *,
-    n_probe: Optional[int] = None,
-    exclude_ids: Optional[jax.Array] = None,
-    interpret: Optional[bool] = None,
-    dead_shards: Sequence[int] = (),
-    registry=None,
-) -> TopKResult:
-    """Sharded IVF top-K: per-shard :meth:`PsiIndex.topk` candidates (each
-    shard prunes to its own ``n_probe`` blocks) + the same cross-shard
-    merge and coverage/degradation contract as ``cluster.cluster_topk``."""
-    phi_rows = jnp.asarray(phi_rows, jnp.float32)
-    b = int(phi_rows.shape[0])
-    dead = set(dead_shards)
-    parts_s, parts_i = [], []
-    for s in range(table.n_shards):
-        if s in dead or indexes[s] is None:
-            continue
-        ss, ii = indexes[s].topk(
-            phi_rows, k, n_probe=n_probe, exclude_ids=exclude_ids,
-            interpret=interpret, registry=registry,
-        )
-        parts_s.append(ss)
-        parts_i.append(ii)
-    coverage = coverage_fraction(table, dead)
-    ranges = dead_item_ranges(table, dead)
-    if not parts_s:
-        es, ei = empty_topk(b, k)
-        return TopKResult(es, ei, coverage, ranges)
-    if len(parts_s) == 1:
-        return TopKResult(parts_s[0], parts_i[0], coverage, ranges)
-    ms, mi = topk_merge_shards(
-        jnp.stack(colocate_parts(parts_s)),
-        jnp.stack(colocate_parts(parts_i)), k,
-    )
-    return TopKResult(ms, mi, coverage, ranges)

@@ -31,7 +31,7 @@ batch per kernel dispatch:
 
   caching: an LRU φ→result cache keyed on ``(key, table_version,
   exclude_list)``. The version comes from the serving table
-  (``cluster.version`` — bumped by every ``publish``), so a live ψ refresh
+  (``mesh.version`` — bumped by every ``publish``), so a live ψ refresh
   implicitly invalidates the whole cache without any flush traffic; on the
   first admission AFTER a version bump every entry keyed on a superseded
   version is EVICTED outright (dead weight would otherwise squat in the
@@ -92,14 +92,14 @@ class MicroBatcher:
     """Coalesce single-row top-K queries into kernel-shaped batches.
 
     ``topk_phi(phi_rows (B, D), exclude_ids (B, L) | None) -> (scores, ids)``
-    is the backing batch executor — typically
-    ``cluster.topk_phi`` / ``engine.topk_phi`` with exclusion passed through.
+    is the backing batch executor — typically ``mesh.topk_phi`` with
+    exclusion passed through.
 
     ::
 
         batcher = MicroBatcher(
-            lambda phi, eids: cluster.topk_phi(phi, exclude_ids=eids),
-            max_batch=32, max_delay=2e-3, version_fn=lambda: cluster.version)
+            lambda phi, eids: mesh.topk_phi(phi, exclude_ids=eids),
+            max_batch=32, max_delay=2e-3, version_fn=lambda: mesh.version)
         t1 = batcher.submit(phi_row, exclude=[3, 7], key=("user", 17))
         ...
         batcher.step()            # deadline check; flush if due

@@ -1,11 +1,14 @@
-"""Fault-tolerant serving mesh: replication, health-checked failover, and
-graceful degradation for the sharded retrieval cluster.
+"""The serving object: top-K retrieval over a sharded, replicated ψ table,
+with health-checked failover and graceful degradation.
 
-``serve/cluster.py`` gives one copy of each ψ row-range: lose a shard and
-its slice of the catalogue silently vanishes. This module is the tier that
-makes the cluster OPERABLE under the failures a "millions of users" serving
-regime implies (Rendle 2021 frames large-catalogue retrieval as exactly
-this availability/tail-latency problem):
+:class:`FaultTolerantRetrievalMesh` is the one front end for top-K
+serving, at every scale: one device is ``n_shards=1, n_replicas=1`` (the
+slab is the table itself — no copy, no merge), a catalogue past one
+device's HBM is ``n_shards=S`` over ``devices=`` (the row-range layout of
+``serve/cluster.py``), and replicas make it OPERABLE under the failures a
+"millions of users" serving regime implies (Rendle 2021 frames
+large-catalogue retrieval as exactly this availability/tail-latency
+problem):
 
   replication — :class:`ReplicaSet` places each row range on R replica
     slabs (numbered shard by shard and dealt round the devices: every
@@ -446,8 +449,8 @@ class ShardHealthMonitor:
 class FaultTolerantRetrievalMesh:
     """Replicated, health-checked, degradation-aware retrieval service.
 
-    The drop-in hardened superset of
-    :class:`~repro.serve.cluster.ShardedRetrievalCluster`::
+    Built from a model's φ builder and exported ψ table (the φ/ψ export
+    contract, ``serve/README.md``)::
 
         mesh = FaultTolerantRetrievalMesh(
             lambda ctx: mf.build_phi(params, ctx),
@@ -457,16 +460,20 @@ class FaultTolerantRetrievalMesh:
         res = mesh.topk(user_ids)          # TopKResult
         res.coverage, res.dead_ranges      # the degradation contract
 
-    Query semantics: bit-identical to the unreplicated cluster (and the
-    single-device engine) whenever every row range has ≥ 1 live replica —
-    replicas are exact copies running the same program, so a mid-stream
-    replica kill under R ≥ 2 is invisible in the results. When a range has
-    NO live replica the query completes over the survivors with
-    ``coverage < 1.0`` and the dead ranges reported.
+    Query semantics follow the kernel (``kernels/topk_score``): exact
+    dense-``lax.top_k`` parity, ascending-id tie policy, (−inf, −1) on
+    slots with no admissible candidate, and results bit-identical to one
+    kernel call over the whole table at any shard count whenever every
+    row range has ≥ 1 live replica — replicas are exact copies running
+    the same program, so a mid-stream replica kill under R ≥ 2 is
+    invisible in the results. When a range has NO live replica the query
+    completes over the survivors with ``coverage < 1.0`` and the dead
+    ranges reported.
 
-    ``publish`` snapshots are versioned double-buffered ReplicaSets (same
-    flip protocol as the cluster); the canary methods implement the staged
-    rollout (see module docstring and ``publish.StagedRollout``).
+    ``publish`` snapshots are versioned double-buffered ReplicaSets
+    (``serve/publish.py``): each query grabs the active set once and
+    serves the whole request from it; the canary methods implement the
+    staged rollout (see module docstring and ``publish.StagedRollout``).
     """
 
     def __init__(
@@ -750,12 +757,10 @@ class FaultTolerantRetrievalMesh:
         return jnp.asarray(self.phi_fn(*query), jnp.float32)
 
     def topk(self, *query, k: Optional[int] = None,
-             exclude_mask: Optional[jax.Array] = None,
              exclude_ids: Optional[jax.Array] = None,
              budget: Optional[float] = None) -> TopKResult:
         return self.topk_phi(
-            self.phi(*query), k=k, exclude_mask=exclude_mask,
-            exclude_ids=exclude_ids, budget=budget,
+            self.phi(*query), k=k, exclude_ids=exclude_ids, budget=budget,
         )
 
     def topk_phi(
@@ -763,13 +768,14 @@ class FaultTolerantRetrievalMesh:
         phi_rows: jax.Array,
         *,
         k: Optional[int] = None,
-        exclude_mask: Optional[jax.Array] = None,
         exclude_ids: Optional[jax.Array] = None,
         budget: Optional[float] = None,
     ) -> TopKResult:
         """(B, k) :class:`TopKResult` with the degradation contract.
 
-        ``budget`` (seconds) overrides ``retry.deadline`` as this request's
+        ``exclude_ids`` is the kernel's (B, L) −1-padded global id form
+        (``kernels.topk_score.ops.exclude_ids_from_lists``). ``budget``
+        (seconds) overrides ``retry.deadline`` as this request's
         retry allowance — the batcher path sets it so queue wait plus
         retries stay inside ``max_delay``. The whole request is served
         from ONE ReplicaSet snapshot (version-consistent)."""
@@ -787,11 +793,6 @@ class FaultTolerantRetrievalMesh:
         indexes = None
         block_items = self.block_items
         if self.retrieval == "ivf":
-            if exclude_mask is not None:
-                raise ValueError(
-                    "retrieval='ivf' takes exclude_ids (global id lists), "
-                    "not a dense exclude_mask"
-                )
             # IVF dispatch resolves its own per-block tiling; the replica
             # failover/retry/health machinery below is retrieval-agnostic
             indexes = self._ivf_indexes(table)
@@ -808,8 +809,8 @@ class FaultTolerantRetrievalMesh:
         load: Dict[int, int] = {r.device_id: 1 for r in rs.pending}
         for s in range(table.n_shards):
             out = self._query_shard(
-                rs, s, phi_rows, k, exclude_mask, exclude_ids,
-                block_items, budget, load, indexes=indexes,
+                rs, s, phi_rows, k, exclude_ids, block_items, budget, load,
+                indexes=indexes,
             )
             if out is None:
                 dead.append(s)
@@ -847,8 +848,8 @@ class FaultTolerantRetrievalMesh:
             self._dev_children[device_id] = ch
         return ch
 
-    def _query_shard(self, rs, s, phi_rows, k, exclude_mask, exclude_ids,
-                     block_items, budget, load, indexes=None):
+    def _query_shard(self, rs, s, phi_rows, k, exclude_ids, block_items,
+                     budget, load, indexes=None):
         """One shard's dispatch with failover + bounded deadline-aware
         retries. Returns (scores, ids) or None (shard unavailable for this
         request — the degradation path). ``indexes`` (IVF mode) swaps the
@@ -897,8 +898,7 @@ class FaultTolerantRetrievalMesh:
                 else:
                     ss, ii = shard_topk(
                         rs.table, s, phi_rows, k, slab=rep.slab,
-                        exclude_mask=exclude_mask, exclude_ids=exclude_ids,
-                        block_items=block_items,
+                        exclude_ids=exclude_ids, block_items=block_items,
                     )
                 lat = self.clock() - t0
                 self.monitor.observe(rep.key, lat)

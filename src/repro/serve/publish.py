@@ -18,14 +18,13 @@ queries meanwhile. The protocol here is the classic double-buffer flip:
      every cached result without any flush traffic.
 
 :class:`PsiPublisher` adapts this to the models' ``fit(callback=...)`` hook:
-at each epoch boundary it snapshots ``export_psi(params)`` into the cluster,
+at each epoch boundary it snapshots ``export_psi(params)`` into the mesh,
 so online serving tracks training with epoch granularity ("live ψ refresh").
 
 **Delta publish** (continual learning): a fold-in produces ONE new/updated ψ
 row (``Model.fold_in_item``), and republishing the whole catalogue through a
 :class:`StagedRollout` for one row would be absurd. ``publish_delta(rows,
-ids)`` — on :class:`~repro.serve.cluster.ShardedRetrievalCluster`,
-:class:`~repro.serve.mesh.FaultTolerantRetrievalMesh`, and
+ids)`` — on :class:`~repro.serve.mesh.FaultTolerantRetrievalMesh` and
 :class:`PsiPublisher` — patches existing rows and/or appends new ids onto
 the authoritative table copy and flips the result live under a NORMAL
 version bump: the double-buffer/atomicity story is unchanged, the batcher
@@ -140,8 +139,8 @@ class PsiPublisher:
 
     ::
 
-        cluster = ShardedRetrievalCluster(phi_fn, n_shards=4, k=100)
-        pub = PsiPublisher(cluster, mf.export_psi, every=1)
+        mesh = FaultTolerantRetrievalMesh(phi_fn, n_shards=4, k=100)
+        pub = PsiPublisher(mesh, mf.export_psi, every=1)
         mf.fit(params, data, hp, n_epochs, callback=pub)
         pub.versions   # [(epoch, version), ...] — the refresh trajectory
 

@@ -1,7 +1,7 @@
 """IVF approximate retrieval tier (serve/ann.py): oracle bit-identity at
 n_probe >= n_clusters, quantized-storage parity, empty-cluster and
 fully-pruned-exclusion edges, delta fold-in consistency, and the
-retrieval='ivf' threading through engine / cluster / mesh."""
+retrieval='ivf' threading through the mesh."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,11 +14,9 @@ from repro.serve.ann import (
     PsiIndex,
     build_shard_indexes,
     fold_delta_indexes,
-    ivf_cluster_topk,
     kmeans,
 )
-from repro.serve.cluster import ShardedRetrievalCluster, shard_psi
-from repro.serve.engine import RetrievalEngine
+from repro.serve.cluster import shard_psi
 from repro.serve.mesh import FaultInjector, FaultTolerantRetrievalMesh
 
 
@@ -254,14 +252,20 @@ def test_needs_reindex_trigger():
 
 # ------------------------------------------------------- sharded indexes
 
+def _ivf_mesh(psi, n_shards, cfg, k):
+    """Sharded, unreplicated IVF serving: the mesh's per-shard indexes."""
+    return FaultTolerantRetrievalMesh(n_shards=n_shards, n_replicas=1, k=k,
+                                      retrieval="ivf", ann=cfg,
+                                      psi_table=psi)
+
+
 def test_sharded_indexes_match_exact_cluster_topk():
     psi = _clustered(250, 8, 6, seed=34)
-    table = shard_psi(psi, 3)
-    cfg = AnnConfig(n_clusters=4, seed=35)
-    idxs = build_shard_indexes(table, cfg)
+    cfg = AnnConfig(n_clusters=4, n_probe=4, seed=35)
+    mesh = _ivf_mesh(psi, 3, cfg, 14)
     phi = _queries(6, 8, seed=36)
     es, ei = topk_score(phi, psi, 14)
-    res = ivf_cluster_topk(table, idxs, phi, 14, n_probe=4)
+    res = mesh.topk_phi(phi)
     np.testing.assert_array_equal(np.asarray(res.ids), np.asarray(ei))
     np.testing.assert_array_equal(np.asarray(res.scores), np.asarray(es))
 
@@ -283,7 +287,12 @@ def test_fold_delta_indexes_rebuilds_when_stale():
     dense = np.asarray(psi).copy()
     dense[0], dense[40] = rows[0], rows[1]
     es, ei = topk_score(phi, jnp.asarray(dense), 9)
-    res = ivf_cluster_topk(table2, idxs2, phi, 9, n_probe=2)
+    # the same fold through the mesh's publish_delta, served at n_probe=2
+    mesh = _ivf_mesh(psi, 3, AnnConfig(n_clusters=2, n_probe=2, seed=38,
+                                       reindex_after=1), 9)
+    mesh.topk_phi(phi)                 # builds v1's indexes
+    mesh.publish_delta(rows, ids)
+    res = mesh.topk_phi(phi)
     np.testing.assert_array_equal(np.asarray(res.ids), np.asarray(ei))
 
 
@@ -292,24 +301,32 @@ def test_fold_delta_indexes_rebuilds_when_stale():
 def test_engine_ivf_oracle_matches_exact_engine():
     psi = _clustered(200, 8, 4, seed=41)
     phi = _queries(5, 8, seed=42)
-    ex = RetrievalEngine(psi, lambda q: q, k=12)
-    iv = RetrievalEngine(psi, lambda q: q, k=12, retrieval="ivf",
-                         ann=AnnConfig(n_clusters=4, n_probe=4, seed=43))
+    cfg = AnnConfig(n_clusters=4, n_probe=4, seed=43)
+    ex = FaultTolerantRetrievalMesh(lambda q: q, n_shards=1, n_replicas=1,
+                                    k=12, psi_table=psi)
+    iv = FaultTolerantRetrievalMesh(lambda q: q, n_shards=1, n_replicas=1,
+                                    k=12, psi_table=psi, retrieval="ivf",
+                                    ann=cfg)
     a, b = ex.topk_phi(phi), iv.topk_phi(phi)
     np.testing.assert_array_equal(np.asarray(a.ids), np.asarray(b.ids))
     np.testing.assert_array_equal(np.asarray(a.scores), np.asarray(b.scores))
+    # one shard indexes the whole table, as a standalone PsiIndex would
+    idx = PsiIndex.build(psi, cfg, id_offset=0)
+    s1, i1 = idx.topk(phi, 12)
+    np.testing.assert_array_equal(np.asarray(b.ids), np.asarray(i1))
+    np.testing.assert_array_equal(np.asarray(b.scores), np.asarray(s1))
     with pytest.raises(ValueError):
-        iv.topk_phi(phi, exclude_mask=jnp.zeros((5, 200), bool))
-    with pytest.raises(ValueError):
-        RetrievalEngine(psi, lambda q: q, retrieval="hnsw")
+        FaultTolerantRetrievalMesh(lambda q: q, n_shards=1, n_replicas=1,
+                                   retrieval="hnsw")
 
 
 def test_cluster_ivf_publish_delta_and_exclusions():
     psi = _clustered(240, 8, 4, seed=44)
     phi = _queries(6, 8, seed=45)
     cfg = AnnConfig(n_clusters=4, n_probe=4, seed=46)
-    cl_ex = ShardedRetrievalCluster(n_shards=3, k=10)
-    cl_iv = ShardedRetrievalCluster(n_shards=3, k=10, retrieval="ivf", ann=cfg)
+    cl_ex = FaultTolerantRetrievalMesh(n_shards=3, n_replicas=1, k=10)
+    cl_iv = FaultTolerantRetrievalMesh(n_shards=3, n_replicas=1, k=10,
+                                       retrieval="ivf", ann=cfg)
     cl_ex.publish(psi)
     cl_iv.publish(psi)
     rows = jnp.asarray(np.random.default_rng(47).normal(size=(3, 8)), jnp.float32)
@@ -321,8 +338,7 @@ def test_cluster_ivf_publish_delta_and_exclusions():
     b = cl_iv.topk_phi(phi, exclude_ids=eids)
     np.testing.assert_array_equal(np.asarray(a.ids), np.asarray(b.ids))
     np.testing.assert_array_equal(np.asarray(a.scores), np.asarray(b.scores))
-    with pytest.raises(ValueError):
-        cl_iv.topk_phi(phi, exclude_mask=jnp.zeros((6, 243), bool))
+    assert not np.isin(np.asarray(b.ids), np.arange(20)).any()
 
 
 def test_mesh_ivf_matches_exact_and_survives_faults():
@@ -352,13 +368,14 @@ def test_mesh_ivf_matches_exact_and_survives_faults():
 def test_empty_shard_index_is_none_and_served_as_empty():
     # 5 shards over 90 rows with rows_per=30 -> shards 3,4 are all padding
     psi = _clustered(90, 8, 3, seed=51)
-    table = shard_psi(psi, 5)
+    mesh = _ivf_mesh(psi, 5, AnnConfig(n_clusters=2, n_probe=2, seed=52), 7)
+    table = mesh.table
     if any(table.valid_rows(s) == 0 for s in range(table.n_shards)):
         idxs = build_shard_indexes(table, AnnConfig(n_clusters=2, seed=52))
         assert any(ix is None for ix in idxs)
         phi = _queries(3, 8, seed=53)
         es, ei = topk_score(phi, psi, 7)
-        res = ivf_cluster_topk(table, idxs, phi, 7, n_probe=2)
+        res = mesh.topk_phi(phi)
         np.testing.assert_array_equal(np.asarray(res.ids), np.asarray(ei))
 
 

@@ -1,6 +1,7 @@
-"""Sharded retrieval cluster: bit-exact parity with the single-device
-engine and the dense oracle at every shard count, cross-shard merge edges,
-live publish/refresh, and the shard_map execution path."""
+"""Sharded serving (the mesh with one replica per shard): bit-exact parity
+with the one-shard mesh and the dense oracle at every shard count,
+cross-shard merge edges, live publish/refresh, and the shard_map execution
+path."""
 import os
 import subprocess
 import sys
@@ -16,32 +17,30 @@ from _zoo import ZOO, model_phi_psi, _rand
 from repro.core.models import mf
 from repro.kernels import vmem
 from repro.kernels.topk_score import topk_score_ref
-from repro.serve.cluster import (
-    ShardedRetrievalCluster,
-    cluster_topk,
-    resolve_cluster_block_items,
-    shard_psi,
-)
-from repro.serve.engine import (
-    RetrievalEngine,
-    exclude_ids_from_lists,
-    exclude_mask_from_lists,
-)
+from repro.kernels.topk_score.ops import exclude_ids_from_lists
+from repro.kernels.topk_score.ref import exclude_ids_to_mask
+from repro.serve.cluster import resolve_cluster_block_items, shard_psi
+from repro.serve.mesh import FaultTolerantRetrievalMesh
+from repro.serve.publish import dense_table
+
+
+def _sharded(psi, n_shards, phi_fn=None, **kw):
+    """Sharded, unreplicated serving: the mesh with one replica a shard."""
+    kw.setdefault("block_items", 32)
+    return FaultTolerantRetrievalMesh(phi_fn, n_shards=n_shards, n_replicas=1,
+                                      psi_table=psi, **kw)
 
 
 @pytest.mark.parametrize("n_shards", [1, 2, 3, 4])
 def test_cluster_bit_identical_to_engine_any_shard_count(n_shards):
     """The acceptance criterion: ids AND scores bit-identical to the
-    single-device engine and the dense lax.top_k oracle, with and without
+    one-shard mesh and the dense lax.top_k oracle, with and without
     exclusion, at shard counts that do and don't divide n_items (101)."""
     rng = np.random.default_rng(0)
     phi, psi = _rand((9, 16), 1), _rand((101, 16), 2)
-    engine = RetrievalEngine(psi, lambda p=phi: p, k=13, block_items=32)
-    cl = ShardedRetrievalCluster(
-        lambda p=phi: p, n_shards=n_shards, k=13, block_items=32,
-        psi_table=psi,
-    )
-    es, ei = engine.topk()
+    single = _sharded(psi, 1, lambda p=phi: p, k=13)
+    cl = _sharded(psi, n_shards, lambda p=phi: p, k=13)
+    es, ei = single.topk()
     cs, ci = cl.topk()
     np.testing.assert_array_equal(np.asarray(ci), np.asarray(ei))
     assert bool((np.asarray(cs) == np.asarray(es)).all())  # BIT-identical
@@ -50,13 +49,13 @@ def test_cluster_bit_identical_to_engine_any_shard_count(n_shards):
 
     lists = [rng.choice(101, size=int(rng.integers(0, 8)), replace=False)
              for _ in range(9)]
-    mask = exclude_mask_from_lists(lists, 101)
     eids = exclude_ids_from_lists(lists)
-    es2, ei2 = engine.topk(exclude_mask=mask)
-    for kwargs in (dict(exclude_mask=mask), dict(exclude_ids=eids)):
-        cs2, ci2 = cl.topk(**kwargs)
-        np.testing.assert_array_equal(np.asarray(ci2), np.asarray(ei2))
-        assert bool((np.asarray(cs2) == np.asarray(es2)).all())
+    es2, ei2 = single.topk(exclude_ids=eids)
+    cs2, ci2 = cl.topk(exclude_ids=eids)
+    np.testing.assert_array_equal(np.asarray(ci2), np.asarray(ei2))
+    assert bool((np.asarray(cs2) == np.asarray(es2)).all())
+    _, ri2 = topk_score_ref(phi, psi, 13, exclude_ids_to_mask(eids, 101))
+    np.testing.assert_array_equal(np.asarray(ci2), np.asarray(ri2))
 
 
 @pytest.mark.parametrize("name", ZOO)
@@ -65,9 +64,7 @@ def test_cluster_parity_all_models(name):
     (37 items ⇒ non-divisible), vs the dense oracle."""
     rng = np.random.default_rng(42)
     phi, psi = model_phi_psi(name, rng)
-    cl = ShardedRetrievalCluster(
-        lambda p=phi: p, n_shards=3, k=12, block_items=32, psi_table=psi
-    )
+    cl = _sharded(psi, 3, lambda p=phi: p, k=12)
     s, i = cl.topk()
     rs, ri = topk_score_ref(phi, psi, 12)
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
@@ -75,9 +72,10 @@ def test_cluster_parity_all_models(name):
                                atol=1e-6)
     lists = [rng.choice(psi.shape[0], size=5, replace=False)
              for _ in range(phi.shape[0])]
-    s2, i2 = cl.topk(exclude_ids=exclude_ids_from_lists(lists))
+    eids = exclude_ids_from_lists(lists)
+    s2, i2 = cl.topk(exclude_ids=eids)
     rs2, ri2 = topk_score_ref(
-        phi, psi, 12, exclude_mask_from_lists(lists, psi.shape[0])
+        phi, psi, 12, exclude_ids_to_mask(eids, psi.shape[0])
     )
     np.testing.assert_array_equal(np.asarray(i2), np.asarray(ri2))
 
@@ -86,13 +84,13 @@ def test_k_larger_than_one_shards_item_count():
     """K exceeding rows_per: every shard returns its whole range and the
     merge still ranks the global catalogue exactly."""
     phi, psi = _rand((4, 8), 3), _rand((10, 8), 4)
-    table = shard_psi(psi, 3)  # rows_per=4 < K
-    assert table.rows_per < 7
-    s, i = cluster_topk(table, phi, 7, block_items=32)
+    cl = _sharded(psi, 3)
+    assert cl.table.rows_per < 7  # rows_per=4 < K
+    s, i = cl.topk_phi(phi, k=7)
     rs, ri = topk_score_ref(phi, psi, 7)
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
     # K even beyond n_items: inadmissible tail is (−inf, −1)
-    s2, i2 = cluster_topk(table, phi, 15, block_items=32)
+    s2, i2 = cl.topk_phi(phi, k=15)
     assert bool((np.asarray(i2)[:, 10:] == -1).all())
     assert bool(np.isneginf(np.asarray(s2)[:, 10:]).all())
 
@@ -105,8 +103,7 @@ def test_global_tie_stability_across_shard_boundaries():
     phi = _rand((5, 6), 6)
     rs, ri = topk_score_ref(phi, psi, 25)
     for n_shards in (2, 3, 4):  # boundaries split the tie pairs differently
-        table = shard_psi(psi, n_shards)
-        s, i = cluster_topk(table, phi, 25, block_items=32)
+        s, i = _sharded(psi, n_shards).topk_phi(phi, k=25)
         np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
 
 
@@ -115,10 +112,11 @@ def test_fully_excluded_shard_returns_neginf_slots():
     (−inf, −1) candidates; the merge must fill from the other shards and
     a fully-excluded CATALOGUE row must come back all (−inf, −1)."""
     phi, psi = _rand((3, 8), 7), _rand((24, 8), 8)
-    table = shard_psi(psi, 3)  # shard 1 owns ids [8, 16)
+    cl = _sharded(psi, 3)  # shard 1 owns ids [8, 16)
+    assert cl.table.offsets[1] == 8 and cl.table.rows_per == 8
     lists = [np.arange(8, 16), np.arange(8, 16), np.arange(24)]
     eids = exclude_ids_from_lists(lists)
-    s, i = cluster_topk(table, phi, 24, exclude_ids=eids, block_items=32)
+    s, i = cl.topk_phi(phi, k=24, exclude_ids=eids)
     got_i, got_s = np.asarray(i), np.asarray(s)
     # rows 0/1: shard 1's ids never appear; 16 admissible slots then −inf
     for r in (0, 1):
@@ -126,9 +124,7 @@ def test_fully_excluded_shard_returns_neginf_slots():
         assert real.size == 16 and not np.isin(real, np.arange(8, 16)).any()
     # row 2: everything excluded — no id leaks at all
     assert bool((got_i[2] == -1).all()) and bool(np.isneginf(got_s[2]).all())
-    rs, ri = topk_score_ref(
-        phi, psi, 24, exclude_mask_from_lists(lists, 24)
-    )
+    rs, ri = topk_score_ref(phi, psi, 24, exclude_ids_to_mask(eids, 24))
     np.testing.assert_array_equal(got_i, np.asarray(ri))
 
 
@@ -142,9 +138,9 @@ def test_publish_versioning_and_live_refresh():
     rng = np.random.default_rng(9)
     n_ctx, n_items, k = 30, 50, 6
     params = mf.init(jax.random.PRNGKey(0), n_ctx, n_items, k)
-    cl = ShardedRetrievalCluster(
-        lambda ctx: mf.build_phi(params, ctx), n_shards=2, k=10,
-        block_items=32,
+    cl = FaultTolerantRetrievalMesh(
+        lambda ctx: mf.build_phi(params, ctx), n_shards=2, n_replicas=1,
+        k=10, block_items=32,
     )
     with pytest.raises(RuntimeError, match="publish"):
         _ = cl.table  # serving before any publish must fail loudly
@@ -161,13 +157,12 @@ def test_publish_versioning_and_live_refresh():
     assert [v for _, v in pub.versions] == [1, 2]
     assert cl.version == 2
 
-    # the live table is epoch-2's ψ: cluster == fresh engine on the export
+    # the live table is epoch-2's ψ: 2 shards == a fresh one-shard mesh
     phi = mf.build_phi(fitted, jnp.arange(8))
-    engine = RetrievalEngine(mf.export_psi(fitted),
-                             lambda ctx: mf.build_phi(fitted, ctx),
-                             k=10, block_items=32)
+    single = _sharded(mf.export_psi(fitted), 1,
+                      lambda ctx: mf.build_phi(fitted, ctx), k=10)
     cs, ci = cl.topk_phi(phi)
-    es, ei = engine.topk_phi(phi)
+    es, ei = single.topk_phi(phi)
     np.testing.assert_array_equal(np.asarray(ci), np.asarray(ei))
     assert bool((np.asarray(cs) == np.asarray(es)).all())
 
@@ -175,7 +170,7 @@ def test_publish_versioning_and_live_refresh():
     old_table = cl.table
     cl.publish(jnp.zeros((n_items, k)))  # v3: degenerate table
     assert cl.version == 3 and old_table.version == 2
-    s_old, i_old = cluster_topk(old_table, phi, 10, block_items=32)
+    s_old, i_old = _sharded(dense_table(old_table), 2, k=10).topk_phi(phi)
     np.testing.assert_array_equal(np.asarray(i_old), np.asarray(ei))
 
 
@@ -189,9 +184,9 @@ def test_cluster_block_items_resolution_raises_not_shrinks(monkeypatch):
     with pytest.raises(vmem.VmemBudgetError):
         resolve_cluster_block_items(table, b=8, k=1024)
     with pytest.raises(vmem.VmemBudgetError):
-        cluster_topk(table, phi, 1024)
+        _sharded(psi, 4, block_items=None).topk_phi(phi, k=1024)
     # an explicit block_items pin (the operator override) still works
-    s, i = cluster_topk(table, phi, 8, block_items=128)
+    s, i = _sharded(psi, 4, block_items=128).topk_phi(phi, k=8)
     rs, ri = topk_score_ref(phi, psi, 8)
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
 
@@ -207,8 +202,8 @@ SHARD_MAP_SCRIPT = textwrap.dedent(
 
     from repro.kernels.topk_score import topk_score_ref
     from repro.kernels.topk_score.ref import SCORE_ATOL, SCORE_RTOL
+    from repro.kernels.topk_score.ops import exclude_ids_from_lists
     from repro.serve.cluster import shard_map_topk, shard_psi
-    from repro.serve.engine import exclude_ids_from_lists
 
     rng = np.random.default_rng(0)
     phi = jnp.asarray(rng.normal(size=(9, 16)), jnp.float32)
@@ -253,10 +248,7 @@ def test_multi_device_placement_single_host():
     """devices= places shards round-robin (degenerate single-device here —
     the placement plumbing must still be parity-clean)."""
     phi, psi = _rand((5, 8), 12), _rand((40, 8), 13)
-    cl = ShardedRetrievalCluster(
-        lambda p=phi: p, n_shards=3, k=9, block_items=32,
-        devices=jax.devices(), psi_table=psi,
-    )
+    cl = _sharded(psi, 3, lambda p=phi: p, k=9, devices=jax.devices())
     s, i = cl.topk()
     rs, ri = topk_score_ref(phi, psi, 9)
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))

@@ -1,4 +1,4 @@
-"""Delta ψ publish (``serve/publish.py`` + cluster/mesh): pure
+"""Delta ψ publish (``serve/publish.py`` + the mesh): pure
 ``apply_delta`` semantics (patch, append, hole/dup/negative validation),
 version-bump invalidation scope (batcher cache keyed on version), stale
 refusal across a delta bump, and the canary-staged refusal on the mesh."""
@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from repro.serve.batcher import MicroBatcher
-from repro.serve.cluster import ShardedRetrievalCluster
 from repro.serve.mesh import FaultTolerantRetrievalMesh
 from repro.serve.publish import PsiPublisher, apply_delta, dense_table
 
@@ -49,11 +48,12 @@ def test_apply_delta_validation():
     np.testing.assert_array_equal(out[18], row)
 
 
-# ------------------------------------------------- cluster version + rows
+# ---------------------------------- sharded (unreplicated) version + rows
 def test_cluster_delta_patch_append_retrievable():
     psi = _psi()
-    cl = ShardedRetrievalCluster(
-        lambda ctx: jnp.ones((len(ctx), 6)), n_shards=3, k=5, psi_table=psi
+    cl = FaultTolerantRetrievalMesh(
+        lambda ctx: jnp.ones((len(ctx), 6)), n_shards=3, n_replicas=1, k=5,
+        psi_table=psi,
     )
     v0 = cl.version
     # large magnitude ⇒ the self inner product dominates every cross score
@@ -71,8 +71,9 @@ def test_cluster_delta_patch_append_retrievable():
 
 def test_publisher_delta_records_versions():
     psi = _psi()
-    cl = ShardedRetrievalCluster(
-        lambda ctx: jnp.ones((len(ctx), 6)), n_shards=2, k=5, psi_table=psi
+    cl = FaultTolerantRetrievalMesh(
+        lambda ctx: jnp.ones((len(ctx), 6)), n_shards=2, n_replicas=1, k=5,
+        psi_table=psi,
     )
     pub = PsiPublisher(cl, lambda p: p)
     row = np.ones(6, np.float32)
@@ -83,8 +84,9 @@ def test_publisher_delta_records_versions():
 # ------------------------------------------- batcher invalidation scope
 def test_delta_bump_invalidates_batcher_cache():
     psi = _psi()
-    cl = ShardedRetrievalCluster(
-        lambda ctx: jnp.ones((len(ctx), 6)), n_shards=2, k=5, psi_table=psi
+    cl = FaultTolerantRetrievalMesh(
+        lambda ctx: jnp.ones((len(ctx), 6)), n_shards=2, n_replicas=1, k=5,
+        psi_table=psi,
     )
     batcher = MicroBatcher(
         lambda phi, eids: cl.topk_phi(phi, exclude_ids=eids),

@@ -27,6 +27,6 @@ def test_quickstart_runs_and_beats_popularity():
 def test_serve_retrieval_example():
     p = _run("serve_retrieval.py")
     assert p.returncode == 0, p.stdout[-1500:] + p.stderr[-1500:]
-    assert "cluster top-k == engine top-k == dense top-k" in p.stdout
+    assert "mesh top-k == dense top-k" in p.stdout
     assert "batcher:" in p.stdout
     assert "streaming sharded eval" in p.stdout

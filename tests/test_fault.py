@@ -19,10 +19,14 @@ from _zoo import _rand
 
 from repro.core.models import mf
 from repro.kernels.topk_score import topk_score_ref
-from repro.kernels.topk_score.ref import SCORE_ATOL, SCORE_RTOL
+from repro.kernels.topk_score.ops import exclude_ids_from_lists
+from repro.kernels.topk_score.ref import (
+    SCORE_ATOL,
+    SCORE_RTOL,
+    exclude_ids_to_mask,
+)
 from repro.serve.batcher import MicroBatcher
 from repro.serve.cluster import dead_item_ranges, shard_psi
-from repro.serve.engine import exclude_ids_from_lists, exclude_mask_from_lists
 from repro.serve.mesh import (
     FaultInjector,
     FaultTolerantRetrievalMesh,
@@ -428,7 +432,7 @@ def test_exclusion_rides_through_failover():
     lists = [rng.choice(101, size=7, replace=False) for _ in range(6)]
     eids = exclude_ids_from_lists(lists)
     rs_ref, ri_ref = topk_score_ref(
-        phi, psi, 20, exclude_mask_from_lists(lists, 101)
+        phi, psi, 20, exclude_ids_to_mask(eids, 101)
     )
     inj.fail(2, 0, "error")
     res = mesh.topk(exclude_ids=eids)

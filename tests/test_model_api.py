@@ -1,8 +1,9 @@
 """Unified Model protocol (``core/models/api.py``): all five zoo models run
 the SAME lifecycle loop — init → objective → fit → epoch → export_psi →
 build_phi → fold_in_user/item — with zero per-model signature branches at
-the call site; plus engine integration (``RetrievalEngine.from_model``) and
-the Dataset.require error contract."""
+the call site; plus serving integration (the mesh built from
+``export_psi``/``build_phi``, fold-in through ``fold_in_user``) and the
+Dataset.require error contract."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +14,7 @@ from repro.core.models import fm, mf, mfsi, parafac, tucker
 from repro.core.models.api import Dataset, Model, build_model
 from repro.core.models.parafac import TensorContext
 from repro.core.models.zoo import ZOO
-from repro.serve.engine import RetrievalEngine
+from repro.serve.mesh import FaultTolerantRetrievalMesh
 from repro.sparse.interactions import build_interactions
 
 N_CTX, N_ITEMS, K = 10, 12, 4
@@ -90,24 +91,20 @@ def test_uniform_lifecycle(name):
 def test_engine_from_model(name):
     model, query = _model(name)
     params = model.fit(model.init(jax.random.PRNGKey(0)), n_epochs=2)
-    eng = RetrievalEngine.from_model(model, params, k=5)
-    res = eng.topk(query)
+    psi = model.export_psi(params)
+    mesh = FaultTolerantRetrievalMesh(
+        lambda q: model.build_phi(params, q), n_shards=1, n_replicas=1, k=5,
+        psi_table=psi)
+    res = mesh.topk(query)                   # the model's own query space
     assert res.ids.shape == (6, 5) and res.coverage == 1.0
-    # parity with the hand-built engine
-    ref = RetrievalEngine(model.export_psi(params),
-                          lambda q: model.build_phi(params, q), k=5)
-    ref_res = ref.topk_phi(model.build_phi(params, query))
+    # parity with the φ rows built by hand
+    ref_res = mesh.topk_phi(model.build_phi(params, query))
     assert bool((res.ids == ref_res.ids).all())
     # request-time fold-in through the serving tier
-    phi = eng.fold_in_phi([0, 2], n_sweeps=32)
-    assert phi.shape == (1, eng.psi.shape[1])
-    assert eng.topk_phi(phi).ids.shape == (1, 5)
-
-
-def test_engine_without_model_refuses_fold_in():
-    eng = RetrievalEngine(jnp.ones((4, 3)), lambda q: jnp.ones((1, 3)), k=2)
-    with pytest.raises(RuntimeError, match="from_model"):
-        eng.fold_in_phi([0])
+    phi = jnp.asarray(model.fold_in_user(params, [0, 2], n_sweeps=32),
+                      jnp.float32)[None, :]
+    assert phi.shape == (1, psi.shape[1])
+    assert mesh.topk_phi(phi).ids.shape == (1, 5)
 
 
 def test_dataset_require_errors():

@@ -7,7 +7,8 @@ import numpy as np
 from repro.core.metrics import ndcg_at_k, recall_at_k
 from repro.core.models import mf
 from repro.eval.ranking import fit_eval_callback, ranking_eval
-from repro.serve.engine import exclude_mask_from_lists
+from repro.kernels.topk_score.ops import exclude_ids_from_lists
+from repro.kernels.topk_score.ref import exclude_ids_to_mask
 from repro.sparse.interactions import build_interactions
 
 
@@ -20,13 +21,18 @@ def _setup(n_ctx=40, n_items=120, k=8, seed=0):
     return rng, params, truth, excl
 
 
+def _dense_mask(excl, n_items):
+    """The oracle's dense (B, n_items) exclusion from ragged id lists."""
+    return exclude_ids_to_mask(exclude_ids_from_lists(excl), n_items)
+
+
 def test_streaming_equals_dense_metrics():
     _, params, truth, excl = _setup()
     phi = mf.build_phi(params, jnp.arange(40))
     psi = mf.export_psi(params)
     res = ranking_eval(phi, psi, truth, k=10, batch_rows=13, exclude=excl,
                        block_items=32)
-    mask = exclude_mask_from_lists(excl, 120)
+    mask = _dense_mask(excl, 120)
     dense = phi @ psi.T
     r = float(recall_at_k(dense, jnp.asarray(truth), 10, mask))
     n = float(ndcg_at_k(dense, jnp.asarray(truth), 10, mask))
@@ -47,7 +53,7 @@ def test_streaming_eval_never_builds_full_mask_rows():
     assert not hasattr(ranking_mod, "exclude_mask_from_lists")
     res = ranking_eval(phi, psi, truth, k=10, batch_rows=16, exclude=excl,
                        block_items=32)
-    mask = exclude_mask_from_lists(excl, 120)
+    mask = _dense_mask(excl, 120)
     dense = phi @ psi.T
     r = float(recall_at_k(dense, jnp.asarray(truth), 10, mask))
     np.testing.assert_allclose(res["recall@10"], r, atol=1e-6)
@@ -56,7 +62,7 @@ def test_streaming_eval_never_builds_full_mask_rows():
 def test_sharded_eval_matches_single_device():
     """cluster= streams the same batches through the sharded table; the
     merge contract makes the metrics identical at any shard count."""
-    from repro.serve.cluster import ShardedRetrievalCluster
+    from repro.serve.mesh import FaultTolerantRetrievalMesh
 
     _, params, truth, excl = _setup(seed=6)
     phi = mf.build_phi(params, jnp.arange(40))
@@ -64,8 +70,8 @@ def test_sharded_eval_matches_single_device():
     single = ranking_eval(phi, psi, truth, k=10, batch_rows=13, exclude=excl,
                           block_items=32)
     for n_shards in (1, 3):
-        cl = ShardedRetrievalCluster(n_shards=n_shards, k=10, block_items=32,
-                                     psi_table=psi)
+        cl = FaultTolerantRetrievalMesh(n_shards=n_shards, n_replicas=1,
+                                        k=10, block_items=32, psi_table=psi)
         sharded = ranking_eval(phi, None, truth, k=10, batch_rows=13,
                                exclude=excl, cluster=cl)
         np.testing.assert_allclose(sharded["recall@10"], single["recall@10"],

@@ -1,24 +1,35 @@
-"""Serving paths: chunked retrieval top-k, bulk scoring, and the request
-micro-batcher (deadline/size flush, out-of-order routing, LRU cache keyed
-on the published table version) under a simulated clock."""
+"""Serving paths: chunked retrieval top-k and the request micro-batcher
+(deadline/size flush, out-of-order routing, LRU cache keyed on the
+published table version) under a simulated clock."""
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.models import mf
 from repro.kernels.topk_score import topk_score_ref
+from repro.kernels.topk_score.ops import exclude_ids_from_lists
+from repro.serve import retrieval_topk
 from repro.serve.batcher import MicroBatcher
-from repro.serve.cluster import ShardedRetrievalCluster
-from repro.serve.engine import exclude_ids_from_lists
-from repro.serve import bulk_score, mf_retrieval_score_fn, retrieval_topk
+from repro.serve.mesh import FaultTolerantRetrievalMesh
 
 import jax
+
+
+def _score_fn(user_vec, item_table):
+    """The separable retrieval product per id chunk: (k)·(k, c) for one
+    user, or (B, k)·(k, c) for a batch."""
+
+    def score(ids):
+        s = jnp.take(item_table, ids, axis=0) @ user_vec.T  # (c,) | (c, B)
+        return s.T if s.ndim == 2 else s
+
+    return score
 
 
 def test_retrieval_topk_exact():
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.normal(size=(5000, 16)), jnp.float32)
     user = jnp.asarray(rng.normal(size=16), jnp.float32)
-    scores, ids = retrieval_topk(mf_retrieval_score_fn(user, table), 5000,
+    scores, ids = retrieval_topk(_score_fn(user, table), 5000,
                                  k=50, chunk=777)
     full = np.asarray(table @ user)
     expect = set(np.argsort(-full)[:50].tolist())
@@ -31,12 +42,12 @@ def test_retrieval_topk_batched_matches_per_row():
     rng = np.random.default_rng(2)
     table = jnp.asarray(rng.normal(size=(3000, 8)), jnp.float32)
     users = jnp.asarray(rng.normal(size=(5, 8)), jnp.float32)
-    scores, ids = retrieval_topk(mf_retrieval_score_fn(users, table), 3000,
+    scores, ids = retrieval_topk(_score_fn(users, table), 3000,
                                  k=20, chunk=512)
     assert scores.shape == (5, 20) and ids.shape == (5, 20)
     full = np.asarray(users @ table.T)
     for r in range(5):
-        s1, i1 = retrieval_topk(mf_retrieval_score_fn(users[r], table), 3000,
+        s1, i1 = retrieval_topk(_score_fn(users[r], table), 3000,
                                 k=20, chunk=512)
         np.testing.assert_array_equal(np.asarray(ids)[r], np.asarray(i1))
         np.testing.assert_array_equal(
@@ -46,7 +57,7 @@ def test_retrieval_topk_batched_matches_per_row():
 def test_retrieval_topk_short_catalogue_no_placeholder_leak():
     table = jnp.asarray(np.random.default_rng(3).normal(size=(7, 4)), jnp.float32)
     user = jnp.asarray([1.0, 0.0, 0.0, 0.0])
-    scores, ids = retrieval_topk(mf_retrieval_score_fn(user, table), 7, k=12)
+    scores, ids = retrieval_topk(_score_fn(user, table), 7, k=12)
     # first 7 slots are the real catalogue, exactly ranked
     np.testing.assert_array_equal(
         np.asarray(ids)[:7], np.argsort(-np.asarray(table @ user), kind="stable")[:7])
@@ -55,23 +66,12 @@ def test_retrieval_topk_short_catalogue_no_placeholder_leak():
     assert bool(np.isneginf(np.asarray(scores)[7:]).all())
 
 
-def test_bulk_score_chunking():
-    w = jnp.asarray([0.5, -1.0, 2.0, 0.25])
-
-    def fwd(batch):
-        return batch["x"] @ w  # arbitrary linear scorer
-
-    x = jnp.asarray(np.random.default_rng(1).normal(size=(1000, 4)), jnp.float32)
-    got = bulk_score(fwd, {"x": x}, chunk=128)
-    np.testing.assert_allclose(got, x @ w, rtol=1e-5)
-
-
 # --------------------------------------------------------------- batcher ---
 def _serving_stack(n_shards=2, k=10, n_ctx=40, n_items=77, seed=0):
     params = mf.init(jax.random.PRNGKey(seed), n_ctx, n_items, 8)
-    cluster = ShardedRetrievalCluster(
-        lambda ctx: mf.build_phi(params, ctx), n_shards=n_shards, k=k,
-        block_items=32, psi_table=mf.export_psi(params),
+    cluster = FaultTolerantRetrievalMesh(
+        lambda ctx: mf.build_phi(params, ctx), n_shards=n_shards,
+        n_replicas=1, k=k, block_items=32, psi_table=mf.export_psi(params),
     )
     clock = {"t": 0.0}
     batcher = MicroBatcher(

@@ -1,5 +1,5 @@
-"""Fused score+top-K kernel: oracle parity, edge cases, and the engine
-contract across the whole k-separable model zoo."""
+"""Fused score+top-K kernel: oracle parity, edge cases, and the serving
+contract (the one-shard mesh) across the whole k-separable model zoo."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,12 +8,13 @@ import pytest
 from _zoo import ZOO, model_phi_psi, _rand
 
 from repro.kernels.topk_score import topk_merge_shards, topk_score, topk_score_ref
-from repro.kernels.topk_score.ref import SCORE_ATOL, SCORE_RTOL
-from repro.serve.engine import (
-    RetrievalEngine,
-    exclude_ids_from_lists,
-    exclude_mask_from_lists,
+from repro.kernels.topk_score.ops import exclude_ids_from_lists
+from repro.kernels.topk_score.ref import (
+    SCORE_ATOL,
+    SCORE_RTOL,
+    exclude_ids_to_mask,
 )
+from repro.serve.mesh import FaultTolerantRetrievalMesh
 
 
 def test_matches_ref_and_dense_topk_nondivisible_blocks():
@@ -49,38 +50,52 @@ def test_tied_scores_rank_ascending_id():
 
 
 def test_exclude_mask_and_fully_masked_row():
+    """Exclusion lists that cover 40% of each row, and every id of row 2."""
     rng = np.random.default_rng(6)
     phi, psi = _rand((7, 12), 6), _rand((90, 12), 7)
-    excl = jnp.asarray(rng.random((7, 90)) < 0.4)
-    excl = excl.at[2, :].set(True)  # row 2: nothing admissible
-    s, i = topk_score(phi, psi, 12, excl, block_items=32)
-    rs, ri = topk_score_ref(phi, psi, 12, excl)
+    hit = rng.random((7, 90)) < 0.4
+    hit[2, :] = True  # row 2: nothing admissible
+    eids = exclude_ids_from_lists(
+        [rng.permutation(np.flatnonzero(row)) for row in hit])
+    assert eids.shape[1] == 90
+    s, i = topk_score(phi, psi, 12, exclude_ids=eids, block_items=32)
+    rs, ri = topk_score_ref(phi, psi, 12, jnp.asarray(hit))
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
-    # excluded ids never leak; fully-masked row is all (−inf, −1)
+    # excluded ids never leak; fully-excluded row is all (−inf, −1)
     assert bool((np.asarray(i)[2] == -1).all())
     assert bool(np.isneginf(np.asarray(s)[2]).all())
     got = np.asarray(i)
-    mask = np.asarray(excl)
     for r in range(7):
         real = got[r][got[r] >= 0]
-        assert not mask[r, real].any()
+        assert not hit[r, real].any()
 
 
 def test_exclude_ids_matches_mask_path():
     """The web-scale id-list exclusion form (in-kernel block-aligned mask
-    slices, no (B, n_items) array) must agree with the dense-mask form."""
+    slices, no (B, n_items) array) must agree with the dense-mask oracle."""
     rng = np.random.default_rng(16)
     phi, psi = _rand((7, 12), 6), _rand((90, 12), 7)
     lists = [rng.choice(90, size=int(rng.integers(0, 9)), replace=False)
              for _ in range(7)]
     eids = exclude_ids_from_lists(lists)
-    mask = exclude_mask_from_lists(lists, 90)
+    mask = exclude_ids_to_mask(eids, 90)
     s_ids, i_ids = topk_score(phi, psi, 12, exclude_ids=eids, block_items=32)
-    s_m, i_m = topk_score(phi, psi, 12, mask, block_items=32)
+    s_m, i_m = topk_score_ref(phi, psi, 12, mask)
     np.testing.assert_array_equal(np.asarray(i_ids), np.asarray(i_m))
-    np.testing.assert_array_equal(np.asarray(s_ids), np.asarray(s_m))
+    # kernel vs reference: the stated fp32 score contract (ref.py)
+    np.testing.assert_allclose(np.asarray(s_ids), np.asarray(s_m),
+                               rtol=SCORE_RTOL, atol=SCORE_ATOL)
     rs, ri = topk_score_ref(phi, psi, 12, exclude_ids=eids)
     np.testing.assert_array_equal(np.asarray(i_ids), np.asarray(ri))
+    # bit for bit: the kernel's own unexcluded top-(12 + 8), excluded ids
+    # dropped, is the excluded top-12 (every list holds at most 8 ids)
+    wide_s, wide_i = map(np.asarray, topk_score(phi, psi, 20, block_items=32))
+    for r, ids in enumerate(lists):
+        keep = ~np.isin(wide_i[r], ids)
+        np.testing.assert_array_equal(np.asarray(i_ids)[r],
+                                      wide_i[r][keep][:12])
+        np.testing.assert_array_equal(np.asarray(s_ids)[r],
+                                      wide_s[r][keep][:12])
 
 
 def test_id_offset_and_n_valid_shard_semantics():
@@ -140,21 +155,24 @@ def test_merge_shards_is_tie_stable_and_pads_inadmissible():
 @pytest.mark.parametrize("name", ZOO)
 def test_streaming_matches_dense_topk_all_models(name):
     """The acceptance check: fused kernel == dense lax.top_k for the zoo,
-    with and without an exclude mask, through the RetrievalEngine."""
+    with and without exclusion, through the one-shard serving mesh."""
     rng = np.random.default_rng(42)
     phi, psi = model_phi_psi(name, rng)
     # model predict ⇔ ⟨φ, ψ⟩ consistency is covered by each model's own
     # tests; here we pin streaming top-k to the dense path over Φ·Ψᵀ
-    engine = RetrievalEngine(psi, lambda p=phi: p, k=12, block_items=32)
-    s, i = engine.topk()
-    ds, di = jax.lax.top_k(engine.scores(phi), 12)
+    mesh = FaultTolerantRetrievalMesh(lambda p=phi: p, n_shards=1,
+                                      n_replicas=1, k=12, block_items=32,
+                                      psi_table=psi)
+    s, i = mesh.topk()
+    ds, di = jax.lax.top_k(phi @ psi.T, 12)
     np.testing.assert_array_equal(np.asarray(i), np.asarray(di))
     np.testing.assert_allclose(np.asarray(s), np.asarray(ds), rtol=1e-5, atol=1e-6)
 
     excl_lists = [rng.choice(psi.shape[0], size=5, replace=False)
                   for _ in range(phi.shape[0])]
-    mask = exclude_mask_from_lists(excl_lists, psi.shape[0])
-    s2, i2 = engine.topk(exclude_mask=mask)
+    eids = exclude_ids_from_lists(excl_lists)
+    mask = exclude_ids_to_mask(eids, psi.shape[0])
+    s2, i2 = mesh.topk(exclude_ids=eids)
     rs2, ri2 = topk_score_ref(phi, psi, 12, mask)
     np.testing.assert_array_equal(np.asarray(i2), np.asarray(ri2))
     got = np.asarray(i2)
@@ -162,8 +180,8 @@ def test_streaming_matches_dense_topk_all_models(name):
     for r in range(got.shape[0]):
         real = got[r][got[r] >= 0]
         assert not m[r, real].any()
-    # the id-list exclusion form agrees with the mask form bit-for-bit
-    s3, i3 = engine.topk(exclude_ids=exclude_ids_from_lists(excl_lists))
+    # the one-shard mesh is the bare kernel call, bit for bit
+    s3, i3 = topk_score(phi, psi, 12, exclude_ids=eids, block_items=32)
     np.testing.assert_array_equal(np.asarray(i3), np.asarray(i2))
     np.testing.assert_array_equal(np.asarray(s3), np.asarray(s2))
 
@@ -258,7 +276,8 @@ def _walk_case(case):
     "several_row_blocks", "bf16", "int8"])
 def test_exclude_id_walk_matches_mask_and_ref(case):
     """The exclude-id form, which walks only each tile's ids, returns what
-    the dense-mask form and the dense reference return, bit for bit."""
+    the dense reference over the shard's dense mask returns, bit for
+    bit."""
     phi, psi, scale, ids, off, n_valid, block_b = _walk_case(case)
     k, n_rows = 40, psi.shape[0]
     kw = dict(psi_scale=scale, id_offset=off, n_valid=n_valid,
@@ -268,12 +287,15 @@ def test_exclude_id_walk_matches_mask_and_ref(case):
     in_shard = (np.asarray(ids) >= 0) & (local >= 0) & (local < n_rows)
     mask = np.zeros((phi.shape[0], n_rows), np.int8)
     mask[np.nonzero(in_shard)[0], local[in_shard]] = 1
-    ms, mi = topk_score(phi, psi, k, jnp.asarray(mask), **kw)
-    np.testing.assert_array_equal(np.asarray(i), np.asarray(mi))
-    np.testing.assert_array_equal(np.asarray(s), np.asarray(ms))
     deq = psi.astype(jnp.float32)
     if scale is not None:
         deq = deq * scale[:, None]
+    ms, mi = topk_score_ref(phi, deq[:n_valid], k,
+                            jnp.asarray(mask[:, :n_valid]))
+    mi = np.asarray(mi)
+    np.testing.assert_array_equal(np.asarray(i), np.where(mi >= 0, mi + off,
+                                                          -1))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(ms))
     rs, ri = topk_score_ref(phi, deq[:n_valid], k,
                             exclude_ids=np.where(in_shard, local, -1))
     ri = np.asarray(ri)
